@@ -117,11 +117,7 @@ def run_chaos(out=print, smoke: bool | None = None) -> dict:
     smoke = bool(os.environ.get("FIG14_SMOKE")) if smoke is None else smoke
 
     from repro.runtime.sharding import ensure_host_devices
-    try:
-        ensure_host_devices(N_SHARDS)
-    except RuntimeError as e:
-        print(f"fig14: skipped ({e})")
-        return {}
+    ensure_host_devices(N_SHARDS)     # raises when too few are visible
 
     from repro.runtime.serving import FaultInjector
 

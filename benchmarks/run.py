@@ -44,6 +44,8 @@ def main(argv=None) -> None:
                     choices=[name for name, _ in MODULES],
                     help="run only these modules")
     args = ap.parse_args(argv)
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     records: dict[str, float] = {}
 

@@ -10,6 +10,8 @@ cells. Run standalone (sets the 512-device flag before jax import):
 """
 import os
 
+# placeholder CPU devices: the dry-run compiles, it never claims a chip
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("XLA_FLAGS",
                       "--xla_force_host_platform_device_count=512")
 

@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from .sinkhorn import LamUnderflowError, cdist, underflow_report
 from .sinkhorn_sparse import (adaptive_loop_scoped,
@@ -38,9 +38,10 @@ from .sinkhorn_sparse import (adaptive_loop_scoped,
 from .sparse import PaddedDocs
 
 
-# jax >= 0.5 requires marking shard-varying scan carries with lax.pvary;
-# on older jax (no varying-manual-axes type system) identity is correct.
-_pvary = getattr(lax, "pvary", lambda x, axes: x)
+def _pvary(x, axes):
+    """Mark a scan carry as varying over the doc-shard axes (shard_map's
+    varying-manual-axes types require it)."""
+    return lax.pcast(x, axes, to="varying")
 
 
 def _doc_axes(mesh: Mesh) -> tuple[str, ...]:
@@ -205,7 +206,7 @@ def sinkhorn_wmd_sparse_distributed(r, vecs_sel, vecs, docs: PaddedDocs,
     out_spec = P(None, doc_axes) if batched else P(doc_axes)
     # the adaptive path's lax.while_loop has no shard_map replication rule
     # (jax #workaround) — drop the rep check only when it is in play
-    rep = {} if tol is None else {"check_rep": False}
+    rep = {} if tol is None else {"check_vma": False}
 
     def finish(out_iters):
         out, iters = out_iters

@@ -100,7 +100,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .sinkhorn import LamUnderflowError, underflow_report
+from .sinkhorn import FP32_GEMM, LamUnderflowError, underflow_report
 from .sinkhorn_sparse import (SolvePrecision, adaptive_loop,
                               adaptive_loop_scoped, marginal_residual,
                               marginal_residual_per_query)
@@ -157,7 +157,7 @@ def _assign_clusters(points: jax.Array, centers: jax.Array) -> jax.Array:
     """Nearest-center assignment for one mini-batch: (B, w) -> (B,)."""
     d2 = (jnp.sum(points * points, axis=1)[:, None]
           + jnp.sum(centers * centers, axis=1)[None, :]
-          - 2.0 * (points @ centers.T))
+          - 2.0 * jnp.matmul(points, centers.T, precision=FP32_GEMM))
     return jnp.argmin(d2, axis=1)
 
 
@@ -233,7 +233,7 @@ def _pivot_dists(points: jax.Array, pivots: jax.Array) -> jax.Array:
     triangle prestage ``|d(q, p) - d(n, p)| <= ||qcent - centroid_n||``."""
     a2 = jnp.sum(points * points, axis=1)[:, None]
     b2 = jnp.sum(pivots * pivots, axis=1)[None, :]
-    d2 = a2 + b2 - 2.0 * (points @ pivots.T)
+    d2 = a2 + b2 - 2.0 * jnp.matmul(points, pivots.T, precision=FP32_GEMM)
     return jnp.sqrt(jnp.maximum(d2, 0.0))
 
 
@@ -1082,7 +1082,8 @@ def _compute_kq(sup: jax.Array, mask: jax.Array, vecs: jax.Array,
                         a.reshape(q * b, -1).T.astype(jnp.bfloat16),
                         preferred_element_type=jnp.float32)
     else:
-        ab = vecs @ a.reshape(q * b, -1).T              # (V, Q*B)
+        ab = jnp.matmul(vecs, a.reshape(q * b, -1).T,
+                        precision=FP32_GEMM)            # (V, Q*B)
     d2 = jnp.maximum(vecs_sq[:, None] + a2.reshape(1, -1) - 2.0 * ab, 0.0)
     m = jnp.sqrt(d2)
     if log_domain:

@@ -54,6 +54,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .sinkhorn import FP32_GEMM
+
 
 def _pow2(n: int, floor: int = 8) -> int:
     b = max(1, int(floor))
@@ -79,7 +81,7 @@ def _cdist_rows(ids: jax.Array, vecs: jax.Array, vecs_sq: jax.Array,
                         a.T.astype(jnp.bfloat16),
                         preferred_element_type=jnp.float32)
     else:
-        ab = vecs @ a.T                                   # (V, U)
+        ab = jnp.matmul(vecs, a.T, precision=FP32_GEMM)   # (V, U)
     d2 = jnp.maximum(vecs_sq[:, None] + a2[None, :] - 2.0 * ab, 0.0)
     return jnp.sqrt(d2).T                                 # (U, V)
 

@@ -72,6 +72,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .sinkhorn import FP32_GEMM
+
 
 @runtime_checkable
 class Pruner(Protocol):
@@ -94,7 +96,7 @@ class Pruner(Protocol):
 def _wcd_bounds(qcent: jax.Array, centroids: jax.Array) -> jax.Array:
     a2 = jnp.sum(qcent * qcent, axis=1)[:, None]
     b2 = jnp.sum(centroids * centroids, axis=1)[None, :]
-    d2 = a2 + b2 - 2.0 * (qcent @ centroids.T)
+    d2 = a2 + b2 - 2.0 * jnp.matmul(qcent, centroids.T, precision=FP32_GEMM)
     return jnp.sqrt(jnp.maximum(d2, 0.0))
 
 
@@ -205,7 +207,7 @@ def _wcd_stage(qcent, centroids, ids_pad, qmask):
     cand = jnp.take(centroids, ids_pad, axis=0)          # (Sp, w)
     a2 = jnp.sum(qcent * qcent, axis=1)[:, None]
     b2 = jnp.sum(cand * cand, axis=1)[None, :]
-    d2 = a2 + b2 - 2.0 * (qcent @ cand.T)
+    d2 = a2 + b2 - 2.0 * jnp.matmul(qcent, cand.T, precision=FP32_GEMM)
     return jnp.where(qmask, jnp.sqrt(jnp.maximum(d2, 0.0)), jnp.inf)
 
 
@@ -218,7 +220,8 @@ def _wcd_dense_keep_all(qcent, centroids, thresh):
     q = qcent[:qc]
     a2 = jnp.sum(q * q, axis=1)[:, None]
     b2 = jnp.sum(centroids * centroids, axis=1)[None, :]
-    d2 = jnp.maximum(a2 + b2 - 2.0 * (q @ centroids.T), 0.0)
+    d2 = jnp.maximum(
+        a2 + b2 - 2.0 * jnp.matmul(q, centroids.T, precision=FP32_GEMM), 0.0)
     return jnp.any(d2 <= jnp.square(thresh)[:, None], axis=0)
 
 
@@ -236,7 +239,8 @@ def _wcd_dense_keep(qcent, centroids, pm, assign, thresh):
     q = qcent[:qc]
     a2 = jnp.sum(q * q, axis=1)[:, None]
     b2 = jnp.sum(centroids * centroids, axis=1)[None, :]
-    d2 = jnp.maximum(a2 + b2 - 2.0 * (q @ centroids.T), 0.0)
+    d2 = jnp.maximum(
+        a2 + b2 - 2.0 * jnp.matmul(q, centroids.T, precision=FP32_GEMM), 0.0)
     cand = jnp.take(pm[:qc], assign, axis=1)             # (qc, N) candidacy
     return jnp.any(cand & (d2 <= jnp.square(thresh)[:, None]), axis=0)
 
@@ -334,7 +338,7 @@ def _probe_dists(sup, r, mask, vecs, centers):
     qcent = jnp.einsum("qb,qbw->qw", r * mask, jnp.take(vecs, sup, axis=0))
     a2 = jnp.sum(qcent * qcent, axis=1)[:, None]
     b2 = jnp.sum(centers * centers, axis=1)[None, :]
-    d2 = a2 + b2 - 2.0 * (qcent @ centers.T)
+    d2 = a2 + b2 - 2.0 * jnp.matmul(qcent, centers.T, precision=FP32_GEMM)
     return jnp.sqrt(jnp.maximum(d2, 0.0)), qcent
 
 

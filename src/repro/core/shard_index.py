@@ -72,7 +72,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.runtime.fault_tolerance import PoisonStep, ShardHealth
@@ -486,7 +486,7 @@ def _build_merge(mesh: Mesh, n_shards: int, k: int):
         return -neg, jnp.take_along_axis(i_flat, pos, axis=1)
 
     return jax.jit(shard_map(merge, mesh=mesh, in_specs=(P("shard"),),
-                             out_specs=(P(), P()), check_rep=False))
+                             out_specs=(P(), P()), check_vma=False))
 
 
 class ShardedWmdEngine:

@@ -36,6 +36,7 @@ def _kernel(a_ref, b_ref, r_ref, *out_refs, lam: float, k_only: bool,
                                  preferred_element_type=jnp.float32)
     else:
         ab = jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                                 precision=jax.lax.Precision.HIGHEST,
                                  preferred_element_type=jnp.float32)  # MXU
     a2 = jnp.sum(a * a, axis=1, keepdims=True)        # (v_r, 1)
     b2 = jnp.sum(b * b, axis=1)[None, :]              # (1, bv)

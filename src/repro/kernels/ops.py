@@ -4,9 +4,9 @@ Handles TPU-alignment padding (the kernels' shape contract) and exposes
 ``sinkhorn_wmd_kernel`` — the full WMD pipeline on the kernel path, result
 bit-identical (up to fp reassociation) to ``repro.core`` oracles.
 
-On CPU (this container) the kernels execute with ``interpret=True``; on a
-real TPU the same call sites compile to Mosaic. ``INTERPRET`` flips the
-default per-platform.
+Off the TPU the kernels execute with ``interpret=True``; on a TPU the same
+call sites compile to Mosaic. :func:`resolve_interpret` picks the mode from
+the platform at call time, and never interprets on a TPU.
 """
 from __future__ import annotations
 
@@ -20,7 +20,18 @@ from . import cdist_exp as _cdist_exp
 from . import rwmd as _rwmd
 from . import sddmm_spmm as _sddmm_spmm
 
-INTERPRET = jax.default_backend() != "tpu"
+def resolve_interpret(interpret: bool | None = None) -> bool:
+    """Pallas interpret mode for a call: ``None`` follows the platform
+    (interpret everywhere but a TPU). On a TPU the kernels always compile
+    to Mosaic, so an explicit ``True`` there is refused rather than
+    silently run through the interpreter."""
+    on_tpu = jax.default_backend() == "tpu"
+    if interpret is None:
+        return not on_tpu
+    if interpret and on_tpu:
+        raise ValueError("Pallas interpret mode is not used on a TPU; "
+                         "pass interpret=None to compile to Mosaic")
+    return bool(interpret)
 
 
 def pad_to(x: jax.Array, axis: int, multiple: int, value=0.0) -> jax.Array:
@@ -40,7 +51,7 @@ def cdist_exp(a, b, r, lam: float, block_v: int = 512,
     ``k_only=True`` returns just K and skips the two dead HBM stores;
     ``gemm``/``log_k`` plumb the SolvePrecision policy (bf16 MXU operands
     / unexponentiated log K for the log-domain solve)."""
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     v_r, w = a.shape
     v = b.shape[0]
     ap = pad_to(pad_to(a, 1, 128), 0, 8)
@@ -66,7 +77,7 @@ def rwmd_min_cdist(a, mask, b, block_v: int = 512,
     RWMD-on-survivors stage) and the result is (Q, Vc) in ``vocab_ids``
     order. Ids are padded to the block size with id 0 — callers index the
     result by candidate position, never by the padded tail."""
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     q, bq, w = a.shape
     ap = pad_to(pad_to(a, 2, 128), 1, 8)
     maskp = pad_to(mask, 1, 8)               # pad support rows masked out
@@ -87,7 +98,7 @@ def rwmd_min_cdist(a, mask, b, block_v: int = 512,
 
 def sddmm_spmm_step(g, g_over_r, val, x, block_n: int = 128,
                     interpret: bool | None = None):
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     v_r, n, length = g.shape
     gp = pad_to(pad_to(pad_to(g, 2, 128), 1, block_n), 0, 8)
     gorp = pad_to(pad_to(pad_to(g_over_r, 2, 128), 1, block_n), 0, 8)
@@ -109,7 +120,7 @@ def sinkhorn_fused_all(g, val, r, lam: float, n_iter: int, block_n: int = 128,
     pad row would stop being inert). ``resmask`` (N,) scopes each block's
     adaptive exit test to the caller's candidate docs (pad docs are
     masked out, matching the val padding)."""
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     v_r, n, length = g.shape
     row_pad = -jnp.inf if log_domain else 0.0
     gp = pad_to(pad_to(pad_to(g, 2, 128), 1, block_n), 0, 8, value=row_pad)
@@ -139,7 +150,7 @@ def sinkhorn_fused_all_batched(g, val, r, lam: float, n_iter: int,
     scopes each query's exit test to its own candidate docs — each grid
     block holds one query's rows, so the per-block exit is a
     per-query-row freeze (ISSUE 5)."""
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     q, v_r, n, length = g.shape
     row_pad = -jnp.inf if log_domain else 0.0
     gp = pad_to(pad_to(pad_to(g, 3, 128), 2, block_n), 1, 8, value=row_pad)

@@ -42,12 +42,13 @@ def _kernel(a_ref, mask_ref, b_ref, out_ref):
     mask = mask_ref[0]                    # (B, 1)
     b = b_ref[...]                        # (bv, w)  streamed vocab tile
     ab = jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                             precision=jax.lax.Precision.HIGHEST,
                              preferred_element_type=jnp.float32)  # MXU
     a2 = jnp.sum(a * a, axis=1, keepdims=True)       # (B, 1)
     b2 = jnp.sum(b * b, axis=1)[None, :]             # (1, bv)
     d = jnp.sqrt(jnp.maximum(a2 + b2 - 2.0 * ab, 0.0))
     d = jnp.where(mask > 0, d, jnp.inf)              # pad rows out of the min
-    out_ref[...] = jnp.min(d, axis=0, keepdims=True)  # (1, bv)
+    out_ref[0] = jnp.min(d, axis=0, keepdims=True)   # (1, bv)
 
 
 @functools.partial(jax.jit, static_argnames=("block_v", "interpret"))
@@ -65,7 +66,10 @@ def rwmd_min_cdist(a: jax.Array, mask: jax.Array, b: jax.Array,
     v = b.shape[0]
     assert v % block_v == 0, (v, block_v)
     grid = (q, v // block_v)
-    return pl.pallas_call(
+    # the output carries a unit middle axis: a (1, block_v) block of a
+    # (Q, V) array is not (8, 128)-aligned on its query axis, while a
+    # (1, 1, block_v) block of (Q, 1, V) spans that axis whole
+    out = pl.pallas_call(
         _kernel,
         grid=grid,
         in_specs=[
@@ -73,10 +77,11 @@ def rwmd_min_cdist(a: jax.Array, mask: jax.Array, b: jax.Array,
             pl.BlockSpec((1, bq, 1), lambda qi, i: (qi, 0, 0)),
             pl.BlockSpec((block_v, w), lambda qi, i: (i, 0)),     # streamed
         ],
-        out_specs=pl.BlockSpec((1, block_v), lambda qi, i: (qi, i)),
-        out_shape=jax.ShapeDtypeStruct((q, v), a.dtype),
+        out_specs=pl.BlockSpec((1, 1, block_v), lambda qi, i: (qi, 0, i)),
+        out_shape=jax.ShapeDtypeStruct((q, 1, v), a.dtype),
         interpret=interpret,
     )(a, mask.reshape(q, bq, 1), b)
+    return out.reshape(q, v)
 
 
 @functools.partial(jax.jit, static_argnames=("block_v", "interpret"))

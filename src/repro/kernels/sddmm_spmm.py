@@ -49,6 +49,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # single source of truth for the GM = -G*log(G)/lam rebuild and the
 # adaptive-exit machinery; pure jnp/lax, so they trace inside Pallas
@@ -94,12 +95,20 @@ def sddmm_spmm_step(g: jax.Array, g_over_r: jax.Array, val: jax.Array,
     )(g, g_over_r, val, x)
 
 
+def _sddmm_with(g, u):
+    """t[j, l] = sum_k g[k, j, l] u[k, j]: (v_r, bn, L) x (v_r, bn)."""
+    return jnp.sum(g * u[:, :, None], axis=0)
+
+
 def _solve_block(g, val, r, n_iter: int, lam: float, tol=None,
                  check_every: int = 4, gemm: str = "fp32",
                  log_domain: bool = False, resmask=None):
     """Shared solver body: one (v_r, bn, L) G tile resident in VMEM.
 
-    g (v_r, bn, L); val (bn, L); r (v_r, 1). Returns (wmd (bn,), iters).
+    g (v_r, bn, L); val (bn, L); r (v_r, 1). Returns (wmd (1, bn), iters).
+    Every array value stays at rank 2 or more (doc vectors are (1, bn)
+    rows or (bn, 1) columns): Mosaic lays out rank-1 vectors poorly and
+    its compiler aborts on some of them.
 
     ``tol`` switches the fixed ``fori_loop`` to a ``lax.while_loop`` with
     a residual epilogue: the doc-marginal residual ``max|val/t - w_prev|``
@@ -111,8 +120,8 @@ def _solve_block(g, val, r, n_iter: int, lam: float, tol=None,
     UNexponentiated log K (pad rows -inf), column-stabilizes it in VMEM,
     and adds the exact shift correction to the distance line.
 
-    ``resmask`` (bn,) scopes the exit test to the CALLER'S candidate docs
-    (ISSUE 5's per-query residual scoping on the kernel path: in the
+    ``resmask`` (bn, 1) scopes the exit test to the CALLER'S candidate
+    docs (per-query residual scoping on the kernel path: in the
     batched kernel each grid block holds exactly one query's rows, so a
     block whose scope excludes its far docs exits — freezing that query's
     rows — as soon as the docs the query actually needs are stationary).
@@ -130,9 +139,11 @@ def _solve_block(g, val, r, n_iter: int, lam: float, tol=None,
     v_r = g.shape[0]
     bn = g.shape[1]
     live = (val > 0).astype(g.dtype)
-    rowmask = (jnp.sum(jnp.abs(g), axis=(1, 2), keepdims=False) > 0)
-    x0 = jnp.where(rowmask, 1.0 / jnp.sum(rowmask.astype(g.dtype)), 0.0)
-    x = jnp.broadcast_to(x0[:, None], (v_r, bn)).astype(g.dtype)
+    rowmask = jnp.sum(jnp.sum(jnp.abs(g), axis=2), axis=1,
+                      keepdims=True) > 0                      # (v_r, 1)
+    n_rows = jnp.sum(rowmask.astype(g.dtype), axis=0, keepdims=True)
+    x0 = jnp.where(rowmask, 1.0 / n_rows, 0.0)                # (v_r, 1)
+    x = jnp.broadcast_to(x0, (v_r, bn)).astype(g.dtype)
 
     # bf16 policy = bf16-ROUNDED OPERANDS with fp32 products/accumulation
     # (cast through bf16, multiply in fp32 — matching the einsum paths'
@@ -146,7 +157,7 @@ def _solve_block(g, val, r, n_iter: int, lam: float, tol=None,
         return a if gd is None else a.astype(gd).astype(jnp.float32)
 
     def _sddmm(u):
-        return jnp.sum(gb * _rnd(u)[:, :, None], axis=0)
+        return _sddmm_with(gb, _rnd(u))
 
     def _spmm(w):
         return jnp.sum(gorb * _rnd(w)[None, :, :], axis=2)
@@ -159,7 +170,7 @@ def _solve_block(g, val, r, n_iter: int, lam: float, tol=None,
 
     resm = live > 0
     if resmask is not None:
-        resm = resm & (resmask > 0)[:, None]
+        resm = resm & (resmask > 0)
     if tol is None:
         x = jax.lax.fori_loop(0, n_iter, lambda _, x: one(x)[0], x)
         iters = jnp.asarray(n_iter, jnp.int32)
@@ -172,12 +183,14 @@ def _solve_block(g, val, r, n_iter: int, lam: float, tol=None,
     t = _sddmm(u)
     w = val * _safe_inv(t) * live
     gm = reconstruct_gm(g, lam)           # in VMEM; never touches HBM
-    # final line: wmd[j] = sum_k u[k,j] * sum_l GM[k,j,l] w[j,l]
-    wmd = jnp.sum(u * jnp.sum(gm * w[None, :, :], axis=2), axis=0)  # (bn,)
+    # final line: wmd[j] = sum_l w[j,l] * sum_k u[k,j] GM[k,j,l] — the
+    # k-sum first (elementwise across vregs), then one lane reduce
+    pw = _sddmm_with(gm, u) * w                               # (bn, L)
     if log_domain:
         # exact rescale correction (t*w == val on live slots)
-        wmd = wmd - jnp.sum(shift * val, axis=1) / lam
-    return wmd, iters
+        pw = pw - shift * val / lam
+    # the lane reduce over a unit leading axis yields the (1, bn) row
+    return jnp.sum(pw[None, :, :], axis=2), iters
 
 
 def _fused_kernel(g_ref, val_ref, r_ref, *refs, n_iter: int,
@@ -188,11 +201,21 @@ def _fused_kernel(g_ref, val_ref, r_ref, *refs, n_iter: int,
         rm = rm_ref[0]
     else:
         (wmd_ref, it_ref), rm = refs, None
-    wmd, iters = _solve_block(g_ref[...], val_ref[...], r_ref[...], n_iter,
-                              lam, tol, check_every, gemm, log_domain,
+    wmd, iters = _solve_block(g_ref[0], val_ref[...], r_ref[0], n_iter, lam,
+                              tol, check_every, gemm, log_domain,
                               resmask=rm)
-    wmd_ref[...] = wmd[None, :]
-    it_ref[...] = jnp.full((1, 1), iters, jnp.int32)
+    wmd_ref[0] = wmd
+    it_ref[...] = jnp.full(it_ref.shape, iters, jnp.int32)
+
+
+def _compiler_params(block_bytes: int):
+    """Scoped-VMEM budget for one grid step: the double-buffered G block
+    plus the solver body's G-sized temporaries (G/r, the broadcast
+    products, the rebuilt GM), with headroom, inside v5e's 128 MiB VMEM.
+    The compiler's default scoped limit (16 MiB) is too small for the
+    adaptive loop over a (64, 128, 128) fp32 tile, which needs ~24 MiB."""
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=int(min(max(8 * block_bytes, 32 << 20), 100 << 20)))
 
 
 @functools.partial(jax.jit,
@@ -213,47 +236,15 @@ def sinkhorn_fused_all(g: jax.Array, val: jax.Array, r: jax.Array, lam: float,
     realized iteration count per doc block (== ``n_iter`` for the fixed
     loop; see :func:`_solve_block` for the adaptive/precision knobs).
     ``resmask`` (N,) float/bool scopes each block's adaptive exit to the
-    caller's candidate docs (ISSUE 5; ignored without ``tol``).
+    caller's candidate docs (ignored without ``tol``). This is
+    the batched solver at Q == 1.
     """
-    v_r, n, length = g.shape
-    assert n % block_n == 0, (n, block_n)
-    grid = (n // block_n,)
-    with_resmask = resmask is not None and tol is not None
-    in_specs = [pl.BlockSpec((v_r, block_n, length), lambda i: (0, i, 0)),
-                pl.BlockSpec((block_n, length), lambda i: (i, 0)),
-                pl.BlockSpec((v_r, 1), lambda i: (0, 0))]
-    args = [g, val, r.reshape(-1, 1)]
-    if with_resmask:
-        in_specs.append(pl.BlockSpec((1, block_n), lambda i: (0, i)))
-        args.append(jnp.asarray(resmask, g.dtype).reshape(1, n))
-    wmd, iters = pl.pallas_call(
-        functools.partial(_fused_kernel, n_iter=n_iter, lam=lam, tol=tol,
-                          check_every=check_every, gemm=gemm,
-                          log_domain=log_domain, with_resmask=with_resmask),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[pl.BlockSpec((1, block_n), lambda i: (0, i)),
-                   pl.BlockSpec((1, 1), lambda i: (0, i))],
-        out_shape=[jax.ShapeDtypeStruct((1, n), g.dtype),
-                   jax.ShapeDtypeStruct((1, n // block_n), jnp.int32)],
-        interpret=interpret,
-    )(*args)
+    wmd, iters = sinkhorn_fused_all_batched(
+        g[None], val, r[None], lam, n_iter, block_n=block_n,
+        interpret=interpret, tol=tol, check_every=check_every, gemm=gemm,
+        log_domain=log_domain,
+        resmask=None if resmask is None else jnp.asarray(resmask)[None])
     return wmd[0], iters[0]
-
-
-def _fused_batched_kernel(g_ref, val_ref, r_ref, *refs, n_iter: int,
-                          lam: float, tol, check_every: int,
-                          gemm: str, log_domain: bool, with_resmask: bool):
-    if with_resmask:
-        rm_ref, wmd_ref, it_ref = refs
-        rm = rm_ref[0]
-    else:
-        (wmd_ref, it_ref), rm = refs, None
-    wmd, iters = _solve_block(g_ref[0], val_ref[...], r_ref[0], n_iter, lam,
-                              tol, check_every, gemm, log_domain,
-                              resmask=rm)
-    wmd_ref[...] = wmd[None, :]
-    it_ref[...] = jnp.full((1, 1), iters, jnp.int32)
 
 
 @functools.partial(jax.jit,
@@ -284,10 +275,15 @@ def sinkhorn_fused_all_batched(g: jax.Array, val: jax.Array, r: jax.Array,
     Grid is (Q, N // block_n): the doc axis varies fastest so each query's
     corpus sweep is contiguous; ``val`` blocks depend only on the doc index
     and are revisited per query (resident after the first pass on TPU).
+
+    Mosaic takes a block whose last two dimensions are (8, 128)-aligned or
+    span the array's own, so the per-block outputs and the mask carry unit
+    axes: wmd is produced as (Q, 1, N), iters as (Q, N // block_n, 1, 1)
+    and ``resmask`` is passed as (Q, N, 1).
     """
     q, v_r, n, length = g.shape
     assert n % block_n == 0, (n, block_n)
-    grid = (q, n // block_n)
+    nb = n // block_n
     with_resmask = resmask is not None and tol is not None
     in_specs = [pl.BlockSpec((1, v_r, block_n, length),
                              lambda qi, i: (qi, 0, i, 0)),
@@ -295,17 +291,21 @@ def sinkhorn_fused_all_batched(g: jax.Array, val: jax.Array, r: jax.Array,
                 pl.BlockSpec((1, v_r, 1), lambda qi, i: (qi, 0, 0))]
     args = [g, val, r.reshape(q, v_r, 1)]
     if with_resmask:
-        in_specs.append(pl.BlockSpec((1, block_n), lambda qi, i: (qi, i)))
-        args.append(jnp.asarray(resmask, g.dtype))
-    return pl.pallas_call(
-        functools.partial(_fused_batched_kernel, n_iter=n_iter, lam=lam,
+        in_specs.append(pl.BlockSpec((1, block_n, 1),
+                                     lambda qi, i: (qi, i, 0)))
+        args.append(jnp.asarray(resmask, g.dtype).reshape(q, n, 1))
+    wmd, iters = pl.pallas_call(
+        functools.partial(_fused_kernel, n_iter=n_iter, lam=lam,
                           tol=tol, check_every=check_every, gemm=gemm,
                           log_domain=log_domain, with_resmask=with_resmask),
-        grid=grid,
+        grid=(q, nb),
         in_specs=in_specs,
-        out_specs=[pl.BlockSpec((1, block_n), lambda qi, i: (qi, i)),
-                   pl.BlockSpec((1, 1), lambda qi, i: (qi, i))],
-        out_shape=[jax.ShapeDtypeStruct((q, n), g.dtype),
-                   jax.ShapeDtypeStruct((q, n // block_n), jnp.int32)],
+        out_specs=[pl.BlockSpec((1, 1, block_n), lambda qi, i: (qi, 0, i)),
+                   pl.BlockSpec((1, 1, 1, 1), lambda qi, i: (qi, i, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((q, 1, n), g.dtype),
+                   jax.ShapeDtypeStruct((q, nb, 1, 1), jnp.int32)],
+        compiler_params=_compiler_params(
+            v_r * block_n * length * g.dtype.itemsize),
         interpret=interpret,
     )(*args)
+    return wmd.reshape(q, n), iters.reshape(q, nb)

@@ -11,7 +11,10 @@ cell in a SUBPROCESS (fresh XLA state; a failing cell doesn't kill the
 sweep). See EXPERIMENTS.md §Dry-run.
 """
 # The 512 placeholder devices MUST be configured before any jax import.
+# They are CPU devices: the dry-run never claims an accelerator, and the
+# ``--all`` sweep's child processes inherit both settings.
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 import argparse

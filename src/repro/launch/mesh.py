@@ -12,19 +12,31 @@ the DCN-friendly layout (optionally int8-compressed, runtime/compression).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(axis_shapes, axis_names, devices=None):
+    """``jax.make_mesh`` with Auto axes. JAX 0.9 makes Explicit axes the
+    default, under which every jit over the mesh must run inside
+    ``jax.set_mesh`` and ops such as ``jnp.repeat`` need an explicit
+    ``out_sharding``; this code base shards through ``shard_map`` and
+    ``NamedSharding`` and lets the compiler propagate the rest."""
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_dev_mesh(n_devices: int | None = None, tp: int = 1):
     """Small mesh for tests/examples on whatever devices exist."""
     n = n_devices or len(jax.devices())
     assert n % tp == 0
-    return jax.make_mesh((n // tp, tp), ("data", "model"))
+    return make_mesh((n // tp, tp), ("data", "model"))
 
 
 # TPU runtime flags the real launch would set (documented here; no-ops on

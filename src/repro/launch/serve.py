@@ -447,6 +447,8 @@ def main() -> None:
     ap.add_argument("--lam", type=float, default=1.0)
     ap.add_argument("--n-iter", type=int, default=15)
     args = ap.parse_args()
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.shards > 1:
         # must run before make_corpus/engine build does the first jax
         # array op — forcing host devices after backend init is a no-op

@@ -129,7 +129,7 @@ def moe_apply_ep(p: Params, x: jax.Array, top_k: int,
       - combine with ONE psum of (n_loc, d) over the model axis — the same
         collective a dense TP layer pays. No global cumsum, no buffer AR.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     b, t, d = x.shape

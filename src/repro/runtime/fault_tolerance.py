@@ -337,6 +337,7 @@ def elastic_mesh(n_devices: int, model_parallel: int = 16,
     Keeps TP fixed (=16: weights are sharded that way and resharding TP is
     the expensive path) and absorbs device loss in the data/pod axes —
     standard elastic-DP. n_devices must be a multiple of model_parallel."""
+    from repro.launch.mesh import make_mesh
     if n_devices % model_parallel:
         raise ValueError(f"{n_devices} devices not divisible by "
                          f"TP={model_parallel}")
@@ -344,9 +345,9 @@ def elastic_mesh(n_devices: int, model_parallel: int = 16,
     if n_devices > pod_size and rest % (pod_size // model_parallel) == 0:
         pods = n_devices // pod_size
         data = pod_size // model_parallel
-        return jax.make_mesh((pods, data, model_parallel),
-                             ("pod", "data", "model"))
-    return jax.make_mesh((rest, model_parallel), ("data", "model"))
+        return make_mesh((pods, data, model_parallel),
+                         ("pod", "data", "model"))
+    return make_mesh((rest, model_parallel), ("data", "model"))
 
 
 def scaled_global_batch(base_batch: int, base_hosts: int,

@@ -198,31 +198,37 @@ def shardings(mesh: Mesh, tree_of_specs) -> Any:
 
 # ------------------------------------------------------- corpus doc sharding
 def ensure_host_devices(n: int) -> int:
-    """Make at least ``n`` devices visible, forcing host-platform CPU
-    devices when no real accelerators exist.
+    """Make at least ``n`` devices visible.
 
-    XLA only honors ``--xla_force_host_platform_device_count`` if it is
-    set BEFORE the backend initializes, so this merges the flag into
-    ``XLA_FLAGS`` and then touches ``jax.devices()``; call it before the
-    first jax array operation (``launch/serve.py --shards N`` and
-    ``examples/wmd_search.py --shards N`` do). Raises if the backend was
-    already initialized with too few devices — the flag cannot apply
-    retroactively. Returns the visible device count.
+    Under ``JAX_PLATFORMS=cpu`` this forces ``n`` host-platform CPU
+    devices: XLA only honors ``--xla_force_host_platform_device_count``
+    if it is set BEFORE the backend initializes, so this merges the flag
+    into ``XLA_FLAGS`` and then touches ``jax.devices()``; call it before
+    the first jax array operation (``launch/serve.py --shards N`` and
+    ``examples/wmd_search.py --shards N`` do). On any other platform it
+    forces nothing: the real devices must number at least ``n``.
+    Raises if too few devices are visible — on the CPU because the
+    backend was already initialized (the flag cannot apply
+    retroactively). Returns the visible device count.
     """
     import os
 
     n = int(n)
+    host_only = (jax.config.jax_platforms or "") == "cpu"
     flags = os.environ.get("XLA_FLAGS", "")
-    if n > 1 and "xla_force_host_platform_device_count" not in flags:
+    if host_only and n > 1 \
+            and "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (
             f"{flags} --xla_force_host_platform_device_count={n}".strip())
     count = jax.device_count()
     if count < n:
+        hint = (f"set XLA_FLAGS=--xla_force_host_platform_device_count={n} "
+                f"in the environment before the process does any jax work"
+                if host_only else
+                "fake host devices are only made under JAX_PLATFORMS=cpu")
         raise RuntimeError(
-            f"need {n} devices but the jax backend initialized with "
-            f"{count}; set XLA_FLAGS=--xla_force_host_platform_device_"
-            f"count={n} in the environment before the process does any "
-            f"jax work")
+            f"need {n} devices but the {jax.default_backend()} backend "
+            f"has {count}; {hint}")
     return count
 
 

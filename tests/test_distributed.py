@@ -14,6 +14,7 @@ SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import numpy as np, jax, jax.numpy as jnp
+    from repro.launch.mesh import make_mesh
     from repro.data.corpus import make_corpus, shard_balanced
     from repro.core import one_to_many, select_support
     from repro.core.sparse import padded_docs_to_dense
@@ -30,7 +31,7 @@ SCRIPT = textwrap.dedent("""
 
     for shape, names in (((2, 4), ("data", "model")),
                          ((2, 2, 2), ("pod", "data", "model"))):
-        mesh = jax.make_mesh(shape, names)
+        mesh = make_mesh(shape, names)
         cd = jnp.asarray(padded_docs_to_dense(c.docs, 512))
         dd = np.asarray(sinkhorn_wmd_dense_distributed(
             r, vs, jnp.asarray(c.vecs), cd, 8.0, 40, mesh))
@@ -43,7 +44,7 @@ SCRIPT = textwrap.dedent("""
 
     # nnz-balanced sharding preserves the distance multiset
     sb = shard_balanced(c.docs, 8)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     db = np.asarray(sinkhorn_wmd_sparse_distributed(
         r, vs, jnp.asarray(c.vecs), sb, 8.0, 40, mesh,
         vshard_precompute=True))

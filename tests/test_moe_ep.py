@@ -19,11 +19,10 @@ SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import numpy as np
     import jax, jax.numpy as jnp
+    from repro.launch.mesh import make_mesh
     from repro.models.moe import init_moe, moe_apply, moe_apply_ep
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
-    # jax >= 0.5 global-mesh API; older jax relies on the `with mesh:` below
-    getattr(jax, "set_mesh", lambda m: None)(mesh)
+    mesh = make_mesh((2, 4), ("data", "model"))
     e, d, ff, k = 8, 32, 16, 2
     p = init_moe(jax.random.PRNGKey(0), d, ff, e, 1, k, tp=4)
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 64, d)) * 0.5
