@@ -26,7 +26,7 @@ WORKER = textwrap.dedent("""
     from repro.data.corpus import make_corpus, shard_balanced
     from repro.core import select_support
     from repro.core.distributed import sinkhorn_wmd_sparse_distributed
-    from repro.launch.mesh import make_mesh
+    from repro.runtime.sharding import make_mesh
     c = make_corpus(vocab_size=8192, embed_dim=64, n_docs=1024, n_queries=1,
                     seed=0, words_per_doc=(19, 43))
     q = c.queries[0]

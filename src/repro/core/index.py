@@ -100,7 +100,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .sinkhorn import FP32_GEMM, LamUnderflowError, underflow_report
+from .sinkhorn import LamUnderflowError, sq_dists, underflow_report
 from .sinkhorn_sparse import (SolvePrecision, adaptive_loop,
                               adaptive_loop_scoped, marginal_residual,
                               marginal_residual_per_query)
@@ -155,10 +155,7 @@ class IvfClusters(NamedTuple):
 @jax.jit
 def _assign_clusters(points: jax.Array, centers: jax.Array) -> jax.Array:
     """Nearest-center assignment for one mini-batch: (B, w) -> (B,)."""
-    d2 = (jnp.sum(points * points, axis=1)[:, None]
-          + jnp.sum(centers * centers, axis=1)[None, :]
-          - 2.0 * jnp.matmul(points, centers.T, precision=FP32_GEMM))
-    return jnp.argmin(d2, axis=1)
+    return jnp.argmin(sq_dists(points, centers), axis=1)
 
 
 @jax.jit
@@ -231,10 +228,7 @@ def _pivot_dists(points: jax.Array, pivots: jax.Array) -> jax.Array:
     """(M, w) points x (P, w) pivots -> (M, P) Euclidean distances — the
     precomputed corpus half (and the per-chunk query half) of the pivot
     triangle prestage ``|d(q, p) - d(n, p)| <= ||qcent - centroid_n||``."""
-    a2 = jnp.sum(points * points, axis=1)[:, None]
-    b2 = jnp.sum(pivots * pivots, axis=1)[None, :]
-    d2 = a2 + b2 - 2.0 * jnp.matmul(points, pivots.T, precision=FP32_GEMM)
-    return jnp.sqrt(jnp.maximum(d2, 0.0))
+    return jnp.sqrt(jnp.maximum(sq_dists(points, pivots), 0.0))
 
 
 def _select_pivots(vecs: jax.Array, n_pivots: int, seed: int = 0,
@@ -1075,17 +1069,10 @@ def _compute_kq(sup: jax.Array, mask: jax.Array, vecs: jax.Array,
     no K column can underflow at any lam.
     """
     q, b = sup.shape
-    a = jnp.take(vecs, sup, axis=0)                     # (Q, B, w)
-    a2 = jnp.sum(a * a, axis=-1)                        # (Q, B)
-    if gemm == "bf16":
-        ab = jnp.matmul(vecs.astype(jnp.bfloat16),
-                        a.reshape(q * b, -1).T.astype(jnp.bfloat16),
-                        preferred_element_type=jnp.float32)
-    else:
-        ab = jnp.matmul(vecs, a.reshape(q * b, -1).T,
-                        precision=FP32_GEMM)            # (V, Q*B)
-    d2 = jnp.maximum(vecs_sq[:, None] + a2.reshape(1, -1) - 2.0 * ab, 0.0)
-    m = jnp.sqrt(d2)
+    a = jnp.take(vecs, sup.reshape(-1), axis=0)         # (Q*B, w)
+    d2 = sq_dists(vecs, a, vecs_sq, gemm_dtype=(
+        jnp.bfloat16 if gemm == "bf16" else None))      # (V, Q*B)
+    m = jnp.sqrt(jnp.maximum(d2, 0.0))
     if log_domain:
         kt = jnp.where(mask.reshape(1, -1) > 0, -lam * m, -jnp.inf)
     else:

@@ -54,7 +54,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .sinkhorn import FP32_GEMM
+from .sinkhorn import sq_dists
 
 
 def _pow2(n: int, floor: int = 8) -> int:
@@ -75,15 +75,9 @@ def _cdist_rows(ids: jax.Array, vecs: jax.Array, vecs_sq: jax.Array,
     stacked chunk GEMM would have produced for that (word, vocab) pair.
     """
     a = jnp.take(vecs, ids, axis=0)                       # (U, w)
-    a2 = jnp.sum(a * a, axis=-1)                          # (U,)
-    if gemm == "bf16":
-        ab = jnp.matmul(vecs.astype(jnp.bfloat16),
-                        a.T.astype(jnp.bfloat16),
-                        preferred_element_type=jnp.float32)
-    else:
-        ab = jnp.matmul(vecs, a.T, precision=FP32_GEMM)   # (V, U)
-    d2 = jnp.maximum(vecs_sq[:, None] + a2[None, :] - 2.0 * ab, 0.0)
-    return jnp.sqrt(d2).T                                 # (U, V)
+    d2 = sq_dists(vecs, a, vecs_sq, gemm_dtype=(
+        jnp.bfloat16 if gemm == "bf16" else None))        # (V, U)
+    return jnp.sqrt(jnp.maximum(d2, 0.0)).T               # (U, V)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))

@@ -72,7 +72,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .sinkhorn import FP32_GEMM
+from .sinkhorn import sq_dists
 
 
 @runtime_checkable
@@ -94,10 +94,7 @@ class Pruner(Protocol):
 
 @jax.jit
 def _wcd_bounds(qcent: jax.Array, centroids: jax.Array) -> jax.Array:
-    a2 = jnp.sum(qcent * qcent, axis=1)[:, None]
-    b2 = jnp.sum(centroids * centroids, axis=1)[None, :]
-    d2 = a2 + b2 - 2.0 * jnp.matmul(qcent, centroids.T, precision=FP32_GEMM)
-    return jnp.sqrt(jnp.maximum(d2, 0.0))
+    return jnp.sqrt(jnp.maximum(sq_dists(qcent, centroids), 0.0))
 
 
 @jax.jit
@@ -205,9 +202,7 @@ def _wcd_stage(qcent, centroids, ids_pad, qmask):
     gather candidate centroids -> cdist vs the (probe-computed) query
     centroids -> mask."""
     cand = jnp.take(centroids, ids_pad, axis=0)          # (Sp, w)
-    a2 = jnp.sum(qcent * qcent, axis=1)[:, None]
-    b2 = jnp.sum(cand * cand, axis=1)[None, :]
-    d2 = a2 + b2 - 2.0 * jnp.matmul(qcent, cand.T, precision=FP32_GEMM)
+    d2 = sq_dists(qcent, cand)
     return jnp.where(qmask, jnp.sqrt(jnp.maximum(d2, 0.0)), jnp.inf)
 
 
@@ -217,11 +212,7 @@ def _wcd_dense_keep_all(qcent, centroids, thresh):
     candidate of every query, so the doc -> probed-cluster lookup drops
     out of the dispatch entirely."""
     qc = thresh.shape[0]
-    q = qcent[:qc]
-    a2 = jnp.sum(q * q, axis=1)[:, None]
-    b2 = jnp.sum(centroids * centroids, axis=1)[None, :]
-    d2 = jnp.maximum(
-        a2 + b2 - 2.0 * jnp.matmul(q, centroids.T, precision=FP32_GEMM), 0.0)
+    d2 = jnp.maximum(sq_dists(qcent[:qc], centroids), 0.0)
     return jnp.any(d2 <= jnp.square(thresh)[:, None], axis=0)
 
 
@@ -236,11 +227,7 @@ def _wcd_dense_keep(qcent, centroids, pm, assign, thresh):
     mass (a (Q, N) GEMM beats gather + mask dispatch chains once most docs
     survive the cluster filter)."""
     qc = thresh.shape[0]
-    q = qcent[:qc]
-    a2 = jnp.sum(q * q, axis=1)[:, None]
-    b2 = jnp.sum(centroids * centroids, axis=1)[None, :]
-    d2 = jnp.maximum(
-        a2 + b2 - 2.0 * jnp.matmul(q, centroids.T, precision=FP32_GEMM), 0.0)
+    d2 = jnp.maximum(sq_dists(qcent[:qc], centroids), 0.0)
     cand = jnp.take(pm[:qc], assign, axis=1)             # (qc, N) candidacy
     return jnp.any(cand & (d2 <= jnp.square(thresh)[:, None]), axis=0)
 
@@ -336,10 +323,7 @@ def _probe_dists(sup, r, mask, vecs, centers):
     """Query centroids + cluster-center distances, one dispatch:
     (cdists (Qp, C), qcent (Qp, w) — reused by the dense WCD pass)."""
     qcent = jnp.einsum("qb,qbw->qw", r * mask, jnp.take(vecs, sup, axis=0))
-    a2 = jnp.sum(qcent * qcent, axis=1)[:, None]
-    b2 = jnp.sum(centers * centers, axis=1)[None, :]
-    d2 = a2 + b2 - 2.0 * jnp.matmul(qcent, centers.T, precision=FP32_GEMM)
-    return jnp.sqrt(jnp.maximum(d2, 0.0)), qcent
+    return jnp.sqrt(jnp.maximum(sq_dists(qcent, centers), 0.0)), qcent
 
 
 @functools.partial(jax.jit, static_argnames=("nprobe",))

@@ -26,17 +26,13 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.kernels.precision import FP32_GEMM
+
 
 # ln(fp32 min normal) ~ -87.3: exp(-x) flushes to exactly 0 beyond this,
 # and an all-zero gathered K column turns the Sinkhorn 1/(K^T u) line into
 # inf/NaN for every document containing that word.
 MAX_NEG_EXP = 87.0
-
-# Precision of every fp32 GEMM in the cdist form |a|^2 + |b|^2 - 2 a.b. A
-# TPU's default matmul precision rounds fp32 operands to bf16, and the form
-# cancels: a word's distance to itself is no longer ~0, and on a v5e at the
-# paper's corpus size (w=300) the WMDs moved by up to 1.4e-2 relative.
-FP32_GEMM = lax.Precision.HIGHEST
 
 
 class LamUnderflowError(FloatingPointError):
@@ -83,6 +79,27 @@ def underflow_report(lam: float, vecs_sel, vecs, docs) -> str:
     )
 
 
+def sq_dists(a: jax.Array, b: jax.Array, a2=None, b2=None,
+             gemm_dtype=None) -> jax.Array:
+    """Squared distances in GEMM form: (m, w) x (n, w) -> (m, n) of
+    ``|a_i|^2 + |b_j|^2 - 2 a_i.b_j``, unclamped (the cancellation can
+    leave small negatives). ``a2``/``b2`` are the squared norms when the
+    caller already holds them. Every cdist GEMM goes through here, so
+    this is where :data:`FP32_GEMM` applies; ``gemm_dtype`` (e.g.
+    ``jnp.bfloat16``) instead casts only the operands, accumulating in
+    fp32."""
+    if a2 is None:
+        a2 = jnp.sum(a * a, axis=-1)
+    if b2 is None:
+        b2 = jnp.sum(b * b, axis=-1)
+    if gemm_dtype is None:
+        ab = jnp.matmul(a, b.T, precision=FP32_GEMM)
+    else:
+        ab = jnp.matmul(a.astype(gemm_dtype), b.astype(gemm_dtype).T,
+                        preferred_element_type=jnp.float32)
+    return a2[:, None] + b2[None, :] - 2.0 * ab
+
+
 def cdist(a: jax.Array, b: jax.Array, gemm_dtype=None) -> jax.Array:
     """Pairwise Euclidean distance, GEMM-shaped (paper §6).
 
@@ -95,15 +112,7 @@ def cdist(a: jax.Array, b: jax.Array, gemm_dtype=None) -> jax.Array:
     the accumulation and the rank-1 norms stay fp32 (the
     :class:`~repro.core.sinkhorn_sparse.SolvePrecision` bf16 policy).
     """
-    a2 = jnp.sum(a * a, axis=-1)[:, None]
-    b2 = jnp.sum(b * b, axis=-1)[None, :]
-    if gemm_dtype is None:
-        ab = jnp.matmul(a, b.T, precision=FP32_GEMM)
-    else:
-        ab = jnp.matmul(a.astype(gemm_dtype), b.astype(gemm_dtype).T,
-                        preferred_element_type=jnp.float32)
-    d2 = jnp.maximum(a2 + b2 - 2.0 * ab, 0.0)
-    return jnp.sqrt(d2)
+    return jnp.sqrt(jnp.maximum(sq_dists(a, b, gemm_dtype=gemm_dtype), 0.0))
 
 
 class SinkhornPrecompute(NamedTuple):

@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .precision import FP32_GEMM
+
 
 def _kernel(a_ref, b_ref, r_ref, *out_refs, lam: float, k_only: bool,
             gemm: str, log_k: bool):
@@ -36,7 +38,7 @@ def _kernel(a_ref, b_ref, r_ref, *out_refs, lam: float, k_only: bool,
                                  preferred_element_type=jnp.float32)
     else:
         ab = jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
-                                 precision=jax.lax.Precision.HIGHEST,
+                                 precision=FP32_GEMM,
                                  preferred_element_type=jnp.float32)  # MXU
     a2 = jnp.sum(a * a, axis=1, keepdims=True)        # (v_r, 1)
     b2 = jnp.sum(b * b, axis=1)[None, :]              # (1, bv)

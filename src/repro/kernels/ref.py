@@ -4,16 +4,14 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-# full-fp32 products, as in the engine's cdist GEMMs (the bf16 rounding a
-# TPU applies by default breaks the |a|^2 + |b|^2 - 2 a.b cancellation)
-HIGHEST = jax.lax.Precision.HIGHEST
+from .precision import FP32_GEMM
 
 
 def cdist_exp_ref(a, b, r, lam: float):
     """Oracle for kernels.cdist_exp: (M, K, K_over_r)."""
     a2 = jnp.sum(a * a, axis=1)[:, None]
     b2 = jnp.sum(b * b, axis=1)[None, :]
-    ab = jnp.matmul(a, b.T, precision=HIGHEST)
+    ab = jnp.matmul(a, b.T, precision=FP32_GEMM)
     d2 = jnp.maximum(a2 + b2 - 2.0 * ab, 0.0)
     m = jnp.sqrt(d2)
     k = jnp.exp(-lam * m)
@@ -25,7 +23,7 @@ def rwmd_min_cdist_ref(a, mask, b):
     distances. a (Q, B, w), mask (Q, B), b (V, w) -> (Q, V)."""
     a2 = jnp.sum(a * a, axis=-1)[:, :, None]
     b2 = jnp.sum(b * b, axis=-1)[None, None, :]
-    ab = jnp.einsum("qbw,vw->qbv", a, b, precision=HIGHEST)
+    ab = jnp.einsum("qbw,vw->qbv", a, b, precision=FP32_GEMM)
     d = jnp.sqrt(jnp.maximum(a2 + b2 - 2.0 * ab, 0.0))
     return jnp.min(jnp.where(mask[:, :, None] > 0, d, jnp.inf), axis=1)
 

@@ -36,13 +36,15 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .precision import FP32_GEMM
+
 
 def _kernel(a_ref, mask_ref, b_ref, out_ref):
     a = a_ref[0]                          # (B, w)   this query's support
     mask = mask_ref[0]                    # (B, 1)
     b = b_ref[...]                        # (bv, w)  streamed vocab tile
     ab = jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
-                             precision=jax.lax.Precision.HIGHEST,
+                             precision=FP32_GEMM,
                              preferred_element_type=jnp.float32)  # MXU
     a2 = jnp.sum(a * a, axis=1, keepdims=True)       # (B, 1)
     b2 = jnp.sum(b * b, axis=1)[None, :]             # (1, bv)

@@ -12,18 +12,8 @@ the DCN-friendly layout (optionally int8-compressed, runtime/compression).
 from __future__ import annotations
 
 import jax
-from jax.sharding import AxisType
 
-
-def make_mesh(axis_shapes, axis_names, devices=None):
-    """``jax.make_mesh`` with Auto axes. JAX 0.9 makes Explicit axes the
-    default, under which every jit over the mesh must run inside
-    ``jax.set_mesh`` and ops such as ``jnp.repeat`` need an explicit
-    ``out_sharding``; this code base shards through ``shard_map`` and
-    ``NamedSharding`` and lets the compiler propagate the rest."""
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
-                         axis_types=(AxisType.Auto,) * len(axis_names),
-                         devices=devices)
+from repro.runtime.sharding import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -51,6 +51,8 @@ import numpy as np
 
 import jax
 
+from repro.runtime.sharding import make_mesh
+
 
 class PoisonStep(Exception):
     """Deterministic failure (NaN loss, assertion) — do NOT retry."""
@@ -337,7 +339,6 @@ def elastic_mesh(n_devices: int, model_parallel: int = 16,
     Keeps TP fixed (=16: weights are sharded that way and resharding TP is
     the expensive path) and absorbs device loss in the data/pod axes —
     standard elastic-DP. n_devices must be a multiple of model_parallel."""
-    from repro.launch.mesh import make_mesh
     if n_devices % model_parallel:
         raise ValueError(f"{n_devices} devices not divisible by "
                          f"TP={model_parallel}")

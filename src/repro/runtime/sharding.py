@@ -15,7 +15,7 @@ import re
 from typing import Any
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 # (regex over 'a/b/c' path, spec builder(ndim) -> PartitionSpec)
 # Specs are written for the LAST dims; leading stacked layer/group dims are
@@ -56,6 +56,17 @@ _RULES: list[tuple[str, tuple]] = [
     # norms & everything small
     (r".*",                        ()),
 ]
+
+
+def make_mesh(axis_shapes, axis_names, devices=None) -> Mesh:
+    """``jax.make_mesh`` with Auto axes. JAX 0.9 makes Explicit axes the
+    default, under which every jit over the mesh must run inside
+    ``jax.set_mesh`` and ops such as ``jnp.repeat`` need an explicit
+    ``out_sharding``; this code base shards through ``shard_map`` and
+    ``NamedSharding`` and lets the compiler propagate the rest."""
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)
 
 
 def _path_str(path) -> str:

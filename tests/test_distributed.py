@@ -14,7 +14,7 @@ SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import numpy as np, jax, jax.numpy as jnp
-    from repro.launch.mesh import make_mesh
+    from repro.runtime.sharding import make_mesh
     from repro.data.corpus import make_corpus, shard_balanced
     from repro.core import one_to_many, select_support
     from repro.core.sparse import padded_docs_to_dense

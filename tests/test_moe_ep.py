@@ -19,7 +19,7 @@ SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import numpy as np
     import jax, jax.numpy as jnp
-    from repro.launch.mesh import make_mesh
+    from repro.runtime.sharding import make_mesh
     from repro.models.moe import init_moe, moe_apply, moe_apply_ep
 
     mesh = make_mesh((2, 4), ("data", "model"))
