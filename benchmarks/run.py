@@ -15,7 +15,7 @@ import traceback
 from . import (fig5_scaling, fig6_multi_query, fig7_cdist, fig8_topk_prune,
                fig9_ivf_prune, fig10_solve_adaptive, fig11_sharded,
                fig12_serving, fig13_pareto, fig14_shard_chaos, fig15_kcache,
-               moe_router, python_baseline, roofline, table1_profile)
+               moe_router, python_baseline, table1_profile)
 
 MODULES = [
     ("table1_profile", table1_profile),
@@ -32,7 +32,6 @@ MODULES = [
     ("fig14_shard_chaos", fig14_shard_chaos),
     ("fig15_kcache", fig15_kcache),
     ("moe_router", moe_router),
-    ("roofline", roofline),
 ]
 
 

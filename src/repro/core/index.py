@@ -1270,6 +1270,8 @@ class WmdEngine:
         self.kcache_min_hits = max(1, int(kcache_min_hits))
         if kcache_slots:
             self.enable_kcache(int(kcache_slots))
+        self._dispatches = 0
+        self._host_syncs = 0
 
     # ------------------------------------------------- cross-request cache
     def enable_kcache(self, slots: int) -> bool:
@@ -1294,6 +1296,21 @@ class WmdEngine:
     def reset_kcache_stats(self) -> None:
         if self._kcache is not None:
             self._kcache.reset_counters()
+
+    # ------------------------------------------------------- host counters
+    def host_stats(self) -> dict:
+        """Host-side counters since the last :meth:`reset_host_stats`:
+        ``dispatches``, the device layer calls the engine makes (one per
+        K block in ``_kq``, one per gather and one per solve in
+        ``_solve_group``), and ``host_syncs``, the blocking result reads
+        of :meth:`query_batch` (one per chunk and doc group). Counting
+        adds no device work and no sync."""
+        return {"dispatches": self._dispatches,
+                "host_syncs": self._host_syncs}
+
+    def reset_host_stats(self) -> None:
+        self._dispatches = 0
+        self._host_syncs = 0
 
     # -------------------------------------------------- realized iterations
     def reset_iter_stats(self) -> None:
@@ -1370,6 +1387,7 @@ class WmdEngine:
         return self.query_batch([r_full])[0]
 
     # ------------------------------------------------------------ staging
+    @functools.partial(jax.profiler.annotate_function, name="wmd.plan")
     def _plan(self, queries: list):
         """Bucket + chunk the query set: [(input positions, width), ...].
 
@@ -1396,6 +1414,7 @@ class WmdEngine:
                 chunks.append((chunk, width))
         return vr, chunks
 
+    @functools.partial(jax.profiler.annotate_function, name="wmd.stage")
     def _prep_chunk(self, chunk_queries: list, width: int):
         """Stage one chunk: (sup, r, mask) device arrays, q-padded to a
         power of two with inert fillers (no support -> G rows all 0, r == 1)
@@ -1415,6 +1434,7 @@ class WmdEngine:
                 jnp.asarray(np.stack([p[1] for p in prepared])),
                 jnp.asarray(np.stack([p[2] for p in prepared])))
 
+    @functools.partial(jax.profiler.annotate_function, name="wmd.dispatch")
     def _solve_group(self, kq, r, mask, grp: DocGroup, n_live=None,
                      stage: str = "batch", qdoc_mask=None, x0q=None,
                      want_profile: bool = False, prof_mask=None):
@@ -1434,6 +1454,7 @@ class WmdEngine:
         kqk, mq = kq
         layout = "qbnl" if self.impl == "kernel" else "qnlb"
         g = _gather_g(kqk, grp.docs.idx, layout=layout)
+        self._dispatches += 2           # the gather and the solve below
         scoped = self.tol is not None and self.scope == "query"
         if self.impl == "kernel":
             from repro.kernels.ops import sinkhorn_fused_all_batched
@@ -1464,6 +1485,7 @@ class WmdEngine:
             return wmd, out[2]
         return wmd
 
+    @functools.partial(jax.profiler.annotate_function, name="wmd.dispatch")
     def _kq(self, sup, mask):
         """(kq, mq) for one staged chunk — treat as an opaque pair; the
         solve stage consumes both (kernel gather + distance epilogue).
@@ -1477,6 +1499,7 @@ class WmdEngine:
         cheaper on CPU (dispatch economy — see the ROADMAP refusion
         note) and its ``mq`` block warms the cache for the next request.
         Both paths produce BIT-IDENTICAL pairs (``core/kcache.py``)."""
+        self._dispatches += 1           # one K block program, either path
         if self.impl == "kernel":
             kq = _compute_kq(sup, mask, self.index.vecs,
                              self.index.vecs_sq, self.lam,
@@ -1528,6 +1551,8 @@ class WmdEngine:
                 self.lam, vecs_sel, self.index.vecs, self.index.docs))
 
     # ----------------------------------------------------------- scoring
+    @functools.partial(jax.profiler.annotate_function,
+                       name="wmd.query_batch")
     def query_batch(self, queries: Sequence) -> jax.Array:
         """Exhaustive WMD for Q queries (full-vocab histogram rows) ->
         (Q, N). Row order matches the input; a query with no support yields
@@ -1556,12 +1581,16 @@ class WmdEngine:
                 out[qi] = np.nan
         for chunk, parts in pending:
             for grp, wmd_g in parts:
-                w = np.asarray(wmd_g)[:len(chunk)]
-                self._raise_if_nan(w, [queries[qi] for qi in chunk])
-                # group cols are STORAGE ids (cluster-major); scatter into
-                # the caller's doc order at this output boundary
-                out[np.ix_(chunk, self._ext(grp.cols))] = w
-        return jnp.asarray(out)
+                with jax.profiler.TraceAnnotation("wmd.collect"):
+                    w = np.asarray(wmd_g)[:len(chunk)]
+                self._host_syncs += 1
+                with jax.profiler.TraceAnnotation("wmd.scatter"):
+                    self._raise_if_nan(w, [queries[qi] for qi in chunk])
+                    # group cols are STORAGE ids (cluster-major); scatter
+                    # into the caller's doc order at this output boundary
+                    out[np.ix_(chunk, self._ext(grp.cols))] = w
+        with jax.profiler.TraceAnnotation("wmd.return"):
+            return jnp.asarray(out)
 
     # ------------------------------------------------------------ search
     def search(self, queries: Sequence, k: int, prune: object = "rwmd",

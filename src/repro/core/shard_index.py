@@ -647,6 +647,15 @@ class ShardedWmdEngine:
         for e in self.engines:
             e.reset_kcache_stats()
 
+    def host_stats(self) -> dict:
+        """Shard-summed :meth:`WmdEngine.host_stats` counters."""
+        per = [e.host_stats() for e in self.engines]
+        return {k: sum(p[k] for p in per) for k in per[0]}
+
+    def reset_host_stats(self) -> None:
+        for e in self.engines:
+            e.reset_host_stats()
+
     # --------------------------------------------------------------- merge
     def _merge_fn(self, k: int):
         fn = self._merge_cache.get(k)

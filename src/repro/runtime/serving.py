@@ -995,6 +995,9 @@ class ServingRuntime:
         kc = getattr(self.engine, "kcache_stats", lambda: None)()
         if kc is not None:
             c["kcache"] = kc
+        host = getattr(self.engine, "host_stats", None)
+        if host is not None:
+            c["host"] = host()
         shards = getattr(self.engine, "n_shards", None)
         if shards:
             c["shards"] = int(shards)
