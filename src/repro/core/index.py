@@ -1083,19 +1083,34 @@ def _compute_kq(sup: jax.Array, mask: jax.Array, vecs: jax.Array,
     return kq, jnp.transpose(m.reshape(-1, q, b), (1, 0, 2))
 
 
-@functools.partial(jax.jit, static_argnames=("layout",))
-def _gather_g(kq: jax.Array, idx: jax.Array, layout: str = "qnlb"):
+@functools.partial(jax.jit, static_argnames=("layout", "block_n"))
+def _gather_g(kq: jax.Array, idx: jax.Array, layout: str = "qnlb",
+              block_n: int = 1):
     """Gather doc-word columns of K: (Q, V, B) x (N, L) -> G.
 
     Kept as its own jit (with :func:`_compute_kq` separate too): XLA CPU
     otherwise fuses the exp/sqrt producer INTO the gather and recomputes it
     per gathered element (~2.4x slower end to end); on TPU the boundary is
     where the engine hands off to the Mosaic kernel anyway.
+
+    ``layout="qlbn"`` is the resident solve's tile (Q, L, B, N_pad): the
+    docs padded to whole ``block_n`` blocks of word-0 columns (inert: the
+    solve gives them val 0) and on the minor axis, with the query words
+    second-minor — the order the TPU lays a K-row gather out in anyway,
+    so the kernel reads the tile as the gather writes it.
     """
     if layout == "qbnl":
         # TPU tile layout: (v_r, block_n, L) per query, sublane = query rows
         return jnp.take(jnp.transpose(kq, (0, 2, 1)), idx, axis=2)
+    if layout == "qlbn":
+        idx = jnp.pad(idx, ((0, -idx.shape[0] % block_n), (0, 0)))
+        return jnp.swapaxes(jnp.take(kq, idx.T, axis=1), 2, 3)
     return jnp.take(kq, idx, axis=1)                         # (Q, N, L, B)
+
+
+def _on_tpu(x: jax.Array) -> bool:
+    """Whether the device array ``x`` lives on a TPU."""
+    return all(d.platform == "tpu" for d in x.devices())
 
 
 _solve_gathered = jax.jit(_solve_batched_einsum,
@@ -1144,7 +1159,9 @@ class WmdEngine:
     ----------
     index:       corpus state from :func:`build_index` (reused across calls)
     lam, n_iter: Sinkhorn strength / iteration count (static per engine)
-    impl:        "sparse" (batched einsum) or "kernel" (batched Pallas)
+    impl:        "sparse" (batched einsum; on a TPU its fixed-iteration
+                 solves run the VMEM-resident Pallas kernel) or "kernel"
+                 (batched Pallas)
     min_bucket:  smallest v_r bucket; queries are padded up to powers of two
     max_batch:   per-solve query cap — larger buckets are chunked so the
                  (Q, B, N, L) gathered tile stays memory-bounded
@@ -1272,6 +1289,7 @@ class WmdEngine:
             self.enable_kcache(int(kcache_slots))
         self._dispatches = 0
         self._host_syncs = 0
+        self._resident_solves = 0
 
     # ------------------------------------------------- cross-request cache
     def enable_kcache(self, slots: int) -> bool:
@@ -1302,15 +1320,20 @@ class WmdEngine:
         """Host-side counters since the last :meth:`reset_host_stats`:
         ``dispatches``, the device layer calls the engine makes (one per
         K block in ``_kq``, one per gather and one per solve in
-        ``_solve_group``), and ``host_syncs``, the blocking result reads
-        of :meth:`query_batch` (one per chunk and doc group). Counting
-        adds no device work and no sync."""
+        ``_solve_group``), ``host_syncs``, the blocking result reads
+        of :meth:`query_batch` (one per chunk and doc group), and
+        ``resident_solves``, the ``_solve_group`` solves that ran the
+        VMEM-resident kernel rather than the einsum (see
+        :meth:`_solve_group`). Counting adds no device work and no
+        sync."""
         return {"dispatches": self._dispatches,
-                "host_syncs": self._host_syncs}
+                "host_syncs": self._host_syncs,
+                "resident_solves": self._resident_solves}
 
     def reset_host_stats(self) -> None:
         self._dispatches = 0
         self._host_syncs = 0
+        self._resident_solves = 0
 
     # -------------------------------------------------- realized iterations
     def reset_iter_stats(self) -> None:
@@ -1450,11 +1473,33 @@ class WmdEngine:
         the solve from a per-query profile; ``want_profile=True`` returns
         ``(wmd, profile)`` — the converged profile survivor solves reuse,
         averaged over ``prof_mask`` docs (``None`` on the kernel path,
-        which reconstructs GM in VMEM and does not expose x)."""
+        which reconstructs GM in VMEM and does not expose x).
+
+        On a TPU, a fixed-iteration solve (``tol`` unset) with no warm
+        start and no profile asked for runs the VMEM-resident kernel
+        (:func:`repro.kernels.sddmm_spmm.sinkhorn_resident`): each doc
+        block's gathered tile is read from HBM once, where the einsum
+        rereads it twice per iteration. Its distances are the einsum's
+        up to rounding. Every other solve, and every solve off a TPU,
+        runs the einsum."""
         kqk, mq = kq
+        resident = (self.impl == "sparse" and self.tol is None
+                    and x0q is None and not want_profile and _on_tpu(kqk))
+        self._dispatches += 2           # the gather and the solve below
+        if resident:
+            from repro.kernels.ops import sinkhorn_resident
+            g = _gather_g(kqk, grp.docs.idx, layout="qlbn",
+                          block_n=self.block_n)
+            wmd = sinkhorn_resident(
+                g, grp.docs.val, r, mask, self.lam, self.n_iter,
+                block_n=self.block_n, interpret=self.interpret,
+                gemm=self.precision.gemm,
+                log_domain=self.precision.log_domain)
+            self._resident_solves += 1
+            self._record_iters(stage, np.int32(self.n_iter), n_live)
+            return wmd
         layout = "qbnl" if self.impl == "kernel" else "qnlb"
         g = _gather_g(kqk, grp.docs.idx, layout=layout)
-        self._dispatches += 2           # the gather and the solve below
         scoped = self.tol is not None and self.scope == "query"
         if self.impl == "kernel":
             from repro.kernels.ops import sinkhorn_fused_all_batched
@@ -1464,7 +1509,8 @@ class WmdEngine:
                 tol=self.tol, check_every=self.check_every,
                 gemm=self.precision.gemm,
                 log_domain=self.precision.log_domain,
-                resmask=qdoc_mask if scoped else None, with_iters=True)
+                resmask=qdoc_mask if scoped else None, with_iters=True,
+                mask=mask)
             # per-block counts -> per-query realized iterations (a query's
             # slowest candidate block is when its columns actually froze)
             self._record_iters(stage,
@@ -1484,6 +1530,20 @@ class WmdEngine:
         if want_profile:
             return wmd, out[2]
         return wmd
+
+    @property
+    def _warms_survivors(self) -> bool:
+        """Whether :meth:`search`'s survivor solves warm-start from the
+        seed solve's profile (``warm_start`` with ``tol`` set)."""
+        return self.warm_start and self.tol is not None
+
+    def _solve_profiled(self, kq, r, mask, grp: DocGroup, **kw):
+        """:meth:`_solve_group` for :meth:`search`: ``(wmd, profile)``,
+        the profile asked for only where :attr:`_warms_survivors` and
+        ``None`` otherwise."""
+        if not self._warms_survivors:
+            return self._solve_group(kq, r, mask, grp, **kw), None
+        return self._solve_group(kq, r, mask, grp, want_profile=True, **kw)
 
     @functools.partial(jax.profiler.annotate_function, name="wmd.dispatch")
     def _kq(self, sup, mask):
@@ -1726,9 +1786,9 @@ class WmdEngine:
                     qmask, r.shape[0], n_pad))
                 pm = (None if prof is None else self._pad_qdoc(
                     prof, r.shape[0], n_pad))
-                w, prof_out = self._solve_group(
+                w, prof_out = self._solve_profiled(
                     kq, r, mask, grp, n_live=qc, stage=stage, qdoc_mask=qm,
-                    x0q=warm, want_profile=True, prof_mask=pm)
+                    x0q=warm, prof_mask=pm)
                 w = np.asarray(w)[:qc, :doc_ids.size]
                 self._raise_if_nan(w, cq)
                 return w, prof_out
@@ -1810,7 +1870,7 @@ class WmdEngine:
         if scoped:
             qmask_surv = (np.asarray(lb[:qc, surv])
                           <= np.asarray(thresh)[:qc, None])
-        warm = xprof if (self.warm_start and self.tol is not None) else None
+        warm = xprof if self._warms_survivors else None
         d_surv, _ = solve(surv, qmask_surv, "survivor", warm=warm)
         return cand, np.concatenate([d_seed, d_surv], axis=1)
 
@@ -1845,10 +1905,10 @@ class WmdEngine:
                     qmask[rows], r.shape[0], n_pad))
                 pm = (None if prof is None else self._pad_qdoc(
                     prof[rows], r.shape[0], n_pad))
-                w, xp = self._solve_group(
+                w, xp = self._solve_profiled(
                     kq, r, mask, grp, n_live=len(chunk), stage=stage,
                     qdoc_mask=qm, x0q=None if warm is None else warm[ci],
-                    want_profile=True, prof_mask=pm)
+                    prof_mask=pm)
                 profs.append(xp)
                 w = np.asarray(w)[:len(chunk), :doc_ids.size]
                 self._raise_if_nan(w, cq)
@@ -2011,8 +2071,7 @@ class WmdEngine:
                                     qp=sup_g.shape[0]), qcent=qcent)
                 qmask_surv = (np.asarray(lbs[:qg, :surv.size])
                               <= np.asarray(thresh)[:qg, None])
-            warm = (xprofs if (self.warm_start and self.tol is not None)
-                    else None)
+            warm = xprofs if self._warms_survivors else None
             d_surv, _ = solve_all(surv, qmask_surv, "survivor", warm=warm)
             d_cand = np.concatenate([d_seed, d_surv], axis=1)
         else:

@@ -141,10 +141,11 @@ def sinkhorn_fused_all_batched(g, val, r, lam: float, n_iter: int,
                                interpret: bool | None = None, tol=None,
                                check_every: int = 4, gemm: str = "fp32",
                                log_domain: bool = False, resmask=None,
-                               with_iters: bool = False):
+                               with_iters: bool = False, mask=None):
     """Batched fused solver with auto-padding. g (Q, v_r, N, L); val (N, L);
     r (Q, v_r) -> wmd (Q, N). Padded query rows carry r == 1, G == 0
-    (G == -inf under ``log_domain`` — see :func:`sinkhorn_fused_all`).
+    (G == -inf under ``log_domain`` — see :func:`sinkhorn_fused_all`) and
+    ``mask`` (Q, v_r) == 0 when given.
     ``with_iters=True`` also returns the (Q, N-blocks) realized iteration
     counts (per-block early exit under ``tol``). ``resmask`` (Q, N)
     scopes each query's exit test to its own candidate docs — each grid
@@ -159,11 +160,25 @@ def sinkhorn_fused_all_batched(g, val, r, lam: float, n_iter: int,
     rmp = None
     if resmask is not None:
         rmp = pad_to(jnp.asarray(resmask, gp.dtype), 1, block_n)
+    mp = None if mask is None else pad_to(jnp.asarray(mask, gp.dtype), 1, 8)
     wmd, iters = _sddmm_spmm.sinkhorn_fused_all_batched(
         gp, valp, rp, lam, n_iter, block_n=block_n, interpret=interpret,
         tol=tol, check_every=check_every, gemm=gemm, log_domain=log_domain,
-        resmask=rmp)
+        resmask=rmp, mask=mp)
     return (wmd[:, :n], iters) if with_iters else wmd[:, :n]
+
+
+def sinkhorn_resident(g, val, r, mask, lam: float, n_iter: int,
+                      block_n: int = 128, interpret: bool | None = None,
+                      gemm: str = "fp32", log_domain: bool = False):
+    """Resident fixed-iteration solve over the engine's (Q, L, B, N_pad)
+    gathered tile; val (N, L), r and mask (Q, B) -> wmd (Q, N). The
+    caller's gather already pads the docs to whole blocks, so nothing is
+    padded here."""
+    return _sddmm_spmm.sinkhorn_resident(
+        g, val, r, mask, lam, n_iter, block_n=block_n,
+        interpret=resolve_interpret(interpret), gemm=gemm,
+        log_domain=log_domain)
 
 
 @functools.partial(jax.jit, static_argnames=("lam", "n_iter", "interpret",

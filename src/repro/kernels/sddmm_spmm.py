@@ -27,16 +27,24 @@ Two kernels, in increasing fusion depth:
     footprint (G==0 pad entries are guarded to 0).
 
 ``sinkhorn_fused_all_batched``
-    The multi-query engine kernel (:mod:`repro.core.index`): identical
+    The multi-query kernel of the engine's ``impl="kernel"``: identical
     per-document schedule, with the grid extended by a leading query
     dimension. A bucket of Q shape-padded queries shares one ``val`` tile
     stream and one compiled executable, so per-query dispatch and
     recompilation cost is amortized across the batch.
 
+``sinkhorn_resident``
+    The same grid and the same solver body over the tile the engine's
+    default path gathers on a TPU: the fixed-iteration solve of
+    :class:`repro.core.index.WmdEngine` there.
+
 Layout note (paper: "data could be transposed on the fly to ensure
-unit-stride data accesses"): G is laid out (v_r, N, L) so both reductions —
-over k (sublane) for SDDMM and over l (lane) for SpMM — are unit-stride in
-VMEM; no transposes are materialized.
+unit-stride data accesses"): one body, :func:`_solve_block`, serves two
+tile orders (:class:`Layout`). ``sinkhorn_fused_all_batched`` takes
+(v_r, N, L) per query, the SDDMM's k-sum over the leading axis and the
+SpMM's l-sum over the lanes; ``sinkhorn_resident`` takes (L, v_r, N),
+the docs on the lanes, the k-sum over the sublanes and the l-sum over
+the leading axis. No transposes are materialized in either.
 
 Padding contract (see ops.py): padded query rows carry G == 0 and padded
 doc slots carry val == 0; the ``where`` guards make both inert, so kernel
@@ -45,6 +53,8 @@ results on padded problems equal the unpadded oracle exactly.
 from __future__ import annotations
 
 import functools
+import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -95,32 +105,51 @@ def sddmm_spmm_step(g: jax.Array, g_over_r: jax.Array, val: jax.Array,
     )(g, g_over_r, val, x)
 
 
-def _sddmm_with(g, u):
-    """t[j, l] = sum_k g[k, j, l] u[k, j]: (v_r, bn, L) x (v_r, bn)."""
-    return jnp.sum(g * u[:, :, None], axis=0)
+class Layout(NamedTuple):
+    """Axis order of one query's G tile in VMEM: ``k`` is the query-word
+    axis, ``l`` the doc-word axis, and the remaining axis holds the docs.
+
+    Doc vectors keep the tile's rank, so every broadcast and reduction is
+    along one axis of the tile: x-shaped values (x, u, r) are the tile
+    summed over ``l`` with the axis kept, t-shaped ones (t, w, val, the
+    residual mask) the tile summed over ``k``."""
+    k: int
+    l: int
+
+    @property
+    def j(self) -> int:
+        return 3 - self.k - self.l
+
+
+KJL = Layout(k=0, l=2)   # (v_r, bn, L): the kernel impl's tile
+LKJ = Layout(k=1, l=0)   # (L, B, bn): the engine's tile, docs on the lanes
 
 
 def _solve_block(g, val, r, n_iter: int, lam: float, tol=None,
                  check_every: int = 4, gemm: str = "fp32",
-                 log_domain: bool = False, resmask=None):
-    """Shared solver body: one (v_r, bn, L) G tile resident in VMEM.
+                 log_domain: bool = False, resmask=None,
+                 layout: Layout = KJL, rowmask=None):
+    """Shared solver body: one G tile resident in VMEM.
 
-    g (v_r, bn, L); val (bn, L); r (v_r, 1). Returns (wmd (1, bn), iters).
-    Every array value stays at rank 2 or more (doc vectors are (1, bn)
-    rows or (bn, 1) columns): Mosaic lays out rank-1 vectors poorly and
-    its compiler aborts on some of them.
+    g is one query's tile in ``layout`` order; val and ``resmask`` are
+    t-shaped, r and ``rowmask`` x-shaped (see :class:`Layout`). Returns
+    (wmd, iters):
+    wmd (1, bn) is the tile summed over k and l. Every array value stays
+    at rank 2 or more: Mosaic lays out rank-1 vectors poorly and its
+    compiler aborts on some of them.
 
     ``tol`` switches the fixed ``fori_loop`` to a ``lax.while_loop`` with
     a residual epilogue: the doc-marginal residual ``max|val/t - w_prev|``
     (relative to each doc's own marginal scale, live slots only) is
     checked every ``check_every`` iterations and each grid block exits
     independently — inert pad blocks (w == 0 throughout) exit at the
-    first check. ``gemm="bf16"`` runs both reductions with bf16 operands
-    and fp32 accumulation. ``log_domain=True`` takes ``g`` as
-    UNexponentiated log K (pad rows -inf), column-stabilizes it in VMEM,
-    and adds the exact shift correction to the distance line.
+    first check. The residual reads the slots on the last axis, so
+    ``tol`` needs :data:`KJL`. ``gemm="bf16"`` runs both reductions with
+    bf16 operands and fp32 accumulation. ``log_domain=True`` takes ``g``
+    as UNexponentiated log K (pad rows -inf), column-stabilizes it in
+    VMEM, and adds the exact shift correction to the distance line.
 
-    ``resmask`` (bn, 1) scopes the exit test to the CALLER'S candidate
+    ``resmask`` scopes the exit test to the CALLER'S candidate
     docs (per-query residual scoping on the kernel path: in the
     batched kernel each grid block holds exactly one query's rows, so a
     block whose scope excludes its far docs exits — freezing that query's
@@ -128,22 +157,35 @@ def _solve_block(g, val, r, n_iter: int, lam: float, tol=None,
     Masked-out docs keep iterating while the block is live but cannot
     hold its exit open; a block with an empty scope exits at the first
     check like a pad block.
+
+    ``rowmask`` (nonzero on the query's live words) seeds x as the
+    einsum path does; without it the live words are the rows of G that
+    are nonzero somewhere in the block. In the linear domain a live doc
+    word whose K column is 0 for every query word (t == 0: exp(-lam*M)
+    underflowed) makes its doc's distance NaN, as the einsum path's raw
+    ``val / t`` does, so the engine raises its underflow error. Only the
+    caller's mask tells a live query whose K underflowed over the whole
+    block from an all-pad filler query, which stays inert.
     """
+    k, l = layout
+    assert tol is None or layout == KJL, "the residual needs slots last"
     shift = None
     if log_domain:
-        shift = jnp.max(g, axis=0)                     # (bn, L)
+        shift = jnp.max(g, axis=k, keepdims=True)
         shift = jnp.where(jnp.isfinite(shift), shift, 0.0)
-        g = jnp.where(jnp.isfinite(g), jnp.exp(g - shift[None]), 0.0)
-    gor = g * _safe_inv(r)[:, :, None]    # padded rows: r inv -> 0 is fine,
-    # but r pad is 1.0 by contract; g pad rows are 0 so gor pad rows are 0.
-    v_r = g.shape[0]
-    bn = g.shape[1]
+        g = jnp.where(jnp.isfinite(g), jnp.exp(g - shift), 0.0)
+    # r pad rows are 1.0 by contract and their G rows 0, so they stay 0
+    rinv = _safe_inv(r)
     live = (val > 0).astype(g.dtype)
-    rowmask = jnp.sum(jnp.sum(jnp.abs(g), axis=2), axis=1,
-                      keepdims=True) > 0                      # (v_r, 1)
-    n_rows = jnp.sum(rowmask.astype(g.dtype), axis=0, keepdims=True)
-    x0 = jnp.where(rowmask, 1.0 / n_rows, 0.0)                # (v_r, 1)
-    x = jnp.broadcast_to(x0, (v_r, bn)).astype(g.dtype)
+    if rowmask is None:
+        rowmask = jnp.sum(jnp.sum(jnp.abs(g), axis=l, keepdims=True),
+                          axis=layout.j, keepdims=True) > 0
+    else:
+        rowmask = rowmask > 0
+    n_rows = jnp.sum(rowmask.astype(g.dtype), axis=k, keepdims=True)
+    x0 = jnp.where(rowmask, 1.0 / n_rows, 0.0)
+    xshape = g.shape[:l] + (1,) + g.shape[l + 1:]
+    x = jnp.broadcast_to(x0, xshape).astype(g.dtype)
 
     # bf16 policy = bf16-ROUNDED OPERANDS with fp32 products/accumulation
     # (cast through bf16, multiply in fp32 — matching the einsum paths'
@@ -151,20 +193,20 @@ def _solve_block(g, val, r, n_iter: int, lam: float, tol=None,
     # would drift further for long docs)
     gd = jnp.bfloat16 if gemm == "bf16" else None
     gb = g if gd is None else g.astype(gd).astype(jnp.float32)
-    gorb = gor if gd is None else gor.astype(gd).astype(jnp.float32)
 
     def _rnd(a):
         return a if gd is None else a.astype(gd).astype(jnp.float32)
 
-    def _sddmm(u):
-        return _sddmm_with(gb, _rnd(u))
+    def _sddmm(gt, u):
+        return jnp.sum(gt * u, axis=k, keepdims=True)
 
     def _spmm(w):
-        return jnp.sum(gorb * _rnd(w)[None, :, :], axis=2)
+        # diag(1/r) is applied after the l-sum, as the einsum path does,
+        # so no G/r tile is kept
+        return jnp.sum(gb * _rnd(w), axis=l, keepdims=True) * rinv
 
     def one(x):
-        u = _safe_inv(x)
-        t = _sddmm(u)
+        t = _sddmm(gb, _rnd(_safe_inv(x)))
         w = val * _safe_inv(t) * live
         return _spmm(w), w
 
@@ -180,42 +222,118 @@ def _solve_block(g, val, r, n_iter: int, lam: float, tol=None,
             x, n_iter, tol, check_every, use_fori=True)
 
     u = _safe_inv(x)
-    t = _sddmm(u)
+    t = _sddmm(gb, _rnd(u))
     w = val * _safe_inv(t) * live
     gm = reconstruct_gm(g, lam)           # in VMEM; never touches HBM
     # final line: wmd[j] = sum_l w[j,l] * sum_k u[k,j] GM[k,j,l] — the
-    # k-sum first (elementwise across vregs), then one lane reduce
-    pw = _sddmm_with(gm, u) * w                               # (bn, L)
+    # k-sum first, then the l-sum
+    pw = _sddmm(gm, u) * w
     if log_domain:
         # exact rescale correction (t*w == val on live slots)
         pw = pw - shift * val / lam
-    # the lane reduce over a unit leading axis yields the (1, bn) row
-    return jnp.sum(pw[None, :, :], axis=2), iters
+    wmd = jnp.sum(pw, axis=l)
+    if not log_domain:
+        # the guard is applied to the (1, bn) row: Mosaic cannot broadcast
+        # the block's live-row count over both axes of a (1, bn, L) tile
+        dead = jnp.sum(((live > 0) & (t <= 0)).astype(g.dtype), axis=l) > 0
+        wmd = jnp.where(dead & (n_rows.reshape(1, 1) > 0), jnp.nan, wmd)
+    return wmd, iters
 
 
-def _fused_kernel(g_ref, val_ref, r_ref, *refs, n_iter: int,
-                  lam: float, tol, check_every: int, gemm: str,
-                  log_domain: bool, with_resmask: bool):
-    if with_resmask:
-        rm_ref, wmd_ref, it_ref = refs
-        rm = rm_ref[0]
-    else:
-        (wmd_ref, it_ref), rm = refs, None
-    wmd, iters = _solve_block(g_ref[0], val_ref[...], r_ref[0], n_iter, lam,
-                              tol, check_every, gemm, log_domain,
-                              resmask=rm)
+def _fused_kernel(g_ref, val_ref, r_ref, *refs, layout: Layout,
+                  n_iter: int, lam: float, tol, check_every: int, gemm: str,
+                  log_domain: bool, with_rowmask: bool, with_resmask: bool):
+    *masks, wmd_ref, it_ref = refs
+    rowmask = masks.pop(0)[0] if with_rowmask else None
+    rm = masks.pop(0)[...] if with_resmask else None
+    wmd, iters = _solve_block(g_ref[0], val_ref[...], r_ref[0], n_iter,
+                              lam, tol, check_every, gemm, log_domain,
+                              resmask=rm, layout=layout, rowmask=rowmask)
     wmd_ref[0] = wmd
     it_ref[...] = jnp.full(it_ref.shape, iters, jnp.int32)
 
 
+def _doc_block(shape, doc_axis: int, block_n: int, per_query: bool):
+    """BlockSpec of ``block_n`` docs along ``doc_axis`` (and one query
+    along axis 0 when ``per_query``), whole along every other axis."""
+    block = list(shape)
+    block[doc_axis] = block_n
+    if per_query:
+        block[0] = 1
+
+    def index(qi, i):
+        at = [0] * len(shape)
+        at[doc_axis] = i
+        if per_query:
+            at[0] = qi
+        return tuple(at)
+    return pl.BlockSpec(tuple(block), index)
+
+
 def _compiler_params(block_bytes: int):
     """Scoped-VMEM budget for one grid step: the double-buffered G block
-    plus the solver body's G-sized temporaries (G/r, the broadcast
-    products, the rebuilt GM), with headroom, inside v5e's 128 MiB VMEM.
+    plus the solver body's G-sized temporaries (the broadcast products,
+    the rebuilt GM), with headroom, inside v5e's 128 MiB VMEM.
     The compiler's default scoped limit (16 MiB) is too small for the
     adaptive loop over a (64, 128, 128) fp32 tile, which needs ~24 MiB."""
     return pltpu.CompilerParams(
         vmem_limit_bytes=int(min(max(8 * block_bytes, 32 << 20), 100 << 20)))
+
+
+def _fused_call(g, val, r, resmask, layout: Layout, *, lam: float,
+                n_iter: int, block_n: int, interpret: bool, tol,
+                check_every: int, gemm: str, log_domain: bool,
+                rowmask=None):
+    """The (Q, N // block_n) grid of :func:`_fused_kernel`: one query's
+    block of ``block_n`` docs per step, its G tile resident in VMEM for
+    the whole solve.
+
+    g (Q, ...) holds each query's (3-axis) tile in ``layout`` order; val
+    is the t-shaped doc plane, (1, N, L) under :data:`KJL` and
+    (L, 1, N) under :data:`LKJ`; r and ``rowmask`` (Q, v_r), ``rowmask``
+    or None; ``resmask`` (Q, N) or None. Returns (wmd (Q, N), iters
+    (Q, N // block_n)).
+
+    Mosaic takes a block whose last two dimensions are (8, 128)-aligned
+    or span the array's own, so the small operands and outputs carry
+    unit axes: r and ``rowmask`` arrive x-shaped, ``resmask`` t-shaped,
+    wmd leaves as (Q, 1, N) and iters as (Q, N // block_n, 1, 1).
+    """
+    q, n = g.shape[0], g.shape[1 + layout.j]
+    assert n % block_n == 0, (n, block_n)
+    nb = n // block_n
+    xshape = (q,) + tuple(-1 if ax == layout.k else 1 for ax in range(3))
+    per_query = pl.BlockSpec((1,) + r.reshape(xshape).shape[1:],
+                             lambda qi, i: (qi, 0, 0, 0))
+    in_specs = [_doc_block(g.shape, 1 + layout.j, block_n, True),
+                _doc_block(val.shape, layout.j, block_n, False), per_query]
+    args = [g, val, r.reshape(xshape)]
+    if rowmask is not None:
+        in_specs.append(per_query)
+        args.append(jnp.asarray(rowmask, g.dtype).reshape(xshape))
+    with_resmask = resmask is not None and tol is not None
+    if with_resmask:
+        rm = jnp.asarray(resmask, g.dtype)
+        rm = rm.reshape((q, n, 1) if layout.j == 1 else (q, 1, n))
+        in_specs.append(_doc_block(rm.shape, layout.j, block_n, True))
+        args.append(rm)
+    wmd, iters = pl.pallas_call(
+        functools.partial(_fused_kernel, layout=layout, n_iter=n_iter,
+                          lam=lam, tol=tol, check_every=check_every,
+                          gemm=gemm, log_domain=log_domain,
+                          with_rowmask=rowmask is not None,
+                          with_resmask=with_resmask),
+        grid=(q, nb),
+        in_specs=in_specs,
+        out_specs=[pl.BlockSpec((1, 1, block_n), lambda qi, i: (qi, 0, i)),
+                   pl.BlockSpec((1, 1, 1, 1), lambda qi, i: (qi, i, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((q, 1, n), g.dtype),
+                   jax.ShapeDtypeStruct((q, nb, 1, 1), jnp.int32)],
+        compiler_params=_compiler_params(
+            math.prod(in_specs[0].block_shape) * g.dtype.itemsize),
+        interpret=interpret,
+    )(*args)
+    return wmd.reshape(q, n), iters.reshape(q, nb)
 
 
 @functools.partial(jax.jit,
@@ -255,13 +373,15 @@ def sinkhorn_fused_all_batched(g: jax.Array, val: jax.Array, r: jax.Array,
                                lam: float, n_iter: int, block_n: int = 128,
                                interpret: bool = False, tol=None,
                                check_every: int = 4, gemm: str = "fp32",
-                               log_domain: bool = False, resmask=None):
+                               log_domain: bool = False, resmask=None,
+                               mask=None):
     """Batched solver: Q queries against one shared corpus in one launch.
 
     g: (Q, v_r, N, L) per-query gathered kernels (log K when
     ``log_domain``); val: (N, L) shared corpus frequencies; r: (Q, v_r)
     with the same padding contract as :func:`sinkhorn_fused_all` per query
-    row. Returns (wmd (Q, N), iters (Q, N // block_n)) — each grid block
+    row; ``mask`` (Q, v_r), nonzero on live query words, or None (see
+    :func:`_solve_block`). Returns (wmd (Q, N), iters (Q, N // block_n)) — each grid block
     records its own realized iteration count, and with ``tol`` set each
     block EXITS independently (per-block early exit; inert pad blocks exit
     at the first residual check).
@@ -276,36 +396,45 @@ def sinkhorn_fused_all_batched(g: jax.Array, val: jax.Array, r: jax.Array,
     corpus sweep is contiguous; ``val`` blocks depend only on the doc index
     and are revisited per query (resident after the first pass on TPU).
 
-    Mosaic takes a block whose last two dimensions are (8, 128)-aligned or
-    span the array's own, so the per-block outputs and the mask carry unit
-    axes: wmd is produced as (Q, 1, N), iters as (Q, N // block_n, 1, 1)
-    and ``resmask`` is passed as (Q, N, 1).
+    The tile is (v_r, block_n, L) per query: the SDDMM's k-sum runs over
+    the leading axis and the SpMM's l-sum over the lanes.
     """
-    q, v_r, n, length = g.shape
-    assert n % block_n == 0, (n, block_n)
-    nb = n // block_n
-    with_resmask = resmask is not None and tol is not None
-    in_specs = [pl.BlockSpec((1, v_r, block_n, length),
-                             lambda qi, i: (qi, 0, i, 0)),
-                pl.BlockSpec((block_n, length), lambda qi, i: (i, 0)),
-                pl.BlockSpec((1, v_r, 1), lambda qi, i: (qi, 0, 0))]
-    args = [g, val, r.reshape(q, v_r, 1)]
-    if with_resmask:
-        in_specs.append(pl.BlockSpec((1, block_n, 1),
-                                     lambda qi, i: (qi, i, 0)))
-        args.append(jnp.asarray(resmask, g.dtype).reshape(q, n, 1))
-    wmd, iters = pl.pallas_call(
-        functools.partial(_fused_kernel, n_iter=n_iter, lam=lam,
-                          tol=tol, check_every=check_every, gemm=gemm,
-                          log_domain=log_domain, with_resmask=with_resmask),
-        grid=(q, nb),
-        in_specs=in_specs,
-        out_specs=[pl.BlockSpec((1, 1, block_n), lambda qi, i: (qi, 0, i)),
-                   pl.BlockSpec((1, 1, 1, 1), lambda qi, i: (qi, i, 0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((q, 1, n), g.dtype),
-                   jax.ShapeDtypeStruct((q, nb, 1, 1), jnp.int32)],
-        compiler_params=_compiler_params(
-            v_r * block_n * length * g.dtype.itemsize),
-        interpret=interpret,
-    )(*args)
-    return wmd.reshape(q, n), iters.reshape(q, nb)
+    return _fused_call(g, val[None], r, resmask, KJL, lam=lam,
+                       n_iter=n_iter, block_n=block_n, interpret=interpret,
+                       tol=tol, check_every=check_every, gemm=gemm,
+                       log_domain=log_domain, rowmask=mask)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("lam", "n_iter", "block_n", "interpret",
+                                    "gemm", "log_domain"))
+def sinkhorn_resident(g: jax.Array, val: jax.Array, r: jax.Array,
+                      mask: jax.Array, lam: float, n_iter: int,
+                      block_n: int = 128,
+                      interpret: bool = False, gemm: str = "fp32",
+                      log_domain: bool = False) -> jax.Array:
+    """Fixed-iteration batched solve over the engine's gathered tile.
+
+    g: (Q, L, B, N_pad) — each query's K rows gathered at the doc words
+    (log K under ``log_domain``), N_pad a multiple of ``block_n`` whose
+    tail docs are inert; val: (N, L) with N <= N_pad; r: (Q, B), padded
+    rows r == 1 and G == 0 (-inf); mask: (Q, B), nonzero on live query
+    words, so that a live query whose K underflowed over a whole block
+    still reads NaN there (see :func:`_solve_block`). Returns wmd (Q, N).
+
+    Each (L, B, block_n) tile is read from HBM once and stays in VMEM
+    for every iteration and the distance line (GM rebuilt from G in
+    VMEM). The docs lie on the lanes and the query words on the
+    sublanes, which is the order the TPU lays out the gather's output
+    in, so the tile reaches the kernel without a copy. Both reductions
+    stay off the lanes: the SDDMM's k-sum runs over the sublanes and the
+    SpMM's l-sum over the leading axis.
+    """
+    n = val.shape[0]
+    n_pad = g.shape[3]
+    valt = jnp.pad(val, ((0, n_pad - n), (0, 0))).T[:, None]  # (L, 1, N_pad)
+    wmd, _ = _fused_call(g, valt, r, None, LKJ, lam=lam, n_iter=n_iter,
+                         block_n=block_n, interpret=interpret, tol=None,
+                         check_every=1, gemm=gemm, log_domain=log_domain,
+                         rowmask=mask)
+    return wmd[:, :n]

@@ -64,12 +64,13 @@ def _rwmd_min_cdist():
 def _fused_batched(tol):
     from repro.kernels.ops import sinkhorn_fused_all_batched
 
-    def fn(g, val, r, resmask):
+    def fn(g, val, r, resmask, mask):
         return sinkhorn_fused_all_batched(
             g, val, r, 1.0, 15, interpret=False, tol=tol, resmask=resmask,
-            with_iters=True)
+            with_iters=True, mask=mask)
     return fn, [((Q, VR, N_DOCS, L), jnp.float32), ((N_DOCS, L), jnp.float32),
-                ((Q, VR), jnp.float32), ((Q, N_DOCS), jnp.float32)]
+                ((Q, VR), jnp.float32), ((Q, N_DOCS), jnp.float32),
+                ((Q, VR), jnp.float32)]
 
 
 def _compute_kq():
@@ -123,6 +124,52 @@ def test_compiles_for_one_v5e_chip(case, one_chip):
     assert ("tpu_custom_call" in text) == is_kernel, case
     stats = compiled.memory_analysis()
     assert stats.argument_size_in_bytes > 0
+
+
+def _compile_resident(vocab, n_group, length, b, q, one_chip):
+    """Compile the gather and the resident solve of one nnz group of
+    ``n_group`` docs of ELL width ``length`` against a chunk of ``q``
+    queries staged at width ``b``: the gather hands its tile over in the
+    layout the kernel reads, so no copy of the tile lies between them."""
+    from repro.core.index import _gather_g
+    from repro.kernels.sddmm_spmm import sinkhorn_resident
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    gather = jax.jit(functools.partial(_gather_g, layout="qlbn",
+                                       block_n=128)).lower(
+        spec((q, vocab, b)), spec((n_group, length), jnp.int32)).compile()
+    tile = gather.out_info
+    assert tile.shape == (q, length, b, -(-n_group // 128) * 128)
+    solve = jax.jit(functools.partial(sinkhorn_resident, lam=10.0,
+                                      n_iter=15)).lower(
+        spec(tile.shape), spec((n_group, length)), spec((q, b)),
+        spec((q, b))).compile()
+    assert "tpu_custom_call" in solve.as_text()
+    handed, taken = gather.output_formats, solve.input_formats[0][0]
+    assert handed.layout == taken.layout, (handed, taken)
+    assert taken.layout.major_to_minor == (0, 1, 2, 3)
+
+
+@pytest.mark.parametrize("q", [1, 2, 4])
+@pytest.mark.parametrize("b", [24, 32, 40, 48])
+@pytest.mark.parametrize("length", [25, 32, 42, 96])
+def test_resident_solve_compiles_for_one_v5e_chip(length, b, q, one_chip):
+    """The engine's TPU solve at the one-to-many scan's shapes: one nnz
+    group of 1,250 docs, each of its ELL widths, each staged query width
+    and each padded chunk size."""
+    _compile_resident(V, N_GROUP, length, b, q, one_chip)
+
+
+@pytest.mark.parametrize("q", [1, 2, 4])
+@pytest.mark.parametrize("b", [128, 192])
+def test_resident_solve_compiles_at_news_widths(b, q, one_chip):
+    """The resident solve's largest tiles: Kusner's 20NEWS deployment
+    (29,671 words, 11,293 docs in four nnz groups of 2,824, the widest of
+    ELL width 192) against queries staged at 128 and 192 words, the
+    widest of the two largest buckets. A (192, 192, 128) fp32 block is 18.9 MB, so this checks that
+    two buffers of it and the body's temporaries fit the scoped VMEM."""
+    _compile_resident(29_671, 2_824, 192, b, q, one_chip)
 
 
 def test_shard_merge_compiles_for_four_v5e_chips(topo):

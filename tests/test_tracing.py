@@ -79,7 +79,8 @@ def test_spans_nest_inside_query_batch(corpus, tmp_path):
 def test_host_stats_match_the_plan(corpus, impl):
     engine = _engine(corpus, impl)
     queries = list(corpus.queries)
-    assert engine.host_stats() == {"dispatches": 0, "host_syncs": 0}
+    zero = {"dispatches": 0, "host_syncs": 0, "resident_solves": 0}
+    assert engine.host_stats() == zero
     engine.query_batch(queries)
     engine.reset_host_stats()
     engine.query_batch(queries)
@@ -87,9 +88,10 @@ def test_host_stats_match_the_plan(corpus, impl):
     c, g = _plan_counts(engine, queries)
     c1, _ = _plan_counts(engine, queries[:1])
     assert engine.host_stats() == {"dispatches": (c + c1) * (1 + 2 * g),
-                                   "host_syncs": (c + c1) * g}
+                                   "host_syncs": (c + c1) * g,
+                                   "resident_solves": 0}
     engine.reset_host_stats()
-    assert engine.host_stats() == {"dispatches": 0, "host_syncs": 0}
+    assert engine.host_stats() == zero
 
 
 def test_results_identical_with_profiler_on_and_off(corpus, tmp_path):
@@ -112,9 +114,11 @@ def test_sharded_host_stats_sum_the_shards(corpus):
     (shard,) = engine.engines
     c, g = _plan_counts(shard, queries)
     assert engine.host_stats() == shard.host_stats() == {
-        "dispatches": c * (1 + 2 * g), "host_syncs": c * g}
+        "dispatches": c * (1 + 2 * g), "host_syncs": c * g,
+        "resident_solves": 0}
     engine.reset_host_stats()
-    assert engine.host_stats() == {"dispatches": 0, "host_syncs": 0}
+    assert engine.host_stats() == {"dispatches": 0, "host_syncs": 0,
+                                   "resident_solves": 0}
 
 
 def test_serving_stats_report_host_counters(corpus):
