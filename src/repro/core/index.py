@@ -38,8 +38,9 @@ batches the query side:
     :mod:`repro.core.prune` (WCD / doc-side RWMD) scores every (query, doc)
     pair first; the Sinkhorn solve then runs only on (a) the k best-bounded
     seed docs and (b) the docs whose bound cannot be excluded by the kth
-    seed distance — gathered out of the frozen index into a trimmed ELL
-    subset slice. With an admissible bound the returned top-k equals the
+    seed distance — solved in fixed-shape tiles of the index's own doc
+    groups (:meth:`CorpusIndex.tiles`). With an admissible bound the
+    returned top-k equals the
     exhaustive one exactly; ``prune=None`` reproduces exhaustive
     ``query_batch`` + argsort bit-for-bit.
 
@@ -58,11 +59,10 @@ batches the query side:
 Cluster-major layout (ISSUE 4)
     ``build_index`` stores the corpus sorted by IVF cluster id: cluster
     ``c``'s documents occupy the contiguous STORAGE rows
-    ``starts[c]:starts[c+1]``, so ``subset()`` gathers of cascade
-    survivors (which arrive as concatenated cluster slices) copy
-    near-contiguous host rows instead of scattering across the corpus.
-    Storage ids are internal; ``ext_ids``/``remap`` translate to/from the
-    caller's original doc order at the output boundary only, so
+    ``starts[c]:starts[c+1]``, and the members of each nnz doc group
+    are kept in that order too. Storage ids are internal;
+    ``ext_ids``/``remap`` translate to/from the caller's original doc
+    order at the output boundary only, so
     ``query_batch`` rows and ``search`` indices are unchanged.
     ``append_docs`` keeps the invariant within the grown group.
 
@@ -91,6 +91,7 @@ fp32 ``exp(-lam*M)`` underflows first and the engine raises
 """
 from __future__ import annotations
 
+import collections
 import functools
 import zlib
 from typing import NamedTuple, Sequence
@@ -399,44 +400,84 @@ class CorpusIndex(NamedTuple):
             return storage_ids
         return self.ext_ids[storage_ids]
 
-    def subset(self, doc_ids, storage: bool = False) -> DocGroup:
-        """Candidate-subset slice for the solve stage: gather ``doc_ids``
-        out of the full ELL corpus into one width-trimmed :class:`DocGroup`
-        (slots are front-compacted at build, so trimming to the subset's
-        max nnz loses nothing). Gathers from the host mirror — candidate
-        sets are small post-prune and change per query chunk, so they are
-        staged like queries: O(|doc_ids| * L) work, one small H2D upload,
-        no device round-trip.
+    def tiles(self, doc_ids, tile: int, storage: bool = False,
+              lookup=None, few: int = 0) -> list:
+        """Candidate docs in fixed-shape tiles of the index's own doc
+        groups, for the solve stage: ``doc_ids`` are split by the nnz
+        :class:`DocGroup` each lives in, and each group's share is cut
+        into tiles of ``tile`` docs (of the group's own doc count, where
+        that is smaller). A tile's ELL rows are gathered on the
+        device from its group's arrays by position (one small id upload,
+        no host slice), at the group's own width L_g; slots past the
+        tile's real docs are inert pad docs (word 0, weight 0), as a
+        group's own padding is. So a solve's shapes follow the group and
+        ``tile``, whatever the candidate count, and its gathered K tile is
+        never larger than Q x L_g x B x ``tile``.
 
-        ``doc_ids`` are original (caller-order) ids by default;
-        ``storage=True`` takes storage ids directly — the engine's internal
-        path, where cascade survivors arrive as concatenated cluster
-        slices and the cluster-major layout makes this gather a
-        near-contiguous host copy. ``cols`` echoes ``doc_ids`` as passed
-        (so it is in the same id space the caller used).
-
-        Shapes are BUCKETED like the query side (doc count padded to a
-        power of two with inert all-zero docs, ELL width to a multiple of
-        8): candidate counts are data-dependent per search step and would
-        otherwise compile a fresh solver executable per step under serving
-        traffic. ``cols`` keeps only the real ids — consumers slice the
-        solve output to ``cols.shape[0]`` columns."""
-        doc_ids = np.asarray(doc_ids, np.int32)
+        Returns ``[(DocGroup, part)]``: the tile's docs (``cols`` holds
+        its real ids as passed, a host array) and ``part``, the positions
+        in ``doc_ids`` its first ``len(part)`` columns answer.
+        ``doc_ids`` are the caller's ids, or storage ids with
+        ``storage=True`` (the engine's internal path). ``lookup`` is
+        :func:`group_lookup` of this index's groups, when the caller
+        keeps one. At most ``few`` docs are one tile of ``few`` slots
+        across the groups, at the widest group's width (``few <= tile``
+        keeps it within the widest group's tile bound)."""
+        doc_ids = np.asarray(doc_ids, np.int32).reshape(-1)
         rows = doc_ids
         if not storage and self.remap is not None:
             rows = self.remap[doc_ids]
-        idx = self.docs_host.idx[rows]
-        val = self.docs_host.val[rows]
-        lg = max(1, int((val > 0).sum(axis=1).max(initial=0)))
-        lg = min(-(-lg // 8) * 8, idx.shape[1])
-        n_pad = 8
-        while n_pad < doc_ids.size:
-            n_pad *= 2
-        pad = ((0, n_pad - doc_ids.size), (0, 0))
-        return DocGroup(docs=PaddedDocs(
-            idx=jnp.asarray(np.pad(idx[:, :lg], pad)),
-            val=jnp.asarray(np.pad(val[:, :lg], pad))),
-            cols=jnp.asarray(doc_ids))
+        few = min(few, self.n_docs)
+        if 0 < doc_ids.size <= few:
+            pos = np.zeros(few, np.int32)
+            pos[:doc_ids.size] = rows
+            width = max(g.docs.idx.shape[1] for g in self.groups)
+            idx, val = _tile_rows(self.docs.idx, self.docs.val,
+                                  jnp.asarray(pos), doc_ids.size,
+                                  width=width)
+            return [(DocGroup(PaddedDocs(idx=idx, val=val), cols=doc_ids),
+                     np.arange(doc_ids.size))]
+        grp_of, pos_of = lookup if lookup is not None else group_lookup(
+            self.groups)
+        in_grp = grp_of[rows]
+        out = []
+        for gi, grp in enumerate(self.groups):
+            sel = np.nonzero(in_grp == gi)[0]
+            # a tile never holds more slots than its group has docs
+            size = min(tile, int(grp.cols.shape[0]))
+            for lo in range(0, sel.size, size):
+                part = sel[lo:lo + size]
+                pos = np.zeros(size, np.int32)
+                pos[:part.size] = pos_of[rows[part]]
+                idx, val = _tile_rows(grp.docs.idx, grp.docs.val,
+                                      jnp.asarray(pos), part.size)
+                out.append((DocGroup(PaddedDocs(idx=idx, val=val),
+                                     cols=doc_ids[part]), part))
+        return out
+
+
+def group_lookup(groups) -> tuple:
+    """Host ``(group, position)`` of every storage id in ``groups``."""
+    n = sum(int(g.cols.shape[0]) for g in groups)
+    grp_of = np.zeros(n, np.int32)
+    pos_of = np.zeros(n, np.int32)
+    for gi, g in enumerate(groups):
+        cols = np.asarray(g.cols)
+        grp_of[cols] = gi
+        pos_of[cols] = np.arange(cols.size, dtype=np.int32)
+    return grp_of, pos_of
+
+
+@functools.partial(jax.jit, static_argnames=("width",))
+def _tile_rows(idx, val, pos, n_real, width=None):
+    """One candidate tile's ELL rows, gathered from its group's (N_g, L_g)
+    arrays (or the corpus' first ``width`` slots) at ``pos``; slots from
+    ``n_real`` on are inert pad docs."""
+    if width is not None:
+        idx, val = idx[:, :width], val[:, :width]
+    live = (jnp.arange(pos.shape[0]) < n_real)[:, None]
+    return (jnp.where(live, jnp.take(idx, pos, axis=0), 0),
+            jnp.where(live, jnp.take(val, pos, axis=0), 0))
 
 
 def _compact_slots(docs: PaddedDocs, dtype):
@@ -472,10 +513,10 @@ def build_index(docs: PaddedDocs, vecs, dtype=jnp.float32,
     quantizer over those centroids (the cascade's shortlist stage).
 
     Storage is CLUSTER-MAJOR (ISSUE 4): after clustering, documents are
-    permuted so cluster ids are non-decreasing — cascade survivor gathers
-    in :meth:`CorpusIndex.subset` become near-contiguous host slices
-    instead of corpus-wide scatters. ``ext_ids``/``remap`` record the
-    permutation; every engine result stays in the caller's doc order.
+    permuted so cluster ids are non-decreasing, so a cluster's members
+    are neighbouring rows of each doc group. ``ext_ids``/``remap``
+    record the permutation; every engine result stays in the caller's
+    doc order.
 
     ``n_clusters`` accepts an int, ``None`` (sqrt(N) default), ``"auto"``
     (the radius sweep), or a numeric string (CLI passthrough).
@@ -1120,6 +1161,13 @@ _solve_gathered = jax.jit(_solve_batched_einsum,
                                            "with_profile"))
 
 
+def _staged_width(v_r: int, bucket: int) -> int:
+    """Staging width of a chunk whose widest query has ``v_r`` words, in
+    the pow2 ``bucket``: the grid of 8 up to 64 words, of 32 above."""
+    step = 8 if v_r <= 64 else 32
+    return max(8, min(bucket, -(-v_r // step) * step))
+
+
 def _prepare_query(q, bucket: int, dtype):
     """Host-side support selection + bucket padding for one query row."""
     q = np.asarray(q, dtype=np.float64).reshape(-1)
@@ -1134,6 +1182,29 @@ def _prepare_query(q, bucket: int, dtype):
     mask = np.zeros(bucket, dtype)
     mask[:v_r] = 1.0
     return sup, r, mask
+
+
+# docs per candidate tile in search: candidate solves run in tiles of the
+# index's doc groups (CorpusIndex.tiles), so their shapes and memory follow
+# the group and this count, never how many docs survived the prune. A
+# multiple of every block_n (the TPU gather pads docs to whole blocks); a
+# step of 20NEWS kNN (~2,000 survivors over four groups) fills about one
+# tile per group, and fewer, larger tiles mean fewer dispatches
+TILE_DOCS = 512
+
+# a candidate set of at most this many docs (a search step's seeds, or few
+# survivors) is solved as one tile across the groups, gathered from the
+# corpus' ELL rows at the widest group's width, rather than one mostly
+# padded tile per group it touches: at 20NEWS widths its K tile is a
+# quarter of the widest group's tile. A multiple of every block_n
+FEW_DOCS = 128
+
+# device bytes of gathered candidate tiles that search's solves may hold
+# in flight at once: the host reads the oldest tile's result back before it
+# dispatches past this, so a candidate solve's memory stays bounded however
+# many tiles its candidates fill. One tile queued behind the running one
+# keeps the device busy; a 20NEWS tile is at most 302 MB (Q 4, L = B = 192)
+INFLIGHT_BYTES = 1 << 28
 
 
 class SearchResult(NamedTuple):
@@ -1277,7 +1348,6 @@ class WmdEngine:
         # cover everything" when they covered the last 4096 dispatches —
         # iter_stats_dropped counts the discards and the serve JSON
         # surfaces it.
-        import collections
         self._iters_pending: collections.deque = collections.deque(
             maxlen=max(1, int(iter_stats_maxlen)))
         self._iters_dropped = 0
@@ -1287,9 +1357,9 @@ class WmdEngine:
         self.kcache_min_hits = max(1, int(kcache_min_hits))
         if kcache_slots:
             self.enable_kcache(int(kcache_slots))
-        self._dispatches = 0
-        self._host_syncs = 0
-        self._resident_solves = 0
+        self._lookup = (None, None)     # (groups, group_lookup(groups))
+        self.last_solved_ids = np.zeros(0, np.int32)
+        self.reset_host_stats()
 
     # ------------------------------------------------- cross-request cache
     def enable_kcache(self, slots: int) -> bool:
@@ -1318,22 +1388,44 @@ class WmdEngine:
     # ------------------------------------------------------- host counters
     def host_stats(self) -> dict:
         """Host-side counters since the last :meth:`reset_host_stats`:
-        ``dispatches``, the device layer calls the engine makes (one per
-        K block in ``_kq``, one per gather and one per solve in
-        ``_solve_group``), ``host_syncs``, the blocking result reads
-        of :meth:`query_batch` (one per chunk and doc group), and
-        ``resident_solves``, the ``_solve_group`` solves that ran the
-        VMEM-resident kernel rather than the einsum (see
-        :meth:`_solve_group`). Counting adds no device work and no
-        sync."""
+
+        - ``dispatches``: the device layer calls the engine makes (one
+          per K block in ``_kq``, one per gather and one per solve in
+          ``_solve_group``, one per candidate tile's row gather);
+        - ``host_syncs``: the blocking result reads of :meth:`query_batch`
+          (one per chunk and doc group);
+        - ``resident_solves``: the ``_solve_group`` solves that ran the
+          VMEM-resident kernel rather than the einsum (see
+          :meth:`_solve_group`);
+        - ``search_queries``: queries handed to :meth:`search`;
+        - ``pairs_solved``: (live query, real candidate doc) pairs that
+          :meth:`search`'s candidate solves scored, seeds and survivors;
+        - ``slots_solved``: (live query, doc slot) pairs of the candidate
+          tiles those solves ran, pad slots included;
+        - ``survivor_tiles``: candidate tile solves dispatched (one per
+          staged chunk and tile, seed and survivor stages alike);
+        - ``prune_syncs``: the blocking host reads of :meth:`search`'s
+          prune stages (seed pick, survivor pass, per-query scopes).
+
+        Counting adds no device work and no sync."""
         return {"dispatches": self._dispatches,
                 "host_syncs": self._host_syncs,
-                "resident_solves": self._resident_solves}
+                "resident_solves": self._resident_solves,
+                "search_queries": self._search_queries,
+                "pairs_solved": self._pairs_solved,
+                "slots_solved": self._slots_solved,
+                "survivor_tiles": self._survivor_tiles,
+                "prune_syncs": self._prune_syncs}
 
     def reset_host_stats(self) -> None:
         self._dispatches = 0
         self._host_syncs = 0
         self._resident_solves = 0
+        self._search_queries = 0
+        self._pairs_solved = 0
+        self._slots_solved = 0
+        self._survivor_tiles = 0
+        self._prune_syncs = 0
 
     # -------------------------------------------------- realized iterations
     def reset_iter_stats(self) -> None:
@@ -1416,10 +1508,15 @@ class WmdEngine:
 
         Queries are grouped into power-of-two v_r buckets and SORTED by v_r
         inside each bucket; each ``max_batch``-sized chunk is then trimmed
-        to the smallest multiple-of-8 width (the TPU sublane) covering its
-        members. The pow2 buckets bound the executable count, the sort +
-        trim bounds padding waste to < 8 rows per query. Empty queries
-        (no support) are left out entirely.
+        to the smallest width of the staging grid covering its members:
+        multiples of 8 (the TPU sublane) up to 64 words, multiples of 32
+        above (96, 128, 160, 192, ...). The pow2 buckets bound the
+        executable count and the sort + trim bounds padding waste to < 8
+        rows per query on short queries; the coarser grid above 64 words
+        halves the number of widths a long-query deployment compiles
+        (11 instead of 23 for 16-192 words) at < 32 rows of padding on
+        queries that long. Empty queries (no support) are left out
+        entirely.
         """
         vr = [int((q > 0).sum()) for q in queries]
         buckets: dict[int, list[int]] = {}
@@ -1433,23 +1530,21 @@ class WmdEngine:
             members = sorted(buckets[b], key=lambda qi: vr[qi])
             for lo in range(0, len(members), self.max_batch):
                 chunk = members[lo:lo + self.max_batch]
-                width = max(8, min(b, -(-max(vr[qi] for qi in chunk) // 8) * 8))
-                chunks.append((chunk, width))
+                chunks.append((chunk, _staged_width(
+                    max(vr[qi] for qi in chunk), b)))
         return vr, chunks
 
     @functools.partial(jax.profiler.annotate_function, name="wmd.stage")
-    def _prep_chunk(self, chunk_queries: list, width: int):
+    def _prep_chunk(self, chunk_queries: list, width: int,
+                    q_pad: int | None = None):
         """Stage one chunk: (sup, r, mask) device arrays, q-padded to a
         power of two with inert fillers (no support -> G rows all 0, r == 1)
-        when ``pad_q``."""
+        when ``pad_q``, or to ``q_pad`` rows when given."""
         prepared = [_prepare_query(q, width, self.dtype)
                     for q in chunk_queries]
         n_live = len(prepared)
-        q_pad = n_live
-        if self.pad_q:
-            q_pad = 1
-            while q_pad < n_live:
-                q_pad *= 2
+        if q_pad is None:
+            q_pad = bucket_size(n_live, 1) if self.pad_q else n_live
         filler = (np.zeros(width, np.int32), np.ones(width, self.dtype),
                   np.zeros(width, self.dtype))
         prepared += [filler] * (q_pad - n_live)
@@ -1653,6 +1748,7 @@ class WmdEngine:
             return jnp.asarray(out)
 
     # ------------------------------------------------------------ search
+    @functools.partial(jax.profiler.annotate_function, name="wmd.search")
     def search(self, queries: Sequence, k: int, prune: object = "rwmd",
                nprobe: int | None = None, mode: str = "exact",
                refine_factor: int = 4) -> SearchResult:
@@ -1672,7 +1768,8 @@ class WmdEngine:
            computes each later (costlier) bound only on the docs the
            previous stage could not exclude;
         2. *solve* (seed): exact Sinkhorn on the union of each query's k
-           best-bounded docs, gathered into a trimmed ELL subset slice;
+           best-bounded docs, in fixed-shape tiles of the index's doc
+           groups (:meth:`CorpusIndex.tiles`, :data:`TILE_DOCS` docs each);
            the per-query kth-smallest exact distance becomes the pruning
            threshold t_q — any doc with lb > t_q cannot enter the top-k.
            Seed selection and thresholding run device-side (top_k / sort
@@ -1683,7 +1780,9 @@ class WmdEngine:
 
         With an admissible bound the result equals the exhaustive top-k
         (indices and distances, up to tie order) while Sinkhorn runs on a
-        strict subset of documents — ``result.solved`` reports how strict.
+        strict subset of documents — ``result.solved`` reports how strict,
+        and :attr:`last_solved_ids` holds the (caller-order) ids of the
+        docs the last call solved.
         The guarantee holds for ``"rwmd"`` (and its compositions), which
         bounds the *computed* truncated-Sinkhorn score; ``"wcd"`` alone
         bounds exact EMD and is exact only up to the iteration's
@@ -1740,6 +1839,8 @@ class WmdEngine:
                                  f"got {refine_factor}")
         k = min(int(k), n)
         nq = len(queries)
+        self._search_queries += nq
+        self.last_solved_ids = np.zeros(0, np.int32)
         out_i = np.full((nq, k), -1, np.int32)
         out_d = np.full((nq, k), np.nan, self.dtype)
         solved = np.zeros(nq, np.int64)
@@ -1748,66 +1849,38 @@ class WmdEngine:
 
         if prune is None:
             d = np.asarray(self.query_batch(queries))
-            for qi in range(nq):
-                if np.isnan(d[qi]).all():
-                    continue                      # empty marginal
-                order = np.argsort(d[qi], kind="stable")[:k]
-                out_i[qi], out_d[qi] = order, d[qi, order]
-                solved[qi] = n
+            with jax.profiler.TraceAnnotation("wmd.rank"):
+                for qi in range(nq):
+                    if np.isnan(d[qi]).all():
+                        continue                  # empty marginal
+                    order = np.argsort(d[qi], kind="stable")[:k]
+                    out_i[qi], out_d[qi] = order, d[qi, order]
+                    solved[qi] = n
+            self.last_solved_ids = np.arange(n, dtype=np.int32)
             return SearchResult(out_i, out_d, solved)
 
         from .prune import CascadePruner, resolve_pruner
         pruner = resolve_pruner(prune, use_kernel=(self.impl == "kernel"),
                                 interpret=self.interpret, nprobe=nprobe)
         _, chunks = self._plan(queries)
+        if not chunks:
+            return SearchResult(out_i, out_d, solved)
         if mode == "refine":
-            if chunks:
-                self._search_refine(queries, k, pruner, nprobe, chunks,
-                                    int(refine_factor), out_i, out_d,
-                                    solved)
-            return SearchResult(out_i, out_d, solved)
-        if isinstance(pruner, CascadePruner):
-            if chunks:
-                self._search_cascade(queries, k, pruner, nprobe, chunks,
-                                     out_i, out_d, solved)
-            return SearchResult(out_i, out_d, solved)
-        for chunk, width in chunks:
-            cq = [queries[qi] for qi in chunk]
-            qc = len(chunk)
-            sup, r, mask = self._prep_chunk(cq, width)
-            kq = self._kq(sup, mask)              # shared by both solves
-
-            def solve(doc_ids, qmask=None, stage="seed", warm=None,
-                      prof=None):
-                # -> ((qc, |ids|) np NaN-checked, warm-start profile)
-                grp = self.index.subset(doc_ids, storage=True)
-                n_pad = grp.docs.idx.shape[0]
-                qm = (None if qmask is None else self._pad_qdoc(
-                    qmask, r.shape[0], n_pad))
-                pm = (None if prof is None else self._pad_qdoc(
-                    prof, r.shape[0], n_pad))
-                w, prof_out = self._solve_profiled(
-                    kq, r, mask, grp, n_live=qc, stage=stage, qdoc_mask=qm,
-                    x0q=warm, prof_mask=pm)
-                w = np.asarray(w)[:qc, :doc_ids.size]
-                self._raise_if_nan(w, cq)
-                return w, prof_out
-
-            cand, d_cand = self._prune_full(pruner, sup, r, mask, qc, k,
-                                            solve)
-            cand_ext = self._ext(cand)       # storage -> caller doc ids
-            for ci, qi in enumerate(chunk):
-                order = np.argsort(d_cand[ci], kind="stable")[:k]
-                out_i[qi, :order.size] = cand_ext[order]
-                out_d[qi, :order.size] = d_cand[ci, order]
-                solved[qi] = cand.size
+            self._search_refine(queries, k, pruner, nprobe, chunks,
+                                int(refine_factor), out_i, out_d, solved)
+        elif isinstance(pruner, CascadePruner):
+            self._search_cascade(queries, k, pruner, nprobe, chunks,
+                                 out_i, out_d, solved)
+        else:
+            self._search_full(queries, k, pruner, chunks, out_i, out_d,
+                              solved)
         return SearchResult(out_i, out_d, solved)
 
     @staticmethod
     def _pad_qdoc(qmask: np.ndarray, qp: int, n_pad: int) -> jax.Array:
         """Pad a (qc, |ids|) per-query candidate mask to the solve's
-        bucketed (Qp, N_pad) shape (fillers and pad docs are False — they
-        are outside every query's residual scope by construction)."""
+        (Qp, N_pad) shape (fillers and pad docs are False — they are
+        outside every query's residual scope by construction)."""
         out = np.zeros((qp, n_pad), bool)
         out[:qmask.shape[0], :qmask.shape[1]] = qmask
         return jnp.asarray(out)
@@ -1816,18 +1889,145 @@ class WmdEngine:
         """Per-query residual scoping active for this engine's solves?"""
         return self.tol is not None and self.scope == "query"
 
-    def _threshold(self, d_seed_dev, k: int, n_seed: int):
-        """Device-side pruning threshold: per-query kth-smallest exact
-        distance among the solved seeds (+ fp slack margin). With fewer
-        than k solved docs nothing may be excluded yet -> +inf."""
-        if n_seed >= k:
-            t = jnp.sort(d_seed_dev, axis=1)[:, k - 1]
-        else:
-            t = jnp.full((d_seed_dev.shape[0],), jnp.inf,
-                         d_seed_dev.dtype)
-        return t + self.prune_slack * (jnp.abs(t) + 1.0)
+    def _thresholds(self, d_seed: np.ndarray, k: int, qp: int) -> jax.Array:
+        """Device-side pruning thresholds, (qp,): each live query's kth
+        smallest exact distance among the solved seeds (+ the fp slack
+        margin); +inf with fewer than k seeds (nothing may be excluded
+        yet), -inf on filler rows (they keep nothing). The seed columns
+        are padded to ``k * qp`` with +inf, so the sort's shape is fixed
+        by k and the staging, not by how many seeds the queries share."""
+        qg = d_seed.shape[0]
+        d = np.full((qp, max(k * qp, d_seed.shape[1])), np.inf, self.dtype)
+        d[:qg, :d_seed.shape[1]] = d_seed
+        return _threshold(jnp.asarray(d), qg,
+                          np.asarray(self.prune_slack, self.dtype), k=k)
 
-    def _prune_full(self, pruner, sup, r, mask, qc, k, solve):
+    def _group_lookup(self) -> tuple:
+        """:func:`group_lookup` of the index's groups, kept until the
+        engine's index changes."""
+        groups, lookup = self._lookup
+        if groups is not self.index.groups:
+            lookup = group_lookup(self.index.groups)
+            self._lookup = (self.index.groups, lookup)
+        return lookup
+
+    @staticmethod
+    def _member_syncs(pm, stages) -> int:
+        """Blocking reads :meth:`CascadePruner.bounds` makes for
+        ``stages``: one (the probed clusters) where a partial probe
+        restricts the RWMD stage."""
+        return int(pm is not None and "rwmd" in stages)
+
+    def _stage_chunks(self, queries, chunks, live_q) -> list:
+        """Stage every v_r chunk once for the candidate solves: [(rows in
+        ``live_q`` order, queries, r, mask, the kq pair)]."""
+        row_of = {qi: g for g, qi in enumerate(live_q)}
+        staged = []
+        for chunk, width in chunks:
+            cq = [queries[qi] for qi in chunk]
+            sup, r, mask = self._prep_chunk(cq, width)
+            staged.append(([row_of[qi] for qi in chunk], cq, r, mask,
+                           self._kq(sup, mask)))
+        return staged
+
+    def _solve_candidates(self, staged, qg: int, doc_ids: np.ndarray,
+                          qmask=None, stage: str = "seed", warm=None,
+                          prof=None):
+        """Exact solves of the storage ``doc_ids`` for every staged chunk,
+        in fixed-shape tiles of the index's doc groups (each tile's shapes
+        are the group's width and :data:`TILE_DOCS`, whatever the count of
+        ``doc_ids``; at most :data:`FEW_DOCS` ids are one tile across the
+        groups). Tiles are dispatched ahead of their reads, with at
+        most :data:`INFLIGHT_BYTES` of gathered tiles not yet read back.
+
+        ``qmask``/``prof`` (qg, |ids|) are the per-query residual scopes
+        and warm-profile docs; ``warm`` the per-chunk start profiles.
+        Returns ((qg, |ids|) host distances, NaN-checked; per chunk, the
+        warm-start profile averaged over its own docs across the tiles,
+        or None)."""
+        out = np.empty((qg, doc_ids.size), self.dtype)
+        tiles = self.index.tiles(doc_ids, TILE_DOCS, storage=True,
+                                 lookup=self._group_lookup(), few=FEW_DOCS)
+        self._dispatches += len(tiles)           # one row gather per tile
+        sel = prof if prof is not None else qmask
+        pending = []
+        unread = collections.deque()     # [entry, gathered tile bytes]
+        held = 0
+        for ci, (rows, cq, r, mask, kq) in enumerate(staged):
+            qp, n_live = r.shape[0], len(rows)
+            for grp, part in tiles:
+                t = grp.docs.idx.shape[0]
+                qm = (None if qmask is None else
+                      self._pad_qdoc(qmask[np.ix_(rows, part)], qp, t))
+                pm = (None if prof is None else
+                      self._pad_qdoc(prof[np.ix_(rows, part)], qp, t))
+                w, xp = self._solve_profiled(
+                    kq, r, mask, grp, n_live=n_live, stage=stage,
+                    qdoc_mask=qm, x0q=None if warm is None else warm[ci],
+                    prof_mask=pm)
+                self._survivor_tiles += 1
+                self._pairs_solved += n_live * part.size
+                self._slots_solved += n_live * t
+                entry = [ci, part, w, xp]
+                pending.append(entry)
+                nbytes = 4 * qp * grp.docs.idx.shape[1] * r.shape[1] * t
+                unread.append((entry, nbytes))
+                held += nbytes
+                # a gathered tile is freed once its solve has run: read the
+                # oldest results back while the tiles dispatched and not
+                # yet solved could hold more than INFLIGHT_BYTES
+                while held > INFLIGHT_BYTES and len(unread) > 1:
+                    old, old_bytes = unread.popleft()
+                    old[2] = np.asarray(old[2])
+                    held -= old_bytes
+        profs = [None] * len(staged)
+        sums = [None] * len(staged)
+        for ci, part, w, xp in pending:
+            rows = staged[ci][0]
+            out[np.ix_(rows, part)] = np.asarray(w)[:len(rows), :part.size]
+            if xp is not None:
+                # the profile is a mean over each query's own docs: weigh
+                # each tile's mean by how many of them the tile holds
+                cnt = (np.full(len(rows), part.size, np.float64)
+                       if sel is None else
+                       sel[np.ix_(rows, part)].sum(axis=1).astype(np.float64))
+                x = np.asarray(xp, np.float64)[:len(rows)] * cnt[:, None]
+                if profs[ci] is None:
+                    profs[ci], sums[ci] = np.zeros_like(x), np.zeros_like(cnt)
+                profs[ci] += x
+                sums[ci] += cnt
+        for ci, (rows, cq, r, _, _) in enumerate(staged):
+            self._raise_if_nan(out[rows], cq)
+            if profs[ci] is not None:
+                x = np.zeros((r.shape[0], r.shape[1]), self.dtype)
+                x[:len(rows)] = profs[ci] / np.maximum(sums[ci], 1.0)[:, None]
+                profs[ci] = jnp.asarray(x)
+        return out, profs
+
+    def _search_full(self, queries, k, pruner, chunks, out_i, out_d,
+                     solved):
+        """Full-sweep pruner search (``"wcd"``, ``"rwmd"``, ``"wcd+rwmd"``),
+        per v_r chunk: the pruner's (Qp, N) bound matrix, then seeds,
+        threshold and survivors (:meth:`_prune_full`), then rank."""
+        solved_ids = []
+        for chunk, width in chunks:
+            cq = [queries[qi] for qi in chunk]
+            qc = len(chunk)
+            sup, r, mask = self._prep_chunk(cq, width)
+            staged = [(list(range(qc)), cq, r, mask, self._kq(sup, mask))]
+            cand, d_cand = self._prune_full(pruner, sup, r, mask, qc, k,
+                                            staged)
+            with jax.profiler.TraceAnnotation("wmd.rank"):
+                cand_ext = self._ext(cand)    # storage -> caller doc ids
+                for ci, qi in enumerate(chunk):
+                    order = np.argsort(d_cand[ci], kind="stable")[:k]
+                    out_i[qi, :order.size] = cand_ext[order]
+                    out_d[qi, :order.size] = d_cand[ci, order]
+                    solved[qi] = cand.size
+            solved_ids.append(cand_ext)
+        self.last_solved_ids = np.unique(np.concatenate(solved_ids))
+
+    def _prune_full(self, pruner, sup, r, mask, qc, k, staged):
         """PR 2's full-sweep prune stage, with seed selection and
         thresholding moved device-side: (Qc, N) argpartition/partition
         become top_k/sort on the device bound matrix, and only compact id
@@ -1851,71 +2051,36 @@ class WmdEngine:
         lb = pruner.lower_bounds(self.index, sup, r, mask)   # (Qp, N) dev
         # seed: each query's k best-bounded docs (chunk union — extra
         # exact distances only tighten the other queries' thresholds)
-        _, seed_pos = jax.lax.top_k(-lb[:qc], k)
-        seed_pos = np.asarray(seed_pos)
-        seed = np.unique(seed_pos).astype(np.int32)
+        with jax.profiler.TraceAnnotation("wmd.seed"):
+            _, seed_pos = jax.lax.top_k(-lb, k)
+            seed_pos = np.asarray(seed_pos)[:qc]
+            self._prune_syncs += 1
+            seed = np.unique(seed_pos).astype(np.int32)
         qmask_seed = None
         if scoped:
             qmask_seed = np.stack([np.isin(seed, seed_pos[qi])
                                    for qi in range(qc)])
-        d_seed, xprof = solve(seed, None, "seed", prof=qmask_seed)
-        thresh = self._threshold(jnp.asarray(d_seed), k, seed.size)
-        surv = np.nonzero(np.asarray(_keep_any(lb, thresh)))[0] \
-            .astype(np.int32)
-        surv = surv[~np.isin(surv, seed)]
+        d_seed, xprof = self._solve_candidates(staged, qc, seed, None,
+                                               "seed", prof=qmask_seed)
+        with jax.profiler.TraceAnnotation("wmd.threshold"):
+            thresh = self._thresholds(d_seed, k, sup.shape[0])
+        with jax.profiler.TraceAnnotation("wmd.survivors"):
+            surv = np.nonzero(np.asarray(_keep_any(lb, thresh)))[0] \
+                .astype(np.int32)
+            self._prune_syncs += 1
+            surv = surv[~np.isin(surv, seed)]
         cand = np.concatenate([seed, surv])
         if not surv.size:
             return cand, d_seed
         qmask_surv = None
         if scoped:
-            qmask_surv = (np.asarray(lb[:qc, surv])
+            qmask_surv = (np.asarray(lb)[:qc, surv]
                           <= np.asarray(thresh)[:qc, None])
+            self._prune_syncs += 1
         warm = xprof if self._warms_survivors else None
-        d_surv, _ = solve(surv, qmask_surv, "survivor", warm=warm)
+        d_surv, _ = self._solve_candidates(staged, qc, surv, qmask_surv,
+                                           "survivor", warm=warm)
         return cand, np.concatenate([d_seed, d_surv], axis=1)
-
-    def _make_solver(self, queries, chunks, live_q):
-        """Stage every v_r chunk once (sup/r/mask + the kq pair) and
-        return ``solve_all(doc_ids, qmask, stage, warm, prof)`` — the
-        chunk-looped exact solve over one candidate id array, shared by
-        the cascade and refine drivers. Rows of the returned (qg, |ids|)
-        matrix follow ``live_q`` order; NaN rows raise
-        :class:`LamUnderflowError` before returning."""
-        index = self.index
-        qg = len(live_q)
-        row_of = {qi: g for g, qi in enumerate(live_q)}
-        prepped = []
-        for chunk, width in chunks:
-            cq = [queries[qi] for qi in chunk]
-            sup, r, mask = self._prep_chunk(cq, width)
-            prepped.append((chunk, cq, sup, r, mask, self._kq(sup, mask)))
-
-        def solve_all(doc_ids, qmask=None, stage="seed", warm=None,
-                      prof=None):
-            # -> ((qg, |ids|) np NaN-checked, per-chunk warm profiles)
-            out = np.empty((qg, doc_ids.size), self.dtype)
-            profs = []
-            # one gather, shared by chunks; survivor ids are cluster-sorted
-            # storage ids, so this is a near-contiguous host slice
-            grp = index.subset(doc_ids, storage=True)
-            n_pad = grp.docs.idx.shape[0]
-            for ci, (chunk, cq, sup, r, mask, kq) in enumerate(prepped):
-                rows = [row_of[qi] for qi in chunk]
-                qm = (None if qmask is None else self._pad_qdoc(
-                    qmask[rows], r.shape[0], n_pad))
-                pm = (None if prof is None else self._pad_qdoc(
-                    prof[rows], r.shape[0], n_pad))
-                w, xp = self._solve_profiled(
-                    kq, r, mask, grp, n_live=len(chunk), stage=stage,
-                    qdoc_mask=qm, x0q=None if warm is None else warm[ci],
-                    prof_mask=pm)
-                profs.append(xp)
-                w = np.asarray(w)[:len(chunk), :doc_ids.size]
-                self._raise_if_nan(w, cq)
-                out[rows] = w
-            return out, profs
-
-        return solve_all
 
     def _search_refine(self, queries, k, pruner, nprobe, chunks,
                        refine_factor, out_i, out_d, solved):
@@ -1930,155 +2095,144 @@ class WmdEngine:
         recall against the exact path is monotone; at a ``k'`` covering
         the candidate universe this IS the exact path's answer (every
         candidate solved, ranked by exact distance)."""
-        from .prune import CascadePruner, _pad_pow2_ids
+        from .prune import CascadePruner
         index = self.index
         live_q = [qi for chunk, _ in chunks for qi in chunk]
         qg = len(live_q)
         width_g = max(width for _, width in chunks)
         sup_g, r_g, mask_g = self._prep_chunk(
             [queries[qi] for qi in live_q], width_g)
-        if isinstance(pruner, CascadePruner):
-            cdists, pm, qcent = pruner.probe(index, sup_g, r_g, mask_g,
-                                             nprobe)
-            # candidate universe = union of probed clusters' members
-            # (every cluster when pm is None — the exhaustive probe)
-            keep_c = (np.ones(index.clusters.n_clusters, bool)
-                      if pm is None else np.asarray(pm)[:qg].any(axis=0))
-            cand = pruner.cluster_members(index, keep_c)
-            if cand.size == 0:
-                return
-            sp = _pad_pow2_ids(cand)
-            lb = pruner.stage_bounds(
-                pruner.stages[-1], index, sup_g, r_g, mask_g, sp,
-                cand.size,
-                pruner.id_qmask(index, pm, sp, cand.size,
-                                qp=sup_g.shape[0]), qcent=qcent)
-        else:
-            cand = np.arange(index.n_docs, dtype=np.int32)
-            sp = cand
-            lb = pruner.lower_bounds(index, sup_g, r_g, mask_g)
-        kp = min(refine_factor * k, cand.size)
-        neg, pos = jax.lax.top_k(-lb[:qg], kp)
-        neg, pos = np.asarray(neg), np.asarray(pos)
+        with jax.profiler.TraceAnnotation("wmd.seed"):
+            if isinstance(pruner, CascadePruner):
+                # the tightest stage over the probed clusters' members
+                # (+inf at each query's non-candidates)
+                _, pm, qcent = pruner.probe(index, sup_g, r_g, mask_g,
+                                            nprobe)
+                (lb,) = pruner.bounds(index, sup_g, mask_g, qcent, pm,
+                                      pruner.stages[-1:],
+                                      self._group_lookup())
+                self._prune_syncs += self._member_syncs(pm, pruner.stages[-1:])
+            else:
+                lb = pruner.lower_bounds(index, sup_g, r_g, mask_g)
+            kp = min(refine_factor * k, index.n_docs)
+            neg, pos = jax.device_get(jax.lax.top_k(-lb[:qg], kp))
+            self._prune_syncs += 1
         # per-query own picks; -inf bounds are non-candidates (a query
         # whose probed universe holds fewer than k' docs)
-        own = []
-        for g in range(qg):
-            p = pos[g][np.isfinite(neg[g])]
-            p = p[p < cand.size]
-            own.append(np.unique(sp[p]).astype(np.int32))
+        own = [np.unique(pos[g][np.isfinite(neg[g])]).astype(np.int32)
+               for g in range(qg)]
         ids = np.unique(np.concatenate(own))
         if ids.size == 0:
             return
         qmask_own = np.stack([np.isin(ids, o) for o in own])
-        solve_all = self._make_solver(queries, chunks, live_q)
-        d, _ = solve_all(ids, qmask_own if self._scoped() else None,
-                         "refine")
+        staged = self._stage_chunks(queries, chunks, live_q)
+        d, _ = self._solve_candidates(
+            staged, qg, ids, qmask_own if self._scoped() else None,
+            "refine")
         # rank each query over its OWN picks only — batch-mates' union
         # candidates are excluded so the pick-set nesting (and with it
         # the recall monotonicity) holds per query, not just per batch
-        dm = np.where(qmask_own, d, np.inf)
-        ids_ext = self._ext(ids)
-        for g, qi in enumerate(live_q):
-            n_own = int(qmask_own[g].sum())
-            order = np.argsort(dm[g], kind="stable")[:min(k, n_own)]
-            out_i[qi, :order.size] = ids_ext[order]
-            out_d[qi, :order.size] = d[g, order]
-            solved[qi] = n_own
+        with jax.profiler.TraceAnnotation("wmd.rank"):
+            dm = np.where(qmask_own, d, np.inf)
+            ids_ext = self._ext(ids)
+            for g, qi in enumerate(live_q):
+                n_own = int(qmask_own[g].sum())
+                order = np.argsort(dm[g], kind="stable")[:min(k, n_own)]
+                out_i[qi, :order.size] = ids_ext[order]
+                out_d[qi, :order.size] = d[g, order]
+                solved[qi] = n_own
+        self.last_solved_ids = ids_ext
 
     def _search_cascade(self, queries, k, pruner, nprobe, chunks,
                         out_i, out_d, solved):
-        """CascadePruner driver — sub-O(N) per-doc prune work, ONE global
-        prune pass for the whole query set:
+        """CascadePruner search — ONE prune pass for the whole query set.
 
         The bound stages don't need the solve's v_r bucketing (they read
         the (Q, B) support arrays directly), so all live queries are staged
-        once at the widest chunk's bucket and every prune dispatch covers
-        the full set — per-chunk pruning would pay the fixed dispatch
-        chain per v_r bucket for no extra precision. Flow:
+        once at the widest chunk's width and at a fixed Q pad (at least
+        ``max_batch`` rows), and every prune dispatch covers the full set.
+        Every prune program's shapes follow the index, k and that staging,
+        never the candidate counts. Flow:
 
-        1. cluster probe (one (Q, C) GEMM) + seed candidates from each
-           query's nearest probed clusters (just enough to cover k docs);
-        2. first-stage bounds on the seed candidates -> per-query best-k
-           seeds -> exact seed solve (per solve chunk) -> threshold t_q;
-        3. ``pruner.survivors``: cluster-radius triangle bound drops whole
-           clusters, then the per-doc stages cheapest-first on what
-           remains;
-        4. exact solve on the final survivors, rank.
+        1. ``wmd.probe``: cluster probe (one (Q, C) GEMM);
+        2. ``wmd.seed``: every stage's bounds of every candidate doc ->
+           per-query best-k seeds by the tightest (one blocking read) ->
+           exact seed solve (per solve chunk, in tiles);
+        3. ``wmd.threshold``: threshold t_q, on the device;
+        4. ``wmd.survivors``: ``pruner.survivors``, the cluster-radius and
+           per-doc bounds against t_q (one blocking read);
+        5. exact solve on the survivors (in tiles), ``wmd.rank``.
         """
-        from .prune import _pad_pow2_ids
         index = self.index
         live_q = [qi for chunk, _ in chunks for qi in chunk]
         qg = len(live_q)
+        qp = bucket_size(qg, self.max_batch)
         width_g = max(width for _, width in chunks)
-        sup_g, r_g, mask_g = self._prep_chunk(
-            [queries[qi] for qi in live_q], width_g)
-        cdists, pm, qcent = pruner.probe(index, sup_g, r_g, mask_g, nprobe)
-        seed_cand = pruner.seed_candidates(index, cdists, mask_g, k, pm)
-        if seed_cand.size == 0:
+        with jax.profiler.TraceAnnotation("wmd.probe"):
+            sup_g, r_g, mask_g = self._prep_chunk(
+                [queries[qi] for qi in live_q], width_g, q_pad=qp)
+            cdists, pm, qcent = pruner.probe(index, sup_g, r_g, mask_g,
+                                             nprobe)
+        lookup = self._group_lookup()
+        with jax.profiler.TraceAnnotation("wmd.seed"):
+            lbs = pruner.bounds(index, sup_g, mask_g, qcent, pm,
+                                lookup=lookup)
+            pos, real = pruner.seeds(lbs, k)
+            self._prune_syncs += 1 + self._member_syncs(pm, pruner.stages)
+            pos, real = pos[:qg], real[:qg]
+            seed = np.unique(pos[real]).astype(np.int32)
+        if seed.size == 0:
             return
-        sp = _pad_pow2_ids(seed_cand)
-        lb = pruner.stage_bounds(
-            pruner.stages[0], index, sup_g, r_g, mask_g, sp,
-            seed_cand.size,
-            pruner.id_qmask(index, pm, sp, seed_cand.size,
-                            qp=sup_g.shape[0]), qcent=qcent)
-        k_eff = min(k, seed_cand.size)
-        neg, seed_pos = jax.lax.top_k(-lb[:qg], k_eff)
-        neg = np.asarray(neg)
-        seed_pos = np.asarray(seed_pos)
-        # -inf picks are non-candidates (a query with < k_eff candidates)
-        pos_seed = np.unique(seed_pos[np.isfinite(neg)])
-        pos_seed = pos_seed[pos_seed < seed_cand.size]
-        if pos_seed.size == 0:
-            return
-        seed = sp[pos_seed]
         scoped = self._scoped()
         qmask_seed = None
         if scoped:
             # per-query seed membership: q's own finite top-k picks
-            qmask_seed = np.zeros((qg, seed.size), bool)
-            for g in range(qg):
-                own = seed_pos[g][np.isfinite(neg[g])]
-                own = own[own < seed_cand.size]
-                qmask_seed[g] = np.isin(seed, sp[own])
+            qmask_seed = np.stack([np.isin(seed, pos[g][real[g]])
+                                   for g in range(qg)])
 
         # solve stage stays v_r-bucketed: per-chunk staging, reused for
         # the seed and survivor solves
-        solve_all = self._make_solver(queries, chunks, live_q)
+        staged = self._stage_chunks(queries, chunks, live_q)
 
         # seed residual scope = the union of real seed docs (any of them
         # can contend for any query once thresholds exist); own picks
         # drive only the warm profile — see _prune_full
-        d_seed, xprofs = solve_all(seed, None, "seed", prof=qmask_seed)
-        thresh = self._threshold(jnp.asarray(d_seed), k, seed.size)
-        surv = pruner.survivors(index, sup_g, r_g, mask_g, cdists, pm,
-                                qcent, thresh, exclude=seed)
-        cand = np.concatenate([seed, surv])
-        if surv.size:
+        d_seed, xprofs = self._solve_candidates(staged, qg, seed, None,
+                                                "seed", prof=qmask_seed)
+        with jax.profiler.TraceAnnotation("wmd.threshold"):
+            thresh = self._thresholds(d_seed, k, qp)
+        with jax.profiler.TraceAnnotation("wmd.survivors"):
+            # with per-query scoping, also which query needs each
+            # survivor: the same bounds against each query's threshold
+            surv = pruner.survivors(index, sup_g, r_g, mask_g, cdists, pm,
+                                    qcent, thresh, exclude=seed, lbs=lbs,
+                                    per_query=scoped, lookup=lookup)
+            self._prune_syncs += 1
             qmask_surv = None
             if scoped:
-                # per-query survivor membership: re-bound the FINAL
-                # survivor set with the cascade's tightest stage (one
-                # extra fused dispatch on the post-prune set) against
-                # each query's own threshold
-                from .prune import _pad_pow2_ids as _pp2
-                sps = _pp2(surv)
-                lbs = pruner.stage_bounds(
-                    pruner.stages[-1], index, sup_g, r_g, mask_g, sps,
-                    surv.size,
-                    pruner.id_qmask(index, pm, sps, surv.size,
-                                    qp=sup_g.shape[0]), qcent=qcent)
-                qmask_surv = (np.asarray(lbs[:qg, :surv.size])
-                              <= np.asarray(thresh)[:qg, None])
+                surv, qmask_surv = surv[0], surv[1][:qg]
+        cand = np.concatenate([seed, surv])
+        if surv.size:
             warm = xprofs if self._warms_survivors else None
-            d_surv, _ = solve_all(surv, qmask_surv, "survivor", warm=warm)
+            d_surv, _ = self._solve_candidates(staged, qg, surv, qmask_surv,
+                                               "survivor", warm=warm)
             d_cand = np.concatenate([d_seed, d_surv], axis=1)
         else:
             d_cand = d_seed
-        cand_ext = self._ext(cand)           # storage -> caller doc ids
-        for g, qi in enumerate(live_q):
-            order = np.argsort(d_cand[g], kind="stable")[:k]
-            out_i[qi, :order.size] = cand_ext[order]
-            out_d[qi, :order.size] = d_cand[g, order]
-            solved[qi] = cand.size
+        with jax.profiler.TraceAnnotation("wmd.rank"):
+            cand_ext = self._ext(cand)       # storage -> caller doc ids
+            for g, qi in enumerate(live_q):
+                order = np.argsort(d_cand[g], kind="stable")[:k]
+                out_i[qi, :order.size] = cand_ext[order]
+                out_d[qi, :order.size] = d_cand[g, order]
+                solved[qi] = cand.size
+        self.last_solved_ids = cand_ext
+
+
+@functools.partial(jax.jit, static_argnames=("k",))
+def _threshold(d, n_live, slack, k: int):
+    """Per row of the padded (Qp, S) seed distances: the kth smallest plus
+    the fp slack margin; rows from ``n_live`` on (fillers) get -inf."""
+    t = jnp.sort(d, axis=1)[:, k - 1]
+    t = t + slack * (jnp.abs(t) + 1.0)
+    return jnp.where(jnp.arange(d.shape[0]) < n_live, t, -jnp.inf)

@@ -44,11 +44,12 @@ approximately. Hence:
 Bounds are in raw distance units (no lam): they bound the transport-cost
 part ``<P, M>``, which is exactly what the solve stage returns.
 
-``CascadePruner`` (ISSUE 3) runs these stages *cheapest-first* over a
-shrinking candidate set — IVF cluster shortlist, pivot triangle bound,
-WCD on the shortlist, RWMD only on WCD survivors (and only over the
-survivors' own vocabulary) — instead of computing every bound on every
-document; see its docstring for the exactness-vs-``nprobe`` contract.
+``CascadePruner`` adds an IVF cluster probe and a
+cluster-radius bound, and holds each (query, doc) pair to every stage at
+once (pivot triangle, WCD, RWMD) in passes of fixed shape, so a search
+step compiles nothing whatever its candidate counts; see its docstring
+for the exactness-vs-``nprobe`` contract. Every RWMD bound, of the
+full sweep and of the cascade, comes from :func:`rwmd_bounds`.
 
 Spec resolution (runnable — the CI ``docs`` job executes this as a
 doctest)::
@@ -71,6 +72,8 @@ from typing import Protocol, Sequence, runtime_checkable
 import numpy as np
 import jax
 import jax.numpy as jnp
+
+from repro.kernels.precision import FP32_GEMM
 
 from .sinkhorn import sq_dists
 
@@ -100,7 +103,8 @@ def _wcd_bounds(qcent: jax.Array, centroids: jax.Array) -> jax.Array:
 @jax.jit
 def _query_centroids(sup, r, mask, vecs):
     a = jnp.take(vecs, sup, axis=0)                  # (Qp, B, w)
-    return jnp.einsum("qb,qbw->qw", r * mask, a)     # pad rows (r==1) masked
+    return jnp.einsum("qb,qbw->qw", r * mask, a,     # pad rows (r==1) masked
+                      precision=FP32_GEMM)
 
 
 class WcdPruner:
@@ -117,23 +121,74 @@ class WcdPruner:
 # (single source of truth; ref.py imports only jax, so no core<->ops cycle)
 from repro.kernels.ref import rwmd_min_cdist_ref
 
-_min_cdist_xla = jax.jit(rwmd_min_cdist_ref)
+# Docs per gathered tile when the RWMD bound covers only some docs (a
+# cascade that probes fewer than all clusters): each tile is (Qp, T, L_g)
+# gathered distances, 12.6 MB at Qp = 8, L_g = 192.
+BOUND_TILE = 2048
 
 
 @jax.jit
-def _min_cdist_subset_xla(sup, mask, vecs, vids):
-    """Candidate-vocab min-cdist with the support/vocab gathers fused in
-    (one dispatch; the XLA twin of kernels.rwmd.rwmd_min_cdist_subset)."""
-    return rwmd_min_cdist_ref(jnp.take(vecs, sup, axis=0), mask,
-                              jnp.take(vecs, vids, axis=0))
+def _finite(minm):
+    # all-pad filler rows have minm == +inf; their bounds are don't-care
+    return jnp.where(jnp.isfinite(minm), minm, 0.0)
 
 
 @jax.jit
-def _rwmd_gather(minm: jax.Array, idx: jax.Array, val: jax.Array):
-    """Own jit on purpose: XLA CPU would otherwise fuse the cdist producer
-    into the gather and recompute it per element (see ROADMAP note)."""
-    g = jnp.take(minm, idx, axis=1)                  # (Qp, N, L)
-    return jnp.einsum("qnl,nl->qn", g, val)
+def _rwmd_minm(sup, mask, vecs):
+    """(Qp, V) distance from every vocabulary word to its nearest live
+    query word, the support gather fused in: the RWMD bound's producer."""
+    return _finite(rwmd_min_cdist_ref(jnp.take(vecs, sup, axis=0), mask,
+                                      vecs))
+
+
+@jax.jit
+def _rwmd_rows(lb, minm, pieces):
+    """Write the RWMD bounds of ELL rows into columns of ``lb`` (Qp, N):
+    each piece is ``(idx, val, cols)``, a doc group's or a gathered tile's
+    (n, L) rows and their (n,) storage ids, where an id >= N is a pad slot
+    and is dropped. Elementwise in fp32: a TPU would round a contraction's
+    operands to bf16, and the bound must not exceed the solve's score by
+    more than ``prune_slack``. Separate from the min-cdist producer on
+    purpose: XLA CPU would otherwise fuse the producer into the gather and
+    recompute it per element (see ROADMAP note)."""
+    for idx, val, cols in pieces:
+        g = jnp.take(minm, idx, axis=1)                  # (Qp, n, L)
+        lb = lb.at[:, cols].set(jnp.sum(g * val[None], axis=-1),
+                                mode="drop")
+    return lb
+
+
+def rwmd_bounds(index, sup, mask, doc_ids=None, use_kernel: bool = False,
+                interpret: bool | None = None, lookup=None) -> jax.Array:
+    """(Qp, N) device RWMD bounds for one query staging, the one
+    implementation every pruner and the cascade share.
+
+    With ``doc_ids`` None every doc is bounded, one dispatch over the
+    index's nnz groups. Otherwise only the storage ``doc_ids`` are (+inf
+    at every other doc), in fixed tiles of :data:`BOUND_TILE` docs of
+    their groups (:meth:`~.index.CorpusIndex.tiles`, which takes the
+    caller's ``lookup``), so the work follows the ids while the compiled
+    shapes follow only the groups. ``use_kernel`` computes the min-cdist
+    with the query-grid Pallas kernel (:mod:`repro.kernels.rwmd`)."""
+    if use_kernel:
+        from repro.kernels import ops
+        minm = _finite(ops.rwmd_min_cdist(jnp.take(index.vecs, sup, axis=0),
+                                          mask, index.vecs,
+                                          interpret=interpret))
+    else:
+        minm = _rwmd_minm(sup, mask, index.vecs)
+    shape = (minm.shape[0], index.n_docs)
+    if doc_ids is None:
+        return _rwmd_rows(jnp.zeros(shape, minm.dtype), minm, tuple(
+            (g.docs.idx, g.docs.val, g.cols) for g in index.groups))
+    lb = jnp.full(shape, jnp.inf, minm.dtype)
+    for grp, part in index.tiles(doc_ids, BOUND_TILE, storage=True,
+                                 lookup=lookup):
+        cols = np.full(grp.docs.idx.shape[0], index.n_docs, np.int32)
+        cols[:part.size] = grp.cols
+        lb = _rwmd_rows(lb, minm,
+                        ((grp.docs.idx, grp.docs.val, jnp.asarray(cols)),))
+    return lb
 
 
 class RwmdPruner:
@@ -153,17 +208,8 @@ class RwmdPruner:
         self.interpret = interpret
 
     def lower_bounds(self, index, sup, r, mask):
-        a = jnp.take(index.vecs, sup, axis=0)        # (Qp, B, w)
-        if self.use_kernel:
-            from repro.kernels import ops
-            minm = ops.rwmd_min_cdist(a, mask, index.vecs,
-                                      interpret=self.interpret)
-        else:
-            minm = _min_cdist_xla(a, mask, index.vecs)
-        # all-pad filler rows have minm == +inf; inf * 0-mass stays out of
-        # live rows, and callers slice fillers off anyway
-        return _rwmd_gather(jnp.where(jnp.isfinite(minm), minm, 0.0),
-                            index.docs.idx, index.docs.val)
+        return rwmd_bounds(index, sup, mask, use_kernel=self.use_kernel,
+                           interpret=self.interpret)
 
 
 class MaxPruner:
@@ -179,122 +225,11 @@ class MaxPruner:
 
 
 # ---------------------------------------------------------------- cascade
-def _pad_pow2_ids(ids: np.ndarray, min_size: int = 8) -> np.ndarray:
-    """Pow2-pad an id array (pad slots get id 0 — a valid row whose
-    computed bounds are garbage the candidacy masks exclude) so
-    data-dependent candidate counts hit a bounded set of compiled shapes."""
-    n_pad = min_size
-    while n_pad < ids.size:
-        n_pad *= 2
-    out = np.zeros(n_pad, np.int32)
-    out[:ids.size] = ids
-    return out
-
-
-# Fused per-stage jits: each cascade stage is ONE device dispatch (bounds +
-# candidacy fold), plus one tiny dispatch for the threshold compare — the
-# stage arrays are small post-shortlist, so op-by-op dispatch overhead would
-# otherwise dominate the stage compute (measured ~4x on CPU at N=8k).
-
-@jax.jit
-def _wcd_stage(qcent, centroids, ids_pad, qmask):
-    """Centroid bounds for a candidate id array, qmask folded to +inf:
-    gather candidate centroids -> cdist vs the (probe-computed) query
-    centroids -> mask."""
-    cand = jnp.take(centroids, ids_pad, axis=0)          # (Sp, w)
-    d2 = sq_dists(qcent, cand)
-    return jnp.where(qmask, jnp.sqrt(jnp.maximum(d2, 0.0)), jnp.inf)
-
-
-@jax.jit
-def _wcd_dense_keep_all(qcent, centroids, thresh):
-    """Exhaustive-probe variant of :func:`_wcd_dense_keep`: every doc is a
-    candidate of every query, so the doc -> probed-cluster lookup drops
-    out of the dispatch entirely."""
-    qc = thresh.shape[0]
-    d2 = jnp.maximum(sq_dists(qcent[:qc], centroids), 0.0)
-    return jnp.any(d2 <= jnp.square(thresh)[:, None], axis=0)
-
-
-@jax.jit
-def _wcd_dense_keep(qcent, centroids, pm, assign, thresh):
-    """Dense WCD threshold pass, ONE dispatch end to end: per-doc centroid
-    bounds over the whole corpus (no candidate gather, query centroids
-    reused from the probe, squared-distance compare — sqrt is monotone),
-    candidacy via the doc -> probed-cluster lookup, keep = any live
-    query's bound passes. The dispatch-economy twin of the gathered
-    :func:`_wcd_stage` path — the survivor pass picks by surviving-cluster
-    mass (a (Q, N) GEMM beats gather + mask dispatch chains once most docs
-    survive the cluster filter)."""
-    qc = thresh.shape[0]
-    d2 = jnp.maximum(sq_dists(qcent[:qc], centroids), 0.0)
-    cand = jnp.take(pm[:qc], assign, axis=1)             # (qc, N) candidacy
-    return jnp.any(cand & (d2 <= jnp.square(thresh)[:, None]), axis=0)
-
-
-@jax.jit
-def _pivot_stage(qd, dd, ids_pad, qmask):
-    """Pivot triangle bounds for a candidate id array, one dispatch:
-    gather candidate pivot-distance rows -> ``max_p |d(q,p) - d(n,p)|``
-    (reverse triangle inequality in the embedding metric, so it
-    lower-bounds the WCD) -> candidacy fold to +inf."""
-    cand = jnp.take(dd, ids_pad, axis=0)                 # (Sp, P)
-    lb = jnp.max(jnp.abs(qd[:, None, :] - cand[None, :, :]), axis=-1)
-    return jnp.where(qmask, lb, jnp.inf)
-
-
-@jax.jit
-def _pivot_dense_keep(qd, dd, pm, assign, thresh):
-    """Dense pivot threshold pass over the whole corpus, one dispatch —
-    the pivot twin of :func:`_wcd_dense_keep`, at O(P) per pair instead
-    of the WCD GEMM's O(w)."""
-    qc = thresh.shape[0]
-    lb = jnp.max(jnp.abs(qd[:qc, None, :] - dd[None, :, :]), axis=-1)
-    cand = jnp.take(pm[:qc], assign, axis=1)             # (qc, N) candidacy
-    return jnp.any(cand & (lb <= thresh[:, None]), axis=0)
-
-
-@jax.jit
-def _pivot_dense_keep_all(qd, dd, thresh):
-    """Exhaustive-probe variant of :func:`_pivot_dense_keep`."""
-    qc = thresh.shape[0]
-    lb = jnp.max(jnp.abs(qd[:qc, None, :] - dd[None, :, :]), axis=-1)
-    return jnp.any(lb <= thresh[:, None], axis=0)
-
-
-@jax.jit
-def _rwmd_epilogue(minm, rel, val, qmask):
-    """RWMD gather + doc-mass contraction + candidacy fold, one dispatch.
-    Separate from the min-cdist producer on purpose (the XLA CPU
-    producer-into-gather refusion hazard — see the ROADMAP note)."""
-    g = jnp.take(jnp.where(jnp.isfinite(minm), minm, 0.0), rel, axis=1)
-    lb = jnp.einsum("qnl,nl->qn", g, val)
-    return jnp.where(qmask, lb, jnp.inf)
-
-
-@jax.jit
-def _rwmd_keep(minm, rel, val, pm, assign_ids, n_real, thresh):
-    """:func:`_rwmd_epilogue` fused with candidacy lookup and the
-    threshold test — the post-threshold RWMD stage in one dispatch after
-    the min-cdist producer."""
-    qc = thresh.shape[0]
-    g = jnp.take(jnp.where(jnp.isfinite(minm), minm, 0.0), rel, axis=1)
-    lb = jnp.einsum("qnl,nl->qn", g[:qc], val)
-    cand = (jnp.take(pm[:qc], assign_ids, axis=1)
-            & (jnp.arange(assign_ids.shape[0])[None, :] < n_real))
-    return jnp.any(cand & (lb <= thresh[:, None]), axis=0)
-
-
-@jax.jit
-def _rwmd_keep_all(minm, rel, val, n_real, thresh):
-    """Exhaustive-probe variant of :func:`_rwmd_keep` (no cluster
-    candidacy lookup; only the pad tail is masked)."""
-    qc = thresh.shape[0]
-    g = jnp.take(jnp.where(jnp.isfinite(minm), minm, 0.0), rel, axis=1)
-    lb = jnp.einsum("qnl,nl->qn", g[:qc], val)
-    keep = jnp.any(lb <= thresh[:, None], axis=0)
-    return keep & (jnp.arange(rel.shape[0]) < n_real)
-
+# Stage programs of the cascade: each bounds every doc of the corpus (the
+# RWMD stage, under a partial probe, only the probed clusters' members, in
+# fixed tiles), so each program's shapes are fixed by the index and the
+# staging (Qp query rows, B words) and never by how many docs a stage lets
+# through -- nothing compiles per search step.
 
 @jax.jit
 def _keep_any(lbm, thresh):
@@ -304,25 +239,53 @@ def _keep_any(lbm, thresh):
 
 
 @jax.jit
-def _cluster_keep_fused(cdists, radii, pm, thresh):
-    """Cluster-radius filter, one dispatch: triangle bound + candidacy +
-    threshold test -> (C,) bool of clusters some live query still needs."""
-    lbm = jnp.where(pm, cdists - radii[None, :], jnp.inf)
-    return jnp.any(lbm[:thresh.shape[0]] <= thresh[:, None], axis=0)
+def _pivot_dense(qd, dd):
+    """(Qp, N) pivot triangle bounds of every doc."""
+    return jnp.max(jnp.abs(qd[:, None, :] - dd[None, :, :]), axis=-1)
 
 
 @jax.jit
-def _cluster_keep_all(cdists, radii, thresh):
-    """Exhaustive-probe variant of :func:`_cluster_keep_fused`."""
-    lbm = cdists - radii[None, :]
-    return jnp.any(lbm[:thresh.shape[0]] <= thresh[:, None], axis=0)
+def _candidates_only(lb, pm, assign):
+    """``lb`` (Qp, N) at +inf wherever the doc's cluster is not probed by
+    the query."""
+    return jnp.where(jnp.take(pm, assign, axis=1), lb, jnp.inf)
+
+
+@jax.jit
+def _probed_clusters(pm, mask):
+    """(C,) bool: clusters some live query row probes."""
+    return jnp.any(pm & jnp.any(mask > 0, axis=1)[:, None], axis=0)
+
+
+@functools.partial(jax.jit, static_argnames=("k",))
+def _seed_pick(lbs, k: int):
+    """Each query row's ``k`` best docs by the tightest of the stage
+    bounds ``lbs`` (their max): (neg bounds, positions), both (Qp, k);
+    non-candidates bound +inf, so a query with fewer than k candidates
+    reads -inf in its tail."""
+    return jax.lax.top_k(-functools.reduce(jnp.maximum, lbs), k)
+
+
+@jax.jit
+def _cascade_keep(lbs, cdists, radii, assign, thresh, exclude):
+    """Docs some live query still needs: ((N,) bool, the (Qp, N) bool per
+    query). A doc survives for query q when its cluster's radius bound
+    ``cdist(q, c) - radius_c`` and every stage bound are all at most
+    ``thresh[q]`` (non-candidates bound +inf); pad query rows carry
+    ``thresh = -inf`` and keep nothing. ``exclude`` (N,) drops the docs
+    already solved."""
+    ok = (cdists - radii[None, :])[:, assign] <= thresh[:, None]
+    for lb in lbs:
+        ok &= lb <= thresh[:, None]
+    return jnp.any(ok, axis=0) & ~exclude, ok
 
 
 @jax.jit
 def _probe_dists(sup, r, mask, vecs, centers):
     """Query centroids + cluster-center distances, one dispatch:
     (cdists (Qp, C), qcent (Qp, w) — reused by the dense WCD pass)."""
-    qcent = jnp.einsum("qb,qbw->qw", r * mask, jnp.take(vecs, sup, axis=0))
+    qcent = jnp.einsum("qb,qbw->qw", r * mask, jnp.take(vecs, sup, axis=0),
+                       precision=FP32_GEMM)
     return jnp.sqrt(jnp.maximum(sq_dists(qcent, centers), 0.0)), qcent
 
 
@@ -334,46 +297,45 @@ def _probe_mask(cdists, nprobe: int):
     return jnp.zeros(cdists.shape, bool).at[rows, idx].set(True)
 
 
-@jax.jit
-def _ids_qmask(pm, assign_ids, n_real):
-    """Per-query candidacy for a padded doc-id array: the doc's cluster
-    must be probed by the query, and the slot must be real (``n_real`` is
-    traced, so shape bucketing stays data-independent)."""
-    sub = jnp.take(pm, assign_ids, axis=1)
-    return sub & (jnp.arange(assign_ids.shape[0])[None, :] < n_real)
-
-
 class CascadePruner:
-    """Cheapest-first cascade over a shrinking candidate set: IVF cluster
-    probe + cluster-radius filter -> pivot triangle bounds -> per-doc WCD
-    -> RWMD min-cdist.
-
-    Unlike the full-sweep pruners above (one (Q, N) bound matrix), the
-    cascade's per-doc work is sub-O(N):
+    """IVF cascade: cluster probe, seed pick, then one survivor pass that
+    holds every candidate doc to the cluster-radius bound and to each
+    per-doc stage (pivot triangle -> WCD -> RWMD) at once.
 
     1. *ivf probe*: one (Q, n_clusters) GEMM against the frozen k-means
        centers. ``nprobe`` nearest clusters per query define the candidate
-       universe (all clusters when ``nprobe=None`` — the exact mode). Seed
-       docs come from each query's nearest probed clusters (just enough to
-       cover k members), so even seed selection never sweeps the corpus.
-    2. *ivf radius filter*: after the seed solve fixes the threshold t_q,
-       the triangle inequality ``wcd(q, n) >= ||qcent - center_c|| -
-       radius_c`` (:class:`~.index.IvfClusters` ``radii``) drops whole
-       clusters against t_q — their members are never touched again.
-    3. *pivot* (optional, the cheapest per-doc rung — Werner & Laber,
-       arXiv:1912.00509): ``max_p |d(q, p) - d(n, p)|`` over the
-       ``n_pivots`` reference words frozen at ``build_index``, using the
-       precomputed ``doc_pivot_d`` table — O(P) per pair vs the WCD
-       GEMM's O(w). The reverse triangle inequality makes it a lower
-       bound on WCD, so it inherits WCD's admissibility (and WCD's
-       truncated-iteration caveat) while touching no embeddings. Spelled
-       ``"ivf+pivot+wcd+rwmd"``; requires an index built with
-       ``n_pivots > 0`` (the default).
-    4. *wcd*: the centroid bound, only on surviving clusters' members.
-    5. *rwmd*: the tight bound, only on WCD survivors — and only over the
-       vocabulary those survivors actually use, so the min-cdist block
-       shrinks from (Q*B, V) to (Q*B, V_survivors)
-       (:func:`repro.kernels.rwmd.rwmd_min_cdist_subset`).
+       universe (all clusters when ``nprobe=None`` — the exact mode).
+    2. *seed pick* (:meth:`seeds`): every stage's bound over every
+       candidate doc, and each query's k best docs by the tightest of
+       them; the engine solves them exactly and takes the kth distance as
+       the threshold t_q (the tighter the seeds' bounds, the lower t_q
+       and the fewer survivors).
+    3. *survivor pass* (:meth:`survivors`): a doc survives for query q when
+       the cluster-radius triangle bound ``||qcent - center_c|| - radius_c
+       <= wcd(q, n)`` (:class:`~.index.IvfClusters` ``radii``) and every
+       stage bound are all at most t_q; the stages are
+       - *pivot* (optional — Werner & Laber, arXiv:1912.00509):
+         ``max_p |d(q, p) - d(n, p)|`` over the ``n_pivots`` reference
+         words frozen at ``build_index`` (the precomputed ``doc_pivot_d``
+         table), O(P) per pair; the reverse triangle inequality makes it
+         a lower bound on WCD, so it inherits WCD's admissibility (and
+         WCD's truncated-iteration caveat). Spelled
+         ``"ivf+pivot+wcd+rwmd"``; requires an index built with
+         ``n_pivots > 0`` (the default);
+       - *wcd*: the centroid bound, one (Q, w) x (w, N) GEMM;
+       - *rwmd*: the tight bound, each doc's mass times its words'
+         distances to the nearest query word (one (Q*B, V) min-cdist,
+         then :func:`rwmd_bounds` over the candidates' ELL rows).
+
+    :meth:`bounds` computes each stage for every doc in one dispatch; the
+    RWMD stage, the one whose work grows with the docs' words, bounds
+    only the probed clusters' members when ``nprobe`` leaves some out
+    (one blocking read of the probed clusters, then fixed tiles), so a
+    reduced probe saves its per-doc work. Every program's shapes follow
+    only the index, ``k`` and the query staging, so a search step
+    compiles nothing new whatever its candidate counts. The seed pick
+    and the survivor pass each end in one blocking read of compact host
+    arrays (seed ids; the survivor bitmap).
 
     Admissibility: the radius bound under-estimates WCD (triangle
     inequality), so at ``nprobe = n_clusters`` the drop set is contained
@@ -436,229 +398,92 @@ class CascadePruner:
         np_eff = c if nprobe is None else max(1, min(int(nprobe), c))
         cdists, qcent = _probe_dists(sup, r, mask, index.vecs, cl.centers)
         # pm None == exhaustive probe: every cluster is every query's
-        # candidate, and the hot stages skip the candidacy lookups
+        # candidate, and the stages skip the candidacy lookups
         pm = None if np_eff == c else _probe_mask(cdists, np_eff)
         return cdists, pm, qcent
 
-    def seed_candidates(self, index, cdists, mask, k: int,
-                        pm) -> np.ndarray:
-        """Seed-candidate doc ids: per live query, walk probed clusters
-        nearest-first until they cover k members; the union across the
-        chunk is returned (host — O(Q * C), never O(N))."""
-        cl = index.clusters
-        sizes = cl.sizes
-        cd = np.asarray(cdists)
-        pm_np = None if pm is None else np.asarray(pm)
-        live = np.asarray(mask).sum(axis=1) > 0
-        chosen = np.zeros(cl.n_clusters, bool)
-        for q in np.nonzero(live)[0]:
-            covered = 0
-            for c in np.argsort(cd[q], kind="stable"):
-                if (pm_np is not None and not pm_np[q, c]) or sizes[c] == 0:
-                    continue
-                chosen[c] = True
-                covered += sizes[c]
-                if covered >= k:
-                    break
-        picked = np.nonzero(chosen)[0]
-        if picked.size == 0:
-            return np.zeros(0, np.int32)
-        # cluster-sorted storage ids: with the index's cluster-major layout
-        # (ISSUE 4) this concat of per-cluster slices is a near-contiguous
-        # run of storage rows — exactly what subset()'s gather wants
-        return np.concatenate(
-            [cl.order[cl.starts[c]:cl.starts[c + 1]] for c in picked])
-
-    def id_qmask(self, index, pm, ids_pad: np.ndarray, n_real: int,
-                 qp: int | None = None) -> jax.Array:
-        """(Qp, Sp) candidacy for a padded id array (see _ids_qmask).
-        ``pm=None`` (exhaustive probe) needs ``qp`` to shape the valid-slot
-        mask."""
-        if pm is None:
-            valid = jnp.arange(ids_pad.size) < n_real
-            return jnp.broadcast_to(valid[None, :], (qp, ids_pad.size))
-        assign_ids = jnp.asarray(
-            index.clusters.assign[ids_pad].astype(np.int32))
-        return _ids_qmask(pm, assign_ids, n_real)
-
-    def cluster_keep(self, index, cdists, pm, thresh) -> np.ndarray:
-        """(C,) host bool: clusters some live query still needs, by the
-        cluster-radius triangle bound against the threshold."""
-        radii = index.clusters.radii.astype(np.float32)
-        if pm is None:
-            return np.asarray(_cluster_keep_all(cdists, radii, thresh))
-        return np.asarray(_cluster_keep_fused(cdists, radii, pm, thresh))
-
-    def cluster_members(self, index, keep_c: np.ndarray) -> np.ndarray:
-        """Cluster-sorted doc ids of the kept clusters (host slice concat —
-        a near-contiguous storage run under the cluster-major layout)."""
-        cl = index.clusters
-        kept = np.nonzero(keep_c[:cl.n_clusters])[0]
-        if kept.size == 0:
-            return np.zeros(0, np.int32)
-        return np.concatenate(
-            [cl.order[cl.starts[c]:cl.starts[c + 1]] for c in kept])
-
-    # --------------------------------------- post-threshold survivor pass
-    def survivors(self, index, sup, r, mask, cdists, pm, qcent, thresh,
-                  exclude: np.ndarray | None = None,
-                  dense_cutoff: float = 0.25) -> np.ndarray:
-        """The post-threshold prune pass, cheapest-first: cluster-radius
-        filter, then the per-doc stages on what remains. Returns surviving
-        doc ids (``exclude`` — typically the already-solved seeds —
-        removed). Shared by ``WmdEngine._prune_cascade`` and the fig9
-        prune-stage benchmark, so the measured pass IS the serving pass.
-
-        When the cluster filter keeps most of the corpus (loose clusters,
-        or simply a hard query), the gathered per-doc WCD stage is replaced
-        by :func:`_wcd_dense_keep` — one dense dispatch over all docs beats
-        gather + mask dispatch chains precisely when the gather wouldn't
-        shrink the problem (the radius bound under-estimates every
-        member's WCD, so the dense threshold test subsumes the cluster
-        filter)."""
-        cl = index.clusters
-        radii = cl.radii.astype(np.float32)
-        stages = self.stages
-        # dispatch the cluster filter and the (speculative) dense
-        # first-stage pass back to back, then sync once — the dense result
-        # is discarded in the rare tight-cluster case where the gather
-        # path wins, but the serial dispatch->sync->dispatch latency it
-        # saves dominates its (Q, N) cost on every other call. The pivot
-        # stage gets the same treatment as WCD (its dense pass is O(P)
-        # per pair, cheaper still).
-        qd = None
-        if stages[0] == "pivot":
-            if index.pivots is None:
-                raise ValueError("cascade has a 'pivot' stage but the "
-                                 "index has no pivot words — rebuild with "
-                                 "build_index(n_pivots > 0)")
-            from .index import _pivot_dists
-            qd = _pivot_dists(qcent, index.pivots)
-        if pm is None:
-            keep_c_dev = _cluster_keep_all(cdists, radii, thresh)
-            if stages[0] == "wcd":
-                keep_d_dev = _wcd_dense_keep_all(qcent, index.centroids,
-                                                 thresh)
-            elif qd is not None:
-                keep_d_dev = _pivot_dense_keep_all(qd, index.doc_pivot_d,
-                                                   thresh)
-            else:
-                keep_d_dev = None
-        else:
-            keep_c_dev = _cluster_keep_fused(cdists, radii, pm, thresh)
-            if stages[0] == "wcd":
-                keep_d_dev = _wcd_dense_keep(qcent, index.centroids, pm,
-                                             cl.assign_dev, thresh)
-            elif qd is not None:
-                keep_d_dev = _pivot_dense_keep(qd, index.doc_pivot_d, pm,
-                                               cl.assign_dev, thresh)
-            else:
-                keep_d_dev = None
-        keep_c = np.asarray(keep_c_dev)
-        kept_docs = int(cl.sizes[keep_c[:cl.n_clusters]].sum())
-        if (keep_d_dev is not None
-                and kept_docs >= dense_cutoff * index.n_docs):
-            surv = np.nonzero(np.asarray(keep_d_dev))[0].astype(np.int32)
-            stages = stages[1:]
-        else:
-            surv = self.cluster_members(index, keep_c)
-        if exclude is not None and exclude.size and surv.size:
-            surv = surv[~np.isin(surv, exclude)]
-        for stage in stages:
-            if surv.size == 0:
-                break
-            sp = _pad_pow2_ids(surv)
-            if stage == "rwmd":
-                prep = self._rwmd_prep(index, sup, mask, sp, surv.size)
-                if prep is None:
-                    break
-                minm, rel, val = prep
-                rel, val = jnp.asarray(rel), jnp.asarray(val)
-                if pm is None:
-                    keep = np.asarray(_rwmd_keep_all(
-                        minm, rel, val, surv.size, thresh))
-                else:
-                    assign_ids = jnp.asarray(cl.assign[sp].astype(np.int32))
-                    keep = np.asarray(_rwmd_keep(
-                        minm, rel, val, pm, assign_ids, surv.size, thresh))
-            else:
-                lbm = self.stage_bounds(
-                    stage, index, sup, r, mask, sp, surv.size,
-                    self.id_qmask(index, pm, sp, surv.size,
-                                  qp=sup.shape[0]), qcent=qcent)
-                keep = np.asarray(_keep_any(lbm, thresh))
-            surv = surv[keep[:surv.size]]
-        return surv
-
-    # ----------------------------------------------------- bounded stages
-    def stage_bounds(self, stage: str, index, sup, r, mask,
-                     ids_pad: np.ndarray, n_real: int, qmask: jax.Array,
-                     qcent: jax.Array | None = None) -> jax.Array:
-        """Masked lower bounds for one cascade stage on a candidate id
-        array: (Qp, Sp) device, +inf wherever ``qmask`` is False (pad
-        slots and per-query non-candidates). One fused dispatch per stage
-        (plus the min-cdist producer for RWMD). Pass the ``qcent`` the
-        probe already computed to skip recomputing query centroids."""
-        if stage in ("wcd", "pivot"):
-            if qcent is None:
-                qcent = _query_centroids(sup, r, mask, index.vecs)
-            if stage == "pivot":
+    def bounds(self, index, sup, mask, qcent, pm, stages=None,
+               lookup=None) -> tuple:
+        """(Qp, N) device bounds, one array per stage (the pruner's own
+        by default), +inf wherever the doc's cluster is not probed by the
+        query. ``lookup`` is the engine's kept ``group_lookup`` for the
+        RWMD stage's tiles under a partial probe, where that stage costs
+        one blocking read."""
+        out = []
+        for stage in self.stages if stages is None else stages:
+            if stage == "wcd":
+                lb = _wcd_bounds(qcent, index.centroids)
+            elif stage == "pivot":
                 if index.pivots is None:
                     raise ValueError(
                         "cascade has a 'pivot' stage but the index has no "
                         "pivot words — rebuild with build_index("
                         "n_pivots > 0)")
                 from .index import _pivot_dists
-                return _pivot_stage(_pivot_dists(qcent, index.pivots),
-                                    index.doc_pivot_d,
-                                    jnp.asarray(ids_pad), qmask)
-            return _wcd_stage(qcent, index.centroids,
-                              jnp.asarray(ids_pad), qmask)
-        return self._rwmd_subset(index, sup, mask, ids_pad, n_real, qmask)
+                lb = _pivot_dense(_pivot_dists(qcent, index.pivots),
+                                  index.doc_pivot_d)
+            else:
+                lb = rwmd_bounds(
+                    index, sup, mask,
+                    None if pm is None else self.probed_members(index, pm,
+                                                                mask),
+                    self.use_kernel, self.interpret, lookup)
+            out.append(lb if pm is None else _candidates_only(
+                lb, pm, index.clusters.assign_dev))
+        return tuple(out)
 
-    def _rwmd_prep(self, index, sup, mask, ids_pad, n_real):
-        """Shared RWMD-subset prep: gather candidate rows host-side (like
-        ``CorpusIndex.subset``), remap their word ids into the compact
-        candidate-vocab space, min-cdist only those embedding rows — the
-        (Q*B, V) block shrinks to (Q*B, V_survivors). Returns
-        (minm device, rel np, val np) or None when the subset is empty."""
-        idx = index.docs_host.idx[ids_pad]
-        val = index.docs_host.val[ids_pad].copy()
-        val[n_real:] = 0.0                    # pad rows out of the vocab
-        nnz = (val > 0).sum(axis=1)
-        lg = max(1, int(nnz.max(initial=0)))
-        lg = min(-(-lg // 8) * 8, idx.shape[1])
-        idx, val = idx[:, :lg], val[:, :lg]
-        live = val > 0
-        vids = np.unique(idx[live])
-        if vids.size == 0:
-            return None
-        rel = np.searchsorted(vids, idx).astype(np.int32)
-        rel[~live] = 0
-        # pow2-bucket the candidate vocab so data-dependent survivor sets
-        # don't compile a fresh min-cdist per step (pad ids repeat vids[0];
-        # the padded columns are computed but never gathered)
-        vids_pad = _pad_pow2_ids(vids, min_size=128)
-        vids_pad[vids.size:] = vids[0]
-        if self.use_kernel:
-            from repro.kernels import ops
-            minm = ops.rwmd_min_cdist(
-                jnp.take(index.vecs, sup, axis=0), mask, index.vecs,
-                interpret=self.interpret,
-                vocab_ids=jnp.asarray(vids_pad, jnp.int32))
-        else:
-            minm = _min_cdist_subset_xla(sup, mask, index.vecs,
-                                         jnp.asarray(vids_pad, jnp.int32))
-        return minm, rel, val
+    def probed_members(self, index, pm, mask) -> np.ndarray:
+        """Storage ids (ascending) of the clusters some live query probes;
+        one blocking read of a (C,) bool."""
+        cl = index.clusters
+        kept = np.nonzero(np.asarray(_probed_clusters(pm, mask)))[0]
+        if kept.size == 0:
+            return np.zeros(0, np.int32)
+        return np.sort(np.concatenate(
+            [cl.order[cl.starts[c]:cl.starts[c + 1]] for c in kept]))
 
-    def _rwmd_subset(self, index, sup, mask, ids_pad, n_real, qmask):
-        """Masked RWMD bounds on a candidate subset (see _rwmd_prep)."""
-        prep = self._rwmd_prep(index, sup, mask, ids_pad, n_real)
-        if prep is None:
-            return jnp.where(qmask, 0.0, jnp.inf)
-        minm, rel, val = prep
-        return _rwmd_epilogue(minm, jnp.asarray(rel), jnp.asarray(val),
-                              qmask)
+    def seeds(self, lbs, k: int):
+        """Each query row's ``k`` best docs by the tightest of the stage
+        :meth:`bounds` ``lbs``: (positions (Qp, k) host storage ids, real
+        (Qp, k) host bool — False where a query has fewer than k
+        candidates). One dispatch, one blocking read."""
+        neg, pos = jax.device_get(_seed_pick(tuple(lbs), k=k))
+        return pos, np.isfinite(neg)
+
+    # --------------------------------------- post-threshold survivor pass
+    def survivors(self, index, sup, r, mask, cdists, pm, qcent, thresh,
+                  exclude: np.ndarray | None = None, lbs: tuple = (),
+                  per_query: bool = False, lookup=None):
+        """The post-threshold prune pass: storage ids (ascending, so
+        cluster-major) of the docs some live query still needs, with
+        ``exclude`` — typically the already-solved seeds — removed.
+        ``thresh`` holds one threshold per live query row; rows past it
+        (fillers) keep nothing. ``lbs`` are the :meth:`bounds` of the
+        pruner's first stages when the caller has them already.
+        ``per_query`` also returns the (Qp, |ids|) host bool of which
+        query needs each survivor. Shared by
+        ``WmdEngine._search_cascade`` and the fig9 prune-stage benchmark,
+        so the measured pass IS the serving pass. One dispatch chain, one
+        blocking read."""
+        cl = index.clusters
+        qp = sup.shape[0]
+        if thresh.shape[0] < qp:
+            thresh = jnp.concatenate([thresh, jnp.full(
+                (qp - thresh.shape[0],), -jnp.inf, thresh.dtype)])
+        excl = np.zeros(index.n_docs, bool)
+        if exclude is not None:
+            excl[exclude] = True
+        lbs = tuple(lbs)
+        if len(lbs) < len(self.stages):
+            lbs += self.bounds(index, sup, mask, qcent, pm,
+                               self.stages[len(lbs):], lookup)
+        keep, ok = _cascade_keep(
+            lbs, cdists, jnp.asarray(cl.radii, jnp.float32), cl.assign_dev,
+            thresh, jnp.asarray(excl))
+        surv = np.nonzero(np.asarray(keep))[0].astype(np.int32)
+        if per_query:
+            return surv, np.asarray(ok)[:, surv]
+        return surv
 
 
 PRUNERS = ("wcd", "rwmd", "wcd+rwmd", "ivf", "ivf+wcd", "ivf+rwmd",

@@ -68,27 +68,12 @@ def cdist_exp(a, b, r, lam: float, block_v: int = 512,
 
 
 def rwmd_min_cdist(a, mask, b, block_v: int = 512,
-                   interpret: bool | None = None, vocab_ids=None):
+                   interpret: bool | None = None):
     """Masked min-over-support cdist with auto-padding (the RWMD prune
-    stage). a (Q, B, w), mask (Q, B), b (V, w) -> minM (Q, V).
-
-    ``vocab_ids`` (Vc,) int32 switches to the candidate-subset kernel path:
-    only those vocabulary rows are streamed (the cascade's
-    RWMD-on-survivors stage) and the result is (Q, Vc) in ``vocab_ids``
-    order. Ids are padded to the block size with id 0 — callers index the
-    result by candidate position, never by the padded tail."""
+    stage). a (Q, B, w), mask (Q, B), b (V, w) -> minM (Q, V)."""
     interpret = resolve_interpret(interpret)
-    q, bq, w = a.shape
     ap = pad_to(pad_to(a, 2, 128), 1, 8)
     maskp = pad_to(mask, 1, 8)               # pad support rows masked out
-    if vocab_ids is not None:
-        vc = vocab_ids.shape[0]
-        bp = pad_to(b, 1, 128)
-        vidp = pad_to(jnp.asarray(vocab_ids, jnp.int32), 0, block_v)
-        minm = _rwmd.rwmd_min_cdist_subset(ap, maskp, bp, vidp,
-                                           block_v=block_v,
-                                           interpret=interpret)
-        return minm[:, :vc]
     v = b.shape[0]
     bp = pad_to(pad_to(b, 1, 128), 0, block_v)
     minm = _rwmd.rwmd_min_cdist(ap, maskp, bp, block_v=block_v,

@@ -84,21 +84,3 @@ def rwmd_min_cdist(a: jax.Array, mask: jax.Array, b: jax.Array,
         interpret=interpret,
     )(a, mask.reshape(q, bq, 1), b)
     return out.reshape(q, v)
-
-
-@functools.partial(jax.jit, static_argnames=("block_v", "interpret"))
-def rwmd_min_cdist_subset(a: jax.Array, mask: jax.Array, b: jax.Array,
-                          vocab_ids: jax.Array, block_v: int = 512,
-                          interpret: bool = False) -> jax.Array:
-    """Candidate-vocab min-cdist: the cascade's RWMD stage only needs the
-    words that actually appear in the surviving documents, so the caller
-    passes their (padded) id array and the streamed vocab side shrinks from
-    (V, w) to (Vc, w) — the (Q*B, V) distance block becomes (Q*B, Vc).
-
-    The gather sits at the kernel boundary (XLA gather feeding the Pallas
-    launch, same split as the solve stage's G gather). ``vocab_ids`` (Vc,)
-    must be ``block_v``-aligned — pad with any valid id; padded columns are
-    garbage the caller's compact gather never reads. Returns (Q, Vc).
-    """
-    return rwmd_min_cdist(a, mask, jnp.take(b, vocab_ids, axis=0),
-                          block_v=block_v, interpret=interpret)

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import WmdEngine, build_index
-from repro.core.index import _gather_g, _solve_gathered
+from repro.core.index import DocGroup, _gather_g, _solve_gathered
 from repro.kernels import ops
 
 
@@ -90,7 +90,10 @@ def _group_mask(engine, queries, doc_scopes, width):
     n = index.n_docs
     sup, r, mask = engine._prep_chunk(queries, width)
     all_ids = np.arange(n, dtype=np.int32)
-    grp = index.subset(all_ids, storage=True)
+    # the whole corpus as one candidate tile: an index of one doc group
+    ((grp, _),) = index._replace(groups=(DocGroup(
+        docs=index.docs, cols=jnp.asarray(all_ids)),)).tiles(
+            all_ids, 8 * (-(-n // 8)), storage=True)
     n_pad = grp.docs.idx.shape[0]
     qdoc = np.zeros((sup.shape[0], n_pad), bool)
     for qi, scope in enumerate(doc_scopes):

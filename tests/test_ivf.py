@@ -1,6 +1,6 @@
 """IVF cascade (ISSUE 3): exactness at nprobe=all, frozen-cluster appends,
-recall monotonicity in nprobe, the candidate-subset RWMD kernel, and the
-underflow guards folded into the low-level solvers."""
+recall monotonicity in nprobe, the RWMD bound on the probed candidates
+only, and the underflow guards folded into the low-level solvers."""
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -11,12 +11,11 @@ from repro.core import (CascadePruner, LamUnderflowError, WmdEngine,
                         select_support)
 from repro.core.distributed import sinkhorn_wmd_sparse_distributed
 from repro.core.index import _assign_clusters
-from repro.core.prune import RwmdPruner, _min_cdist_xla, _pad_pow2_ids
+from repro.core import prune as prune_mod
+from repro.core.prune import RwmdPruner
 from repro.core.sinkhorn_sparse import sinkhorn_wmd_sparse
 from repro.core.sparse import PaddedDocs
 from repro.data.corpus import make_corpus
-from repro.kernels import ops
-from repro.kernels.ref import rwmd_min_cdist_ref
 
 
 @pytest.fixture(scope="module")
@@ -154,43 +153,63 @@ def test_small_nprobe_pads_result_rows(corpus):
         assert (got[:n_real] >= 0).all()
 
 
-# ------------------------------------------------- candidate-subset kernel
-def test_rwmd_subset_kernel_matches_full_sweep(rng):
-    a = jnp.asarray(rng.standard_normal((3, 12, 40)).astype(np.float32))
-    b = jnp.asarray(rng.standard_normal((300, 40)).astype(np.float32))
-    mask = jnp.asarray((rng.random((3, 12)) > 0.3).astype(np.float32))
-    mask = mask.at[:, 0].set(1.0)
-    vids = np.unique(rng.integers(0, 300, 70)).astype(np.int32)
-    want = np.asarray(rwmd_min_cdist_ref(a, mask, b))[:, vids]
-    got = ops.rwmd_min_cdist(a, mask, b, block_v=128,
-                             vocab_ids=jnp.asarray(vids))
-    assert got.shape == (3, vids.size)
-    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-4)
-    got_xla = np.asarray(_min_cdist_xla(a, mask, jnp.take(b,
-                                        jnp.asarray(vids), axis=0)))
-    np.testing.assert_allclose(got_xla, want, rtol=1e-6, atol=1e-6)
+# ------------------------------------------ RWMD bound on probed members
+def _staged(engine, queries):
+    _, chunks = engine._plan(queries)
+    chunk, width = chunks[0]
+    return (len(chunk),) + engine._prep_chunk(
+        [queries[qi] for qi in chunk], width)
+
+
+@pytest.mark.parametrize("tile", [4, 2048])
+def test_rwmd_bounds_on_ids_match_every_doc(corpus, engine, monkeypatch,
+                                            tile):
+    """The RWMD bound of chosen docs, gathered in tiles of their groups
+    (several tiles per group at ``tile`` 4), equals the every-doc pass
+    at those docs and is +inf at every other doc."""
+    monkeypatch.setattr(prune_mod, "BOUND_TILE", tile)
+    qc, sup, r, mask = _staged(engine, list(corpus.queries[:4]))
+    index = engine.index
+    full = np.asarray(prune_mod.rwmd_bounds(index, sup, mask))[:qc]
+    ids = np.asarray([3, 5, 17, 41, 42, 43, 60, 90, 95], np.int32)
+    got = np.asarray(prune_mod.rwmd_bounds(index, sup, mask, ids))[:qc]
+    np.testing.assert_array_equal(got[:, ids], full[:, ids])
+    rest = np.setdiff1d(np.arange(index.n_docs), ids)
+    assert np.isinf(got[:, rest]).all()
+
+
+def test_partial_probe_bounds_only_probed_members(corpus, engine):
+    """Under nprobe < n_clusters the cascade's RWMD stage reads only the
+    probed clusters' members: every other doc bounds +inf, and each
+    query's own candidates keep the every-doc bound."""
+    qc, sup, r, mask = _staged(engine, list(corpus.queries[:4]))
+    index = engine.index
+    casc = CascadePruner(nprobe=2)
+    _, pm, qcent = casc.probe(index, sup, r, mask)
+    (lb,) = casc.bounds(index, sup, mask, qcent, pm, ("rwmd",))
+    lb = np.asarray(lb)[:qc]
+    full = np.asarray(RwmdPruner().lower_bounds(index, sup, r, mask))[:qc]
+    own = np.asarray(pm)[:qc][:, index.clusters.assign]      # (qc, N)
+    np.testing.assert_array_equal(lb[own], full[own])
+    assert np.isinf(lb[~own]).all()
+    members = casc.probed_members(index, pm, mask)
+    assert set(members) == set(np.nonzero(own.any(axis=0))[0])
+    assert members.size < index.n_docs
 
 
 @pytest.mark.parametrize("use_kernel", [False, True])
 def test_cascade_rwmd_stage_matches_full_pruner(corpus, engine, use_kernel):
-    """The cascade's vocab-subset RWMD bounds == the full-sweep RwmdPruner
-    columns for the same docs (the bound itself must not change when the
-    vocabulary shrinks to the candidates' support)."""
-    queries = list(corpus.queries[:4])
+    """The cascade's RWMD stage == the full-sweep RwmdPruner's bounds
+    (one implementation, rwmd_bounds), whether its min-cdist runs in XLA
+    or in the Pallas kernel."""
+    qc, sup, r, mask = _staged(engine, list(corpus.queries[:4]))
     index = engine.index
-    _, chunks = engine._plan(queries)
-    chunk, width = chunks[0]
-    sup, r, mask = engine._prep_chunk([queries[qi] for qi in chunk], width)
     full = np.asarray(RwmdPruner().lower_bounds(index, sup, r, mask))
     casc = CascadePruner(use_kernel=use_kernel,
                          interpret=True if use_kernel else None)
-    ids = np.asarray([3, 17, 41, 90, 5], np.int32)
-    sp = _pad_pow2_ids(ids)
-    qm = casc.id_qmask(index, None, sp, ids.size, qp=sup.shape[0])
-    lb = np.asarray(casc.stage_bounds("rwmd", index, sup, r, mask, sp,
-                                      ids.size, qm))
-    np.testing.assert_allclose(lb[:len(chunk), :ids.size],
-                               full[:len(chunk)][:, ids],
+    _, pm, qcent = casc.probe(index, sup, r, mask)
+    (lb,) = casc.bounds(index, sup, mask, qcent, pm, ("rwmd",))
+    np.testing.assert_allclose(np.asarray(lb)[:qc], full[:qc],
                                rtol=5e-5, atol=5e-5)
 
 

@@ -18,7 +18,7 @@ from repro.core import (LamUnderflowError, MaxPruner, RwmdPruner,
                         build_index, one_to_many, resolve_pruner,
                         select_support)
 from repro.core.exact_ot import exact_emd
-from repro.core.prune import _min_cdist_xla
+from repro.core.prune import _rwmd_minm
 from repro.core.sinkhorn import cdist
 from repro.core.sparse import PaddedDocs
 from repro.data.corpus import make_corpus
@@ -122,8 +122,12 @@ def test_rwmd_min_cdist_kernel_matches_ref(rng):
     got = ops.rwmd_min_cdist(a, mask, b, block_v=128)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
-    got_xla = _min_cdist_xla(a, mask, b)
-    np.testing.assert_allclose(np.asarray(got_xla), np.asarray(want),
+    # the program's XLA producer takes the query words from the
+    # vocabulary by id
+    sup = jnp.asarray(rng.integers(0, 300, (3, 12)).astype(np.int32))
+    want_v = rwmd_min_cdist_ref(jnp.take(b, sup, axis=0), mask, b)
+    got_xla = _rwmd_minm(sup, mask, b)
+    np.testing.assert_allclose(np.asarray(got_xla), np.asarray(want_v),
                                rtol=1e-6, atol=1e-6)
 
 
@@ -219,18 +223,27 @@ def test_subset_solve_matches_full_columns(corpus, engine):
     full = np.asarray(engine.query_batch(queries))
     doc_ids = np.asarray([5, 17, 3, 90, 41], np.int32)
     _, chunks = engine._plan(queries)
+    tiles = engine.index.tiles(doc_ids, 8)
+    # fixed-shape tiles of the doc groups: every tile holds 8 doc slots
+    # (inert pad docs past its real ones) at its group's own width, and
+    # together the tiles hold each id once
+    np.testing.assert_array_equal(
+        np.sort(np.concatenate([part for _, part in tiles])), np.arange(5))
+    for grp, part in tiles:
+        np.testing.assert_array_equal(grp.cols, doc_ids[part])
+        assert grp.docs.idx.shape[0] == 8
+        assert grp.docs.idx.shape[1] in {
+            g.docs.idx.shape[1] for g in engine.index.groups}
+        assert not np.asarray(grp.docs.val)[part.size:].any()
     for chunk, width in chunks:
         sup, r, mask = engine._prep_chunk([queries[qi] for qi in chunk],
                                           width)
-        grp = engine.index.subset(doc_ids)
-        # shape-bucketed: doc count padded to pow2 (inert all-zero docs),
-        # cols keeps only the real ids
-        assert grp.cols.shape[0] == doc_ids.size
-        assert grp.docs.idx.shape[0] == 8
-        w = np.asarray(engine._solve_group(engine._kq(sup, mask), r, mask,
-                                           grp))[:len(chunk), :doc_ids.size]
-        np.testing.assert_allclose(w, full[np.ix_(chunk, doc_ids)],
-                                   rtol=1e-5, atol=1e-6)
+        for grp, part in tiles:
+            w = np.asarray(engine._solve_group(engine._kq(sup, mask), r,
+                                               mask, grp))
+            np.testing.assert_allclose(
+                w[:len(chunk), :part.size],
+                full[np.ix_(chunk, doc_ids[part])], rtol=1e-5, atol=1e-6)
 
 
 # ---------------------------------------------------------- streaming index

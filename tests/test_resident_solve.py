@@ -110,7 +110,10 @@ def test_selection_rule(index, queries, monkeypatch, case, resident):
     out = eng._solve_group(kq, r, mask, grp, n_live=2, **kw)
     wmd = out[0] if "want_profile" in kw else out
     assert eng.host_stats() == {"dispatches": 3, "host_syncs": 0,
-                                "resident_solves": int(resident)}
+                                "resident_solves": int(resident),
+                                "search_queries": 0, "pairs_solved": 0,
+                                "slots_solved": 0, "survivor_tiles": 0,
+                                "prune_syncs": 0}
     assert wmd.shape == (r.shape[0], grp.docs.idx.shape[0])
     eng.reset_host_stats()
     assert eng.host_stats()["resident_solves"] == 0

@@ -390,15 +390,15 @@ def test_cluster_major_storage_invariants(corpus):
     for grp in index.groups:
         cols = np.asarray(grp.cols)
         assert (np.diff(cols) >= 0).all()  # cluster-major within the group
-    # public subset() takes caller-order ids
+    # public tiles() takes caller-order ids
     ids = np.asarray([5, 17, 3], np.int32)
-    grp = index.subset(ids)
-    np.testing.assert_array_equal(np.asarray(grp.cols), ids)
-    want_rows = index.remap[ids]
-    np.testing.assert_array_equal(
-        np.asarray(grp.docs.idx)[: ids.size],
-        np.asarray(index.docs.idx)[want_rows][:, : grp.docs.idx.shape[1]],
-    )
+    for grp, part in index.tiles(ids, 8):
+        np.testing.assert_array_equal(grp.cols, ids[part])
+        want_rows = index.remap[ids[part]]
+        np.testing.assert_array_equal(
+            np.asarray(grp.docs.idx)[: part.size],
+            np.asarray(index.docs.idx)[want_rows][:, : grp.docs.idx.shape[1]],
+        )
 
 
 def test_cluster_major_append_search_matches_rebuild():

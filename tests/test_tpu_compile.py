@@ -188,3 +188,80 @@ def test_shard_merge_compiles_for_four_v5e_chips(topo):
     ops = re.findall(r"= \S+ (all-gather|all-reduce|all-to-all|"
                      r"reduce-scatter|collective-permute)(?:-start)?\(", text)
     assert ops == ["all-gather"], ops
+
+
+# Kusner's 20NEWS deployment: 29,671 words, 11,293 docs in four nnz groups
+# of 2,824 (the last 2,821) of ELL widths 45, 64, 89 and 192, searched
+# in candidate tiles of 512 docs by chunks of up to 4 queries staged at
+# up to 192 words, with k = 19
+NEWS_V, NEWS_N, NEWS_TILE, NEWS_K, NEWS_C = 29_671, 11_293, 512, 19, 106
+NEWS_GROUPS = ((2_824, 45), (2_824, 64), (2_824, 89), (2_821, 192))
+
+
+@pytest.mark.parametrize("length", [length for _, length in NEWS_GROUPS])
+def test_news_survivor_tile_compiles(length, one_chip):
+    """One survivor tile of each 20NEWS doc group at B = 192, Q = 4: the
+    tile's rows gathered from its group, the gather and the resident
+    solve."""
+    from repro.core.index import _tile_rows
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    n_group = dict((l, n) for n, l in NEWS_GROUPS)[length]
+    rows = jax.jit(_tile_rows).lower(
+        spec((n_group, length), jnp.int32), spec((n_group, length)),
+        spec((NEWS_TILE,), jnp.int32), spec((), jnp.int32)).compile()
+    assert [o.shape for o in rows.out_info] == [(NEWS_TILE, length)] * 2
+    _compile_resident(NEWS_V, NEWS_TILE, length, 192, 4, one_chip)
+
+
+def test_news_few_docs_tile_compiles(one_chip):
+    """A step's seeds as one tile of FEW_DOCS docs across the 20NEWS
+    groups, its rows gathered from the corpus' ELL arrays at the widest
+    group's width (192), at B = 192 and Q = 4."""
+    from repro.core.index import FEW_DOCS, _tile_rows
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    rows = _tile_rows.lower(
+        spec((NEWS_N, 192), jnp.int32), spec((NEWS_N, 192)),
+        spec((FEW_DOCS,), jnp.int32), spec((), jnp.int32),
+        width=192).compile()
+    assert [o.shape for o in rows.out_info] == [(FEW_DOCS, 192)] * 2
+    _compile_resident(NEWS_V, FEW_DOCS, 192, 192, 4, one_chip)
+
+
+def _news_groups(spec):
+    return tuple((spec((n, length), jnp.int32), spec((n, length)),
+                  spec((n,), jnp.int32)) for n, length in NEWS_GROUPS)
+
+
+def test_news_cascade_bounds_compile(one_chip):
+    """The cascade's bound programs for 20NEWS at a staging width of 192
+    words and a Q pad of 4: probe, seed pick, threshold, and the dense
+    survivor pass (WCD, RWMD min-cdist and bounds, the fused keep)."""
+    from repro.core.index import _threshold
+    from repro.core.prune import (_cascade_keep, _probe_dists, _rwmd_minm,
+                                  _rwmd_rows, _seed_pick, _wcd_bounds)
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    q, b, n = 4, 192, NEWS_N
+    sup, mask = spec((q, b), jnp.int32), spec((q, b))
+    vecs = spec((NEWS_V, W))
+    cases = [
+        (_probe_dists, (sup, mask, mask, vecs, spec((NEWS_C, W)))),
+        (_wcd_bounds, (spec((q, W)), spec((n, W)))),
+        (_rwmd_minm, (sup, mask, vecs)),
+        (_rwmd_rows, (spec((q, n)), spec((q, NEWS_V)), _news_groups(spec))),
+        (functools.partial(_seed_pick, k=NEWS_K),
+         ((spec((q, n)), spec((q, n))),)),
+        (functools.partial(_threshold, k=NEWS_K),
+         (spec((q, NEWS_K * q)), spec((), jnp.int32), spec(()))),
+        (_cascade_keep,
+         ((spec((q, n)), spec((q, n))), spec((q, NEWS_C)), spec((NEWS_C,)),
+          spec((n,), jnp.int32), spec((q,)), spec((n,), jnp.bool_))),
+    ]
+    for fn, args in cases:
+        compiled = jax.jit(fn).lower(*args).compile()
+        assert compiled.memory_analysis().argument_size_in_bytes > 0
