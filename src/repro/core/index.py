@@ -1083,24 +1083,27 @@ def _solve_batched_einsum(g, mq, idx, val, r, mask, lam, n_iter, tol=None,
 
 
 @functools.partial(jax.jit, static_argnames=("lam", "gemm", "log_domain",
-                                             "with_m"))
+                                             "with_m", "vocab_major"))
 def _compute_kq(sup: jax.Array, mask: jax.Array, vecs: jax.Array,
                 vecs_sq: jax.Array, lam: float, gemm: str = "fp32",
-                log_domain: bool = False, with_m: bool = True):
+                log_domain: bool = False, with_m: bool = True,
+                vocab_major: bool = False):
     """Stacked cdist GEMM -> K for one query chunk: (Q, B) ids -> (Q, V, B).
 
-    One (V, Q*B) GEMM replaces Q separate (v_r, V) cdists. The TRANSPOSED
-    orientation makes the subsequent doc-word gathers copy contiguous rows
-    instead of striding over the vocab axis; the reorder to (Q, V, B)
-    happens on this SMALL matrix, never on the Q*N*L*B gather output.
-    Padded rows (mask == 0) come out as all-zero K columns (G == 0).
+    One (V, Q*B) GEMM replaces Q separate (v_r, V) cdists. Its output
+    holds each vocabulary word's Q*B kernel values in one contiguous row.
+    ``vocab_major=True`` returns that (V, Q*B) kernel ``kt`` alone, the
+    resident solve's hand-over: its gather copies one whole row per doc
+    word (:func:`_gather_g`). Otherwise it is reordered to (Q, V, B) on
+    this SMALL matrix, never on the Q*N*L*B gather output. Padded rows
+    (mask == 0) come out as all-zero K columns (G == 0).
 
     Returns (kq (Q, V, B), mq (Q, V, B)): the kernel AND the raw cdist —
     the solve's distance-line epilogue gathers its transport costs from
     ``mq`` instead of reconstructing them via ``log(G)`` (see
     :func:`_solve_batched_einsum`). ``mq`` is unmasked (the epilogue's
     ``g > 0`` guard excludes pad rows). ``with_m=False`` returns ``kq``
-    alone — the Pallas path reconstructs GM in VMEM and must not pay an
+    alone — the Pallas paths reconstruct GM in VMEM and must not pay an
     unused (Q, V, B) buffer per staged chunk.
 
     ``gemm="bf16"`` casts only the GEMM operands (fp32 accumulation via
@@ -1118,15 +1121,17 @@ def _compute_kq(sup: jax.Array, mask: jax.Array, vecs: jax.Array,
         kt = jnp.where(mask.reshape(1, -1) > 0, -lam * m, -jnp.inf)
     else:
         kt = jnp.exp(-lam * m) * mask.reshape(1, -1)
+    if vocab_major:
+        return kt
     kq = jnp.transpose(kt.reshape(-1, q, b), (1, 0, 2))       # (Q, V, B)
     if not with_m:
         return kq
     return kq, jnp.transpose(m.reshape(-1, q, b), (1, 0, 2))
 
 
-@functools.partial(jax.jit, static_argnames=("layout", "block_n"))
+@functools.partial(jax.jit, static_argnames=("layout", "block_n", "q"))
 def _gather_g(kq: jax.Array, idx: jax.Array, layout: str = "qnlb",
-              block_n: int = 1):
+              block_n: int = 1, q: int = 1):
     """Gather doc-word columns of K: (Q, V, B) x (N, L) -> G.
 
     Kept as its own jit (with :func:`_compute_kq` separate too): XLA CPU
@@ -1134,18 +1139,27 @@ def _gather_g(kq: jax.Array, idx: jax.Array, layout: str = "qnlb",
     per gathered element (~2.4x slower end to end); on TPU the boundary is
     where the engine hands off to the Mosaic kernel anyway.
 
-    ``layout="qlbn"`` is the resident solve's tile (Q, L, B, N_pad): the
-    docs padded to whole ``block_n`` blocks of word-0 columns (inert: the
-    solve gives them val 0) and on the minor axis, with the query words
-    second-minor — the order the TPU lays a K-row gather out in anyway,
-    so the kernel reads the tile as the gather writes it.
+    ``layout="qlbn"`` is the resident solve's tile (Q, L, B, N_pad) from
+    the vocabulary-major (V, Q*B) kernel ``kq`` of ``q`` queries: the docs
+    padded to whole ``block_n`` blocks of word-0 columns (inert: the solve
+    gives them val 0) and on the minor axis, with the query words
+    second-minor. Each doc word's Q*B values are one contiguous row,
+    gathered whole in (L, N_pad) order, so the reorder to the tile is a
+    batch of 2-D (N_pad, Q*B) -> (Q*B, N_pad) transposes. Doc word ids
+    lie in [0, V), so ``mode="clip"`` changes no value; it spares the
+    masking pass over the tile that ``jnp.take``'s default fill mode adds
+    (on a v5e, 10-30% of the gather's time).
     """
     if layout == "qbnl":
         # TPU tile layout: (v_r, block_n, L) per query, sublane = query rows
         return jnp.take(jnp.transpose(kq, (0, 2, 1)), idx, axis=2)
     if layout == "qlbn":
         idx = jnp.pad(idx, ((0, -idx.shape[0] % block_n), (0, 0)))
-        return jnp.swapaxes(jnp.take(kq, idx.T, axis=1), 2, 3)
+        n_pad, length = idx.shape
+        rows = jnp.take(kq, idx.T.reshape(-1), axis=0,
+                        mode="clip")                        # (L*N_pad, Q*B)
+        rows = jnp.swapaxes(rows.reshape(length, n_pad, -1), 1, 2)
+        return jnp.swapaxes(rows.reshape(length, q, -1, n_pad), 0, 1)
     return jnp.take(kq, idx, axis=1)                         # (Q, N, L, B)
 
 
@@ -1390,13 +1404,16 @@ class WmdEngine:
         """Host-side counters since the last :meth:`reset_host_stats`:
 
         - ``dispatches``: the device layer calls the engine makes (one
-          per K block in ``_kq``, one per gather and one per solve in
-          ``_solve_group``, one per candidate tile's row gather);
+          per K block in ``_kq``, and one more where a cached block is
+          reordered for the resident solve; one per gather and one per
+          solve in ``_solve_group``; one per candidate tile's row gather);
         - ``host_syncs``: the blocking result reads of :meth:`query_batch`
           (one per chunk and doc group);
         - ``resident_solves``: the ``_solve_group`` solves that ran the
           VMEM-resident kernel rather than the einsum (see
           :meth:`_solve_group`);
+        - ``row_gathers``: resident solves whose tile came from the
+          whole-row gather of :meth:`_kq`'s vocabulary-major kernel;
         - ``search_queries``: queries handed to :meth:`search`;
         - ``pairs_solved``: (live query, real candidate doc) pairs that
           :meth:`search`'s candidate solves scored, seeds and survivors;
@@ -1411,6 +1428,7 @@ class WmdEngine:
         return {"dispatches": self._dispatches,
                 "host_syncs": self._host_syncs,
                 "resident_solves": self._resident_solves,
+                "row_gathers": self._row_gathers,
                 "search_queries": self._search_queries,
                 "pairs_solved": self._pairs_solved,
                 "slots_solved": self._slots_solved,
@@ -1421,6 +1439,7 @@ class WmdEngine:
         self._dispatches = 0
         self._host_syncs = 0
         self._resident_solves = 0
+        self._row_gathers = 0
         self._search_queries = 0
         self._pairs_solved = 0
         self._slots_solved = 0
@@ -1559,7 +1578,7 @@ class WmdEngine:
         """Solve one prepared chunk against one doc group (device array,
         not yet synced): gather the group's K columns, run the batched
         solver. Works for index groups and pruned candidate subsets alike —
-        the solve stage of the pipeline. ``kq`` is the (kq, mq) pair from
+        the solve stage of the pipeline. ``kq`` is the pair from
         :meth:`_kq`. Realized iteration counts land in :meth:`iter_stats`
         under ``stage`` (device values, synced lazily).
 
@@ -1570,21 +1589,24 @@ class WmdEngine:
         averaged over ``prof_mask`` docs (``None`` on the kernel path,
         which reconstructs GM in VMEM and does not expose x).
 
-        On a TPU, a fixed-iteration solve (``tol`` unset) with no warm
-        start and no profile asked for runs the VMEM-resident kernel
-        (:func:`repro.kernels.sddmm_spmm.sinkhorn_resident`): each doc
-        block's gathered tile is read from HBM once, where the einsum
-        rereads it twice per iteration. Its distances are the einsum's
-        up to rounding. Every other solve, and every solve off a TPU,
-        runs the einsum."""
+        Where :meth:`_kq` handed over the vocabulary-major kernel (on a
+        TPU, a fixed-iteration solve: see :attr:`_resident`), the solve
+        runs the VMEM-resident kernel
+        (:func:`repro.kernels.sddmm_spmm.sinkhorn_resident`) over a tile
+        gathered one whole K row per doc word: each doc block's tile is
+        read from HBM once, where the einsum rereads it twice per
+        iteration. Its distances are the einsum's up to rounding. Every
+        other solve, and every solve off a TPU, runs the einsum."""
         kqk, mq = kq
-        resident = (self.impl == "sparse" and self.tol is None
-                    and x0q is None and not want_profile and _on_tpu(kqk))
         self._dispatches += 2           # the gather and the solve below
-        if resident:
+        if kqk.ndim == 2:               # (V, Q*B): the resident hand-over
+            if x0q is not None or want_profile:
+                raise ValueError("warm starts and profiles need tol set; "
+                                 "the resident solve runs without it")
             from repro.kernels.ops import sinkhorn_resident
             g = _gather_g(kqk, grp.docs.idx, layout="qlbn",
-                          block_n=self.block_n)
+                          block_n=self.block_n, q=r.shape[0])
+            self._row_gathers += 1
             wmd = sinkhorn_resident(
                 g, grp.docs.val, r, mask, self.lam, self.n_iter,
                 block_n=self.block_n, interpret=self.interpret,
@@ -1640,12 +1662,23 @@ class WmdEngine:
             return self._solve_group(kq, r, mask, grp, **kw), None
         return self._solve_group(kq, r, mask, grp, want_profile=True, **kw)
 
+    @property
+    def _resident(self) -> bool:
+        """Whether every solve runs the VMEM-resident kernel: the sparse
+        impl, fixed iterations (``tol`` unset, so no warm start or
+        profile either: see :attr:`_warms_survivors`), on a TPU."""
+        return (self.impl == "sparse" and self.tol is None
+                and _on_tpu(self.index.vecs))
+
     @functools.partial(jax.profiler.annotate_function, name="wmd.dispatch")
     def _kq(self, sup, mask):
         """(kq, mq) for one staged chunk — treat as an opaque pair; the
         solve stage consumes both (kernel gather + distance epilogue).
-        The kernel impl reconstructs GM in VMEM, so its pair carries
-        ``mq=None`` instead of an unused (Q, V, B) buffer.
+        The Pallas paths reconstruct GM in VMEM, so their pairs carry
+        ``mq=None`` instead of an unused (Q, V, B) buffer: the kernel
+        impl's kq is (Q, V, B), and the resident solve's
+        (:attr:`_resident`) is the GEMM's vocabulary-major (V, Q*B)
+        kernel, whose doc-word gather copies whole rows.
 
         With a :meth:`enable_kcache` cache attached, chunks whose words
         are mostly resident assemble the pair from cached cdist rows
@@ -1653,45 +1686,50 @@ class WmdEngine:
         below ``kcache_min_hits`` resident rows the one-shot GEMM is
         cheaper on CPU (dispatch economy — see the ROADMAP refusion
         note) and its ``mq`` block warms the cache for the next request.
-        Both paths produce BIT-IDENTICAL pairs (``core/kcache.py``)."""
+        Both paths produce BIT-IDENTICAL pairs (``core/kcache.py``); for
+        the resident solve the cached path reorders its kq to (V, Q*B)."""
         self._dispatches += 1           # one K block program, either path
+        prec = {"gemm": self.precision.gemm,
+                "log_domain": self.precision.log_domain}
         if self.impl == "kernel":
-            kq = _compute_kq(sup, mask, self.index.vecs,
-                             self.index.vecs_sq, self.lam,
-                             gemm=self.precision.gemm,
-                             log_domain=self.precision.log_domain,
-                             with_m=False)
-            return kq, None
+            return _compute_kq(sup, mask, self.index.vecs,
+                               self.index.vecs_sq, self.lam, **prec,
+                               with_m=False), None
         cache = self._kcache
-        if cache is not None and cache.vecs is not self.index.vecs:
+        if cache is None:
+            if self._resident:
+                return _compute_kq(sup, mask, self.index.vecs,
+                                   self.index.vecs_sq, self.lam, **prec,
+                                   vocab_major=True), None
+            return _compute_kq(sup, mask, self.index.vecs,
+                               self.index.vecs_sq, self.lam, **prec)
+        if cache.vecs is not self.index.vecs:
             # anything that swapped the embedding table (a new index, a
             # snapshot reload) invalidates every resident row; append_docs
             # reuses vecs by identity — the vocabulary is frozen — so
             # appends sail through here with the cache intact
             cache = self._kcache = cache.rebind(self.index.vecs,
                                                 self.index.vecs_sq)
-        if cache is None:
-            return _compute_kq(sup, mask, self.index.vecs,
-                               self.index.vecs_sq, self.lam,
-                               gemm=self.precision.gemm,
-                               log_domain=self.precision.log_domain)
         sup_np = np.asarray(sup)
         ids = np.unique(sup_np.reshape(-1))
         n_hit = cache.lookup(ids)
         oversize = len(ids) > cache.slots
         if oversize or n_hit < self.kcache_min_hits:
             cache.note_fallback(oversize=oversize)
-            kq, mq = _compute_kq(sup, mask, self.index.vecs,
-                                 self.index.vecs_sq, self.lam,
-                                 gemm=self.precision.gemm,
-                                 log_domain=self.precision.log_domain)
-            cache.warm(sup_np, mq)
-            return kq, mq
-        from .kcache import assemble_kq
-        rows = cache.rows(ids)
-        inv = jnp.asarray(np.searchsorted(ids, sup_np).astype(np.int32))
-        return assemble_kq(rows, inv, mask, self.lam,
-                           log_domain=self.precision.log_domain)
+            pair = _compute_kq(sup, mask, self.index.vecs,
+                               self.index.vecs_sq, self.lam, **prec)
+            cache.warm(sup_np, pair[1])
+        else:
+            from .kcache import assemble_kq
+            rows = cache.rows(ids)
+            inv = jnp.asarray(np.searchsorted(ids, sup_np).astype(np.int32))
+            pair = assemble_kq(rows, inv, mask, self.lam,
+                               log_domain=self.precision.log_domain)
+        if not self._resident:
+            return pair
+        self._dispatches += 1           # the reorder below
+        kq = pair[0]
+        return jnp.transpose(kq, (1, 0, 2)).reshape(kq.shape[1], -1), None
 
     def _raise_if_nan(self, wmd_np: np.ndarray, chunk_queries: list) -> None:
         """Every chunk query has support, so NaN here means the lam-driven

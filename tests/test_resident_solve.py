@@ -2,9 +2,10 @@
 
 On a TPU a fixed-iteration solve with no warm start runs
 ``kernels.sddmm_spmm.sinkhorn_resident`` over the gather's
-(Q, L, B, N_pad) tile instead of the einsum. Here the platform probe is
-patched, so the kernel runs in Pallas interpret mode on the CPU, and its
-answers are held to the einsum path's.
+(Q, L, B, N_pad) tile instead of the einsum; the K block hands that gather
+its vocabulary-major (V, Q*B) kernel, whose rows it copies whole. Here the
+platform probe is patched, so the kernel runs in Pallas interpret mode on
+the CPU, and its answers are held to the einsum path's.
 """
 import numpy as np
 import jax.numpy as jnp
@@ -12,6 +13,7 @@ import pytest
 
 import repro.core.index as index_mod
 from repro.core import LamUnderflowError, WmdEngine, build_index
+from repro.core.index import _gather_g
 from repro.data.corpus import make_corpus
 from repro.kernels import ops
 
@@ -62,22 +64,27 @@ def test_query_batch_matches_einsum(index, queries, precision, monkeypatch):
     got = np.asarray(eng.query_batch(queries))
     chunks = len(eng._plan(queries)[1])
     assert chunks == 4
-    assert eng.host_stats()["resident_solves"] == chunks * len(index.groups)
+    stats = eng.host_stats()
+    assert stats["resident_solves"] == chunks * len(index.groups)
+    assert stats["row_gathers"] == stats["resident_solves"]
     assert np.isnan(got[-1]).all() and np.isnan(want[-1]).all()
     np.testing.assert_allclose(got[:-1], want[:-1], rtol=1e-5)
     assert eng.iter_stats().tolist() == einsum.iter_stats().tolist()
 
 
+@pytest.mark.parametrize("k", [5, 19])
 @pytest.mark.parametrize("prune", ["rwmd", "ivf+wcd+rwmd"])
-def test_search_topk_matches_einsum(index, queries, monkeypatch, prune):
+def test_search_topk_matches_einsum(index, queries, monkeypatch, prune, k):
     live = queries[:-1]
     einsum = WmdEngine(index, lam=LAM, n_iter=N_ITER)
-    want = einsum.search(live, k=5, prune=prune)
+    want = einsum.search(live, k=k, prune=prune)
     assert einsum.host_stats()["resident_solves"] == 0
     monkeypatch.setattr(index_mod, "_on_tpu", lambda x: True)
     eng = WmdEngine(index, lam=LAM, n_iter=N_ITER)
-    got = eng.search(live, k=5, prune=prune)
-    assert eng.host_stats()["resident_solves"] > 0
+    got = eng.search(live, k=k, prune=prune)
+    stats = eng.host_stats()
+    assert stats["resident_solves"] > 0
+    assert stats["row_gathers"] == stats["resident_solves"]
     np.testing.assert_array_equal(got.indices, want.indices)
     np.testing.assert_allclose(got.distances, want.distances, rtol=1e-5)
     np.testing.assert_array_equal(got.solved, want.solved)
@@ -92,11 +99,13 @@ def test_search_topk_matches_einsum(index, queries, monkeypatch, prune):
     ("tpu+kernel_impl", False),
 ])
 def test_selection_rule(index, queries, monkeypatch, case, resident):
-    """The resident solve runs only on a TPU, without ``tol``, a warm
-    start or a profile asked for; every other solve keeps its path."""
+    """The resident solve runs only on a TPU, without ``tol``; every
+    other solve keeps its path. Warm starts and profiles exist only with
+    ``tol`` set (``WmdEngine._warms_survivors``), so they keep theirs."""
     monkeypatch.setattr(index_mod, "_on_tpu", lambda x: case != "cpu")
+    adaptive = case in ("tpu+tol", "tpu+profile", "tpu+warm")
     eng = WmdEngine(index, lam=LAM, n_iter=N_ITER,
-                    tol=1e-3 if case == "tpu+tol" else None,
+                    tol=1e-3 if adaptive else None,
                     impl="kernel" if case == "tpu+kernel_impl" else "sparse")
     chunk = queries[1:3]
     sup, r, mask = eng._prep_chunk(chunk, 16)
@@ -111,12 +120,78 @@ def test_selection_rule(index, queries, monkeypatch, case, resident):
     wmd = out[0] if "want_profile" in kw else out
     assert eng.host_stats() == {"dispatches": 3, "host_syncs": 0,
                                 "resident_solves": int(resident),
+                                "row_gathers": int(resident),
                                 "search_queries": 0, "pairs_solved": 0,
                                 "slots_solved": 0, "survivor_tiles": 0,
                                 "prune_syncs": 0}
     assert wmd.shape == (r.shape[0], grp.docs.idx.shape[0])
+    v, (q, b) = index.vecs.shape[0], r.shape
+    assert kq[0].shape == ((v, q * b) if resident else (q, v, b))
     eng.reset_host_stats()
     assert eng.host_stats()["resident_solves"] == 0
+    assert eng.host_stats()["row_gathers"] == 0
+
+
+@pytest.mark.parametrize("kw", [{"want_profile": True}, {"x0q": "warm"}])
+def test_resident_hand_over_refuses_warm_start_and_profile(index, queries,
+                                                           on_tpu, kw):
+    """The vocabulary-major hand-over feeds the fixed-iteration resident
+    solve only: a warm start or a profile asked of it raises rather than
+    being dropped."""
+    eng = WmdEngine(index, lam=LAM, n_iter=N_ITER)
+    sup, r, mask = eng._prep_chunk(queries[1:3], 16)
+    if "x0q" in kw:
+        kw = {"x0q": jnp.full(r.shape, 1.0 / 16)}
+    with pytest.raises(ValueError, match="tol"):
+        eng._solve_group(eng._kq(sup, mask), r, mask, index.groups[0], **kw)
+    assert eng.host_stats()["resident_solves"] == 0
+
+
+@pytest.mark.parametrize("log_domain", [False, True])
+@pytest.mark.parametrize("b", [24, 48, 192])
+@pytest.mark.parametrize("q", [1, 2, 4])
+def test_row_gather_equals_element_gather(q, b, log_domain):
+    """The whole-row gather from the vocabulary-major (V, Q*B) kernel is
+    the element gather of its (Q, V, B) transpose, bit for bit: pad docs
+    (the doc count is not a multiple of ``block_n``) are word-0 columns,
+    and masked query words keep their -inf rows in the log domain."""
+    rng = np.random.default_rng(q * 1000 + b)
+    v, n_docs, length, block_n = 97, 45, 7, 16
+    kt = rng.uniform(0.0, 1.0, (v, q * b)).astype(np.float32)
+    mask = rng.uniform(size=q * b) < 0.8
+    kt[:, ~mask] = -np.inf if log_domain else 0.0
+    if log_domain:
+        kt[:, mask] = np.log(kt[:, mask])
+    idx = rng.integers(0, v, (n_docs, length)).astype(np.int32)
+    got = _gather_g(jnp.asarray(kt), jnp.asarray(idx), layout="qlbn",
+                    block_n=block_n, q=q)
+    kq = jnp.transpose(jnp.asarray(kt).reshape(v, q, b), (1, 0, 2))
+    n_pad = -(-n_docs // block_n) * block_n
+    idx_pad = np.zeros((n_pad, length), np.int32)
+    idx_pad[:n_docs] = idx
+    want = jnp.swapaxes(jnp.take(kq, jnp.asarray(idx_pad).T, axis=1), 2, 3)
+    assert got.shape == want.shape == (q, length, b, n_pad)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_kcache_on_resident_path_keeps_topk(index, queries, on_tpu):
+    """With a K cache attached, the resident path takes the cached
+    block reordered vocabulary-major and returns the same top-k as the
+    one-shot K block, fallback and cached chunks alike."""
+    live = queries[:-1]
+    plain = WmdEngine(index, lam=LAM, n_iter=N_ITER)
+    want = plain.search(live, k=5, prune="ivf+wcd+rwmd")
+    eng = WmdEngine(index, lam=LAM, n_iter=N_ITER, kcache_min_hits=1)
+    assert eng.enable_kcache(256)
+    for _ in range(2):          # the first pass warms, the second hits
+        got = eng.search(live, k=5, prune="ivf+wcd+rwmd")
+        np.testing.assert_array_equal(got.indices, want.indices)
+        np.testing.assert_allclose(got.distances, want.distances,
+                                   rtol=1e-5)
+    stats = eng.host_stats()
+    assert stats["row_gathers"] == stats["resident_solves"] > 0
+    cache = eng.kcache_stats()
+    assert cache["hits"] > 0 and cache["fallbacks"] < cache["lookups"]
 
 
 def test_underflow_raises_on_resident_path(corpus, index, on_tpu):

@@ -85,6 +85,13 @@ def _gather_g():
     return _gather_g, [((Q, V, VR), jnp.float32), ((N_GROUP, L), jnp.int32)]
 
 
+def _compute_kq_rows():
+    from repro.core.index import _compute_kq
+    fn = functools.partial(_compute_kq, lam=1.0, vocab_major=True)
+    return fn, [((Q, VR), jnp.int32), ((Q, VR), jnp.float32),
+                ((V, W), jnp.float32), ((V,), jnp.float32)]
+
+
 def _solve_gathered(tol):
     from repro.core.index import _solve_gathered
 
@@ -105,6 +112,7 @@ CASES = {
     "sinkhorn_fused_all_batched_tol": (functools.partial(_fused_batched,
                                                          1e-3), True),
     "compute_kq": (_compute_kq, False),
+    "compute_kq_rows": (_compute_kq_rows, False),
     "gather_g": (_gather_g, False),
     "solve_gathered": (functools.partial(_solve_gathered, None), False),
     "solve_gathered_tol": (functools.partial(_solve_gathered, 1e-3), False),
@@ -129,16 +137,23 @@ def test_compiles_for_one_v5e_chip(case, one_chip):
 def _compile_resident(vocab, n_group, length, b, q, one_chip):
     """Compile the gather and the resident solve of one nnz group of
     ``n_group`` docs of ELL width ``length`` against a chunk of ``q``
-    queries staged at width ``b``: the gather hands its tile over in the
-    layout the kernel reads, so no copy of the tile lies between them."""
+    queries staged at width ``b``: the gather copies whole (Q*B) rows of
+    the K block's vocabulary-major (V, Q*B) hand-over, and hands its tile
+    over in the layout the kernel reads, so no copy of the tile lies
+    between them."""
+    import re
+
     from repro.core.index import _gather_g
     from repro.kernels.sddmm_spmm import sinkhorn_resident
 
     def spec(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     gather = jax.jit(functools.partial(_gather_g, layout="qlbn",
-                                       block_n=128)).lower(
-        spec((q, vocab, b)), spec((n_group, length), jnp.int32)).compile()
+                                       block_n=128, q=q)).lower(
+        spec((vocab, q * b)), spec((n_group, length), jnp.int32)).compile()
+    slices = re.findall(r"= \S+ gather\(.*slice_sizes=\{([\d,]+)\}",
+                        gather.as_text())
+    assert slices == [f"1,{q * b}"], slices
     tile = gather.out_info
     assert tile.shape == (q, length, b, -(-n_group // 128) * 128)
     solve = jax.jit(functools.partial(sinkhorn_resident, lam=10.0,

@@ -33,8 +33,8 @@ def _engine(corpus, impl="sparse", **kw):
 
 
 ZERO = {"dispatches": 0, "host_syncs": 0, "resident_solves": 0,
-        "search_queries": 0, "pairs_solved": 0, "slots_solved": 0,
-        "survivor_tiles": 0, "prune_syncs": 0}
+        "row_gathers": 0, "search_queries": 0, "pairs_solved": 0,
+        "slots_solved": 0, "survivor_tiles": 0, "prune_syncs": 0}
 
 
 def _plan_counts(engine, queries):
