@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Chip smoke test: WMD serving at the paper's corpus size on a TPU.
 
-    python3 chip_smoke.py               # one chip: serving + kernel path
+    python3 chip_smoke.py               # one chip: serving
     python3 chip_smoke.py --four-chips  # four chips: sharded search only
 
 One chip. The paper's corpus (``paper_corpus``: V=100,000 words of w=300,
@@ -19,10 +19,9 @@ stream starts. Then the answers are checked:
 - the sparse engine's distances agree with the dense reference
   (``core/sinkhorn.py``, full-precision matmuls) on a few queries and docs,
   and with an uncached sparse engine, which builds its K block with one
-  stacked GEMM instead of from cached per-word rows;
-- the Pallas engine (``impl="kernel"``) agrees with the uncached sparse
-  engine, and its solver lowered to a Mosaic ``tpu_custom_call`` (never
-  interpret).
+  stacked GEMM instead of from cached per-word rows. The resident Pallas
+  solve's Mosaic compile is checked without a chip by
+  ``tests/test_tpu_compile.py``.
 
 Four chips. ``shard_corpus(paper_corpus, 4)`` searched at ``nprobe=None``
 must equal the one-device engine's exhaustive top-k up to tie order; each
@@ -57,9 +56,8 @@ N_REQUESTS, RATE_QPS = 32, 6.0
 HOST_LAG_S = 0.25
 # relative tolerances, fp32 on both sides of each comparison
 TOPK_RTOL = 1e-4      # served vs exhaustive: same solver, other doc subsets
-# the same K block under another solver (Pallas vs einsum) or from the
-# other K builder (cached rows vs one stacked GEMM): only the fp32
-# rounding of the solve differs. Both builders reduce the query-word
+# the same K block from the other K builder (cached rows vs one stacked
+# GEMM): only the fp32 rounding of the solve differs. Both builders reduce the query-word
 # norms through ``sq_dists``, so a word's distance to itself (the
 # square root of cancellation noise) is the same bits from either
 SAME_K_RTOL = 1e-5
@@ -341,40 +339,6 @@ def phase_stacked(checks, corpus, index, full):
     checks.expect(sharing <= SAME_K_RTOL,
                   f"cached vs stacked K rel. diff {sharing:.3g} on docs "
                   f"sharing a query word")
-    return stacked
-
-
-def phase_kernel(checks, corpus, index, stacked, counter):
-    """The Pallas engine on the same index: compiled to Mosaic, and its
-    distances against the uncached sparse engine's, which builds the
-    same K block."""
-    import jax
-    import numpy as np
-    from repro.core import WmdEngine
-    from repro.kernels import ops
-    checks.expect(ops.resolve_interpret(None) is False,
-                  "Pallas interpret mode is on")
-    g = jax.ShapeDtypeStruct((4, 64, 1280, 48), np.float32)
-    lowered = jax.jit(lambda g, v, r: ops.sinkhorn_fused_all_batched(
-        g, v, r, LAM, N_ITER)).lower(
-        g, jax.ShapeDtypeStruct((1280, 48), np.float32),
-        jax.ShapeDtypeStruct((4, 64), np.float32))
-    mosaic = "tpu_custom_call" in lowered.as_text()
-    checks.expect(mosaic, "fused solver did not lower to a Mosaic call")
-
-    c0, s0 = counter.snapshot()
-    t0 = time.perf_counter()
-    kengine = WmdEngine(index, impl="kernel", lam=LAM, n_iter=N_ITER)
-    got = np.asarray(kengine.query_batch(list(corpus.queries)))
-    diff = max_rel_diff(got, stacked)
-    c1, s1 = counter.snapshot()
-    emit("kernel", seconds=time.perf_counter() - t0, compiles=c1 - c0,
-         compile_seconds=s1 - s0, mosaic=mosaic, rtol=SAME_K_RTOL,
-         max_rel_diff=diff, answers_checked=int(got.size))
-    checks.expect(bool(np.all(np.isfinite(got))),
-                  "kernel engine returned non-finite distances")
-    checks.expect(diff <= SAME_K_RTOL,
-                  f"kernel vs sparse rel. diff {diff:.3g}")
 
 
 def run_one_chip(checks: Checks, seed: int) -> None:
@@ -387,11 +351,8 @@ def run_one_chip(checks: Checks, seed: int) -> None:
     if full is not None:
         checks.run("dense_reference", phase_dense_reference, checks,
                    corpus, full)
-        stacked = checks.run("kcache_vs_stacked", phase_stacked, checks,
-                             corpus, index, full)
-        if stacked is not None:
-            checks.run("kernel", phase_kernel, checks, corpus, index,
-                       stacked, counter)
+        checks.run("kcache_vs_stacked", phase_stacked, checks, corpus,
+                   index, full)
     emit("memory", peak_bytes_in_use=peak_bytes())
 
 

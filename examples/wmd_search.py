@@ -53,7 +53,7 @@ def main() -> None:
     ap.add_argument("--refine-factor", type=int, default=4,
                     help="--mode refine: solve budget multiple")
     ap.add_argument("--impl", default="sparse",
-                    help="engine: sparse|kernel; --looped accepts any "
+                    help="--looped: the one_to_many impl, any "
                          "repro.core.IMPLS entry")
     ap.add_argument("--n-clusters", default=None,
                     help="IVF cluster count at index build (int, or 'auto' "
@@ -122,7 +122,7 @@ def main() -> None:
     else:
         prune = None if args.prune == "none" else args.prune
         nprobe = args.nprobe if args.nprobe > 0 else None
-        kw = dict(lam=LAM, n_iter=15, impl=args.impl,
+        kw = dict(lam=LAM, n_iter=15,
                   tol=args.tol if args.tol > 0 else None,
                   check_every=args.check_every, precision=args.precision,
                   scope=args.scope, warm_start=args.warm_start)

@@ -25,11 +25,12 @@ batches the query side:
     kernel tests), stacks each bucket into one ``(Q, v_r, ...)`` problem and
     runs the solver ONCE per bucket: the per-query ``(v_r, V)`` cdist
     becomes a single ``(Q*v_r, V)`` GEMM, the Sinkhorn loop runs as one
-    batched einsum or one Pallas launch with a query grid dimension
-    (:func:`repro.kernels.sddmm_spmm.sinkhorn_fused_all_batched`), and jit
-    caching collapses to one executable per bucket shape instead of one per
-    distinct ``v_r``. GM is reconstructed from G everywhere (never
-    materialized), so the per-bucket footprint is two nnz-sized arrays.
+    Pallas launch with a query grid dimension on a TPU
+    (:func:`repro.kernels.sddmm_spmm.sinkhorn_resident`) or one batched
+    einsum, and jit caching collapses to one executable per bucket shape
+    instead of one per distinct ``v_r``. GM is reconstructed from G
+    everywhere (never materialized), so the per-bucket footprint is two
+    nnz-sized arrays.
 
 ``WmdEngine.search`` (the staged retrieval pipeline, ISSUE 2)
     The paper's motivating workload is top-k retrieval, and exhaustive
@@ -107,7 +108,7 @@ from .sinkhorn_sparse import (SolvePrecision, adaptive_loop,
                               marginal_residual_per_query)
 from .sparse import PaddedDocs
 
-ENGINE_IMPLS = ("sparse", "kernel")
+ENGINE_IMPLS = ("sparse",)
 
 
 class DocGroup(NamedTuple):
@@ -963,8 +964,8 @@ def _solve_batched_einsum(g, mq, idx, val, r, mask, lam, n_iter, tol=None,
     in both domains, and the reason the log path needs NO shift
     correction here. The vocab-level M is held for the chunk (same size
     as ``kq``); the nnz-level (Q, N, L, B) product exists only inside
-    this jit. The Pallas kernel path keeps the in-VMEM ``reconstruct_gm``
-    (on TPU recompute beats the extra HBM gather).
+    this jit. The resident Pallas solve keeps the in-VMEM
+    ``reconstruct_gm`` (on TPU recompute beats the extra HBM gather).
 
     Returns (wmd (Q, N), realized iterations (int32 scalar)).
     """
@@ -1083,11 +1084,10 @@ def _solve_batched_einsum(g, mq, idx, val, r, mask, lam, n_iter, tol=None,
 
 
 @functools.partial(jax.jit, static_argnames=("lam", "gemm", "log_domain",
-                                             "with_m", "vocab_major"))
+                                             "vocab_major"))
 def _compute_kq(sup: jax.Array, mask: jax.Array, vecs: jax.Array,
                 vecs_sq: jax.Array, lam: float, gemm: str = "fp32",
-                log_domain: bool = False, with_m: bool = True,
-                vocab_major: bool = False):
+                log_domain: bool = False, vocab_major: bool = False):
     """Stacked cdist GEMM -> K for one query chunk: (Q, B) ids -> (Q, V, B).
 
     One (V, Q*B) GEMM replaces Q separate (v_r, V) cdists. Its output
@@ -1102,9 +1102,7 @@ def _compute_kq(sup: jax.Array, mask: jax.Array, vecs: jax.Array,
     the solve's distance-line epilogue gathers its transport costs from
     ``mq`` instead of reconstructing them via ``log(G)`` (see
     :func:`_solve_batched_einsum`). ``mq`` is unmasked (the epilogue's
-    ``g > 0`` guard excludes pad rows). ``with_m=False`` returns ``kq``
-    alone — the Pallas paths reconstruct GM in VMEM and must not pay an
-    unused (Q, V, B) buffer per staged chunk.
+    ``g > 0`` guard excludes pad rows).
 
     ``gemm="bf16"`` casts only the GEMM operands (fp32 accumulation via
     ``preferred_element_type``); ``log_domain=True`` returns
@@ -1124,8 +1122,6 @@ def _compute_kq(sup: jax.Array, mask: jax.Array, vecs: jax.Array,
     if vocab_major:
         return kt
     kq = jnp.transpose(kt.reshape(-1, q, b), (1, 0, 2))       # (Q, V, B)
-    if not with_m:
-        return kq
     return kq, jnp.transpose(m.reshape(-1, q, b), (1, 0, 2))
 
 
@@ -1150,9 +1146,6 @@ def _gather_g(kq: jax.Array, idx: jax.Array, layout: str = "qnlb",
     masking pass over the tile that ``jnp.take``'s default fill mode adds
     (on a v5e, 10-30% of the gather's time).
     """
-    if layout == "qbnl":
-        # TPU tile layout: (v_r, block_n, L) per query, sublane = query rows
-        return jnp.take(jnp.transpose(kq, (0, 2, 1)), idx, axis=2)
     if layout == "qlbn":
         idx = jnp.pad(idx, ((0, -idx.shape[0] % block_n), (0, 0)))
         n_pad, length = idx.shape
@@ -1244,9 +1237,10 @@ class WmdEngine:
     ----------
     index:       corpus state from :func:`build_index` (reused across calls)
     lam, n_iter: Sinkhorn strength / iteration count (static per engine)
-    impl:        "sparse" (batched einsum; on a TPU its fixed-iteration
-                 solves run the VMEM-resident Pallas kernel) or "kernel"
-                 (batched Pallas)
+    impl:        "sparse", the only value. The code picks the solve: on a
+                 TPU a fixed-iteration solve runs the VMEM-resident Pallas
+                 kernel, every other solve the batched einsum (see
+                 :meth:`_solve_group`)
     min_bucket:  smallest v_r bucket; queries are padded up to powers of two
     max_batch:   per-solve query cap — larger buckets are chunked so the
                  (Q, B, N, L) gathered tile stays memory-bounded
@@ -1285,8 +1279,8 @@ class WmdEngine:
                  columns, so the scaling transfers; docs open at the
                  profile and re-converge in fewer iterations — measured
                  in :meth:`iter_stats_by_stage` as the ``"survivor"``
-                 series). Opt-in, and only active with ``tol`` set on
-                 the einsum path (``impl="sparse"``): warm starting is
+                 series). Opt-in, and only active with ``tol`` set
+                 (which takes the einsum): warm starting is
                  sound when the adaptive exit actually CONVERGES (both
                  inits land within ``tol`` of the same fixed point); in
                  a cap-bound regime (``n_iter`` hit first) it changes
@@ -1301,8 +1295,8 @@ class WmdEngine:
                  (:meth:`iter_stats`); overflow discards the OLDEST record
                  and is counted by :attr:`iter_stats_dropped` so a
                  long-running serve can tell a window from a full history.
-    kcache_slots: opt-in cross-request cdist-row cache (ISSUE 10;
-                 ``impl="sparse"`` only): keep this many hot words'
+    kcache_slots: opt-in cross-request cdist-row cache (ISSUE 10):
+                 keep this many hot words'
                  ``(V,)`` corpus-distance rows device-resident with an
                  LRU clock, so Zipfian serving traffic assembles its
                  ``(Q, V, B)`` K block from cached rows + a misses-only
@@ -1333,16 +1327,9 @@ class WmdEngine:
         if scope not in ("chunk", "query"):
             raise ValueError(f"scope must be 'chunk' or 'query', "
                              f"got {scope!r}")
-        if kcache_slots and impl == "kernel":
-            raise ValueError(
-                "kcache_slots needs impl='sparse': the kernel impl's "
-                "staged pair carries no mq block to warm the cache from "
-                "(and reconstructs GM in VMEM, bypassing the kq the "
-                "cache would assemble)")
         self.index = index
         self.lam = float(lam)
         self.n_iter = int(n_iter)
-        self.impl = impl
         self.min_bucket = int(min_bucket)
         self.max_batch = int(max_batch)
         self.pad_q = bool(pad_q)
@@ -1378,13 +1365,9 @@ class WmdEngine:
     # ------------------------------------------------- cross-request cache
     def enable_kcache(self, slots: int) -> bool:
         """Attach a :class:`~repro.core.kcache.KCache` of ``slots``
-        resident cdist rows (replacing any existing cache). Returns
-        ``False`` on the kernel impl — its staged pair has no ``mq`` to
-        warm from — so serving's enable-by-default stays a no-op there.
-        Search results are unchanged bit-for-bit (the cache module's
-        exactness contract, pinned by the property suite)."""
-        if self.impl == "kernel":
-            return False
+        resident cdist rows (replacing any existing cache); returns
+        ``True``. Search results are unchanged bit-for-bit (the cache
+        module's exactness contract, pinned by the property suite)."""
         from .kcache import KCache
         self._kcache = KCache(self.index.vecs, self.index.vecs_sq,
                               int(slots), gemm=self.precision.gemm)
@@ -1586,8 +1569,7 @@ class WmdEngine:
         own candidate docs (``scope="query"``); ``x0q`` (Q, B) warm-starts
         the solve from a per-query profile; ``want_profile=True`` returns
         ``(wmd, profile)`` — the converged profile survivor solves reuse,
-        averaged over ``prof_mask`` docs (``None`` on the kernel path,
-        which reconstructs GM in VMEM and does not expose x).
+        averaged over ``prof_mask`` docs.
 
         Where :meth:`_kq` handed over the vocabulary-major kernel (on a
         TPU, a fixed-iteration solve: see :attr:`_resident`), the solve
@@ -1615,25 +1597,8 @@ class WmdEngine:
             self._resident_solves += 1
             self._record_iters(stage, np.int32(self.n_iter), n_live)
             return wmd
-        layout = "qbnl" if self.impl == "kernel" else "qnlb"
-        g = _gather_g(kqk, grp.docs.idx, layout=layout)
+        g = _gather_g(kqk, grp.docs.idx)
         scoped = self.tol is not None and self.scope == "query"
-        if self.impl == "kernel":
-            from repro.kernels.ops import sinkhorn_fused_all_batched
-            wmd, iters = sinkhorn_fused_all_batched(
-                g, grp.docs.val, r, self.lam, self.n_iter,
-                block_n=self.block_n, interpret=self.interpret,
-                tol=self.tol, check_every=self.check_every,
-                gemm=self.precision.gemm,
-                log_domain=self.precision.log_domain,
-                resmask=qdoc_mask if scoped else None, with_iters=True,
-                mask=mask)
-            # per-block counts -> per-query realized iterations (a query's
-            # slowest candidate block is when its columns actually froze)
-            self._record_iters(stage,
-                               jnp.max(iters, axis=1) if scoped
-                               else jnp.max(iters), n_live)
-            return (wmd, None) if want_profile else wmd
         out = _solve_gathered(g, mq, grp.docs.idx, grp.docs.val, r,
                               mask, self.lam, self.n_iter, self.tol,
                               self.check_every, self.precision.gemm,
@@ -1664,20 +1629,18 @@ class WmdEngine:
 
     @property
     def _resident(self) -> bool:
-        """Whether every solve runs the VMEM-resident kernel: the sparse
-        impl, fixed iterations (``tol`` unset, so no warm start or
-        profile either: see :attr:`_warms_survivors`), on a TPU."""
-        return (self.impl == "sparse" and self.tol is None
-                and _on_tpu(self.index.vecs))
+        """Whether every solve runs the VMEM-resident kernel: fixed
+        iterations (``tol`` unset, so no warm start or profile either:
+        see :attr:`_warms_survivors`), on a TPU."""
+        return self.tol is None and _on_tpu(self.index.vecs)
 
     @functools.partial(jax.profiler.annotate_function, name="wmd.dispatch")
     def _kq(self, sup, mask):
         """(kq, mq) for one staged chunk — treat as an opaque pair; the
         solve stage consumes both (kernel gather + distance epilogue).
-        The Pallas paths reconstruct GM in VMEM, so their pairs carry
-        ``mq=None`` instead of an unused (Q, V, B) buffer: the kernel
-        impl's kq is (Q, V, B), and the resident solve's
-        (:attr:`_resident`) is the GEMM's vocabulary-major (V, Q*B)
+        The resident solve (:attr:`_resident`) reconstructs GM in VMEM,
+        so its pair carries ``mq=None`` instead of an unused (Q, V, B)
+        buffer, and its kq is the GEMM's vocabulary-major (V, Q*B)
         kernel, whose doc-word gather copies whole rows.
 
         With a :meth:`enable_kcache` cache attached, chunks whose words
@@ -1691,10 +1654,6 @@ class WmdEngine:
         self._dispatches += 1           # one K block program, either path
         prec = {"gemm": self.precision.gemm,
                 "log_domain": self.precision.log_domain}
-        if self.impl == "kernel":
-            return _compute_kq(sup, mask, self.index.vecs,
-                               self.index.vecs_sq, self.lam, **prec,
-                               with_m=False), None
         cache = self._kcache
         if cache is None:
             if self._resident:
@@ -1898,8 +1857,7 @@ class WmdEngine:
             return SearchResult(out_i, out_d, solved)
 
         from .prune import CascadePruner, resolve_pruner
-        pruner = resolve_pruner(prune, use_kernel=(self.impl == "kernel"),
-                                interpret=self.interpret, nprobe=nprobe)
+        pruner = resolve_pruner(prune, nprobe=nprobe)
         _, chunks = self._plan(queries)
         if not chunks:
             return SearchResult(out_i, out_d, solved)

@@ -117,10 +117,6 @@ class WcdPruner:
                            index.centroids)
 
 
-# XLA fallback for kernels.rwmd: the kernels' oracle IS the implementation
-# (single source of truth; ref.py imports only jax, so no core<->ops cycle)
-from repro.kernels.ref import rwmd_min_cdist_ref
-
 # Docs per gathered tile when the RWMD bound covers only some docs (a
 # cascade that probes fewer than all clusters): each tile is (Qp, T, L_g)
 # gathered distances, 12.6 MB at Qp = 8, L_g = 192.
@@ -128,17 +124,19 @@ BOUND_TILE = 2048
 
 
 @jax.jit
-def _finite(minm):
-    # all-pad filler rows have minm == +inf; their bounds are don't-care
-    return jnp.where(jnp.isfinite(minm), minm, 0.0)
-
-
-@jax.jit
 def _rwmd_minm(sup, mask, vecs):
     """(Qp, V) distance from every vocabulary word to its nearest live
-    query word, the support gather fused in: the RWMD bound's producer."""
-    return _finite(rwmd_min_cdist_ref(jnp.take(vecs, sup, axis=0), mask,
-                                      vecs))
+    query word, the support gather fused in: the RWMD bound's producer.
+    A (Qp*B, V) cdist GEMM at ``FP32_GEMM``, then the masked min over each
+    query's words."""
+    a = jnp.take(vecs, sup, axis=0)                  # (Qp, B, w)
+    a2 = jnp.sum(a * a, axis=-1)[:, :, None]
+    b2 = jnp.sum(vecs * vecs, axis=-1)[None, None, :]
+    ab = jnp.einsum("qbw,vw->qbv", a, vecs, precision=FP32_GEMM)
+    d = jnp.sqrt(jnp.maximum(a2 + b2 - 2.0 * ab, 0.0))
+    minm = jnp.min(jnp.where(mask[:, :, None] > 0, d, jnp.inf), axis=1)
+    # all-pad filler rows have minm == +inf; their bounds are don't-care
+    return jnp.where(jnp.isfinite(minm), minm, 0.0)
 
 
 @jax.jit
@@ -158,8 +156,7 @@ def _rwmd_rows(lb, minm, pieces):
     return lb
 
 
-def rwmd_bounds(index, sup, mask, doc_ids=None, use_kernel: bool = False,
-                interpret: bool | None = None, lookup=None) -> jax.Array:
+def rwmd_bounds(index, sup, mask, doc_ids=None, lookup=None) -> jax.Array:
     """(Qp, N) device RWMD bounds for one query staging, the one
     implementation every pruner and the cascade share.
 
@@ -168,15 +165,9 @@ def rwmd_bounds(index, sup, mask, doc_ids=None, use_kernel: bool = False,
     at every other doc), in fixed tiles of :data:`BOUND_TILE` docs of
     their groups (:meth:`~.index.CorpusIndex.tiles`, which takes the
     caller's ``lookup``), so the work follows the ids while the compiled
-    shapes follow only the groups. ``use_kernel`` computes the min-cdist
-    with the query-grid Pallas kernel (:mod:`repro.kernels.rwmd`)."""
-    if use_kernel:
-        from repro.kernels import ops
-        minm = _finite(ops.rwmd_min_cdist(jnp.take(index.vecs, sup, axis=0),
-                                          mask, index.vecs,
-                                          interpret=interpret))
-    else:
-        minm = _rwmd_minm(sup, mask, index.vecs)
+    shapes follow only the groups. The min-cdist producer is
+    :func:`_rwmd_minm`."""
+    minm = _rwmd_minm(sup, mask, index.vecs)
     shape = (minm.shape[0], index.n_docs)
     if doc_ids is None:
         return _rwmd_rows(jnp.zeros(shape, minm.dtype), minm, tuple(
@@ -192,24 +183,12 @@ def rwmd_bounds(index, sup, mask, doc_ids=None, use_kernel: bool = False,
 
 
 class RwmdPruner:
-    """Doc-side relaxed WMD — tight, provably <= the engine's score.
-
-    ``use_kernel=True`` computes the masked min-cdist with the query-grid
-    Pallas kernel (:mod:`repro.kernels.rwmd`) so the prune stage is as
-    TPU-resident as the solve stage; the O(nnz) gather stays in XLA either
-    way (same boundary as the solve's G gather).
-    """
+    """Doc-side relaxed WMD — tight, provably <= the engine's score."""
 
     name = "rwmd"
 
-    def __init__(self, use_kernel: bool = False,
-                 interpret: bool | None = None):
-        self.use_kernel = use_kernel
-        self.interpret = interpret
-
     def lower_bounds(self, index, sup, r, mask):
-        return rwmd_bounds(index, sup, mask, use_kernel=self.use_kernel,
-                           interpret=self.interpret)
+        return rwmd_bounds(index, sup, mask)
 
 
 class MaxPruner:
@@ -367,8 +346,7 @@ class CascadePruner:
     """
 
     def __init__(self, stages: Sequence[str] = ("wcd", "rwmd"),
-                 nprobe: int | None = None, use_kernel: bool = False,
-                 interpret: bool | None = None):
+                 nprobe: int | None = None):
         stages = tuple(stages)
         if not stages or any(s not in ("pivot", "wcd", "rwmd")
                              for s in stages):
@@ -376,8 +354,6 @@ class CascadePruner:
                              f"('pivot', 'wcd', 'rwmd'), got {stages!r}")
         self.stages = stages
         self.nprobe = nprobe
-        self.use_kernel = use_kernel
-        self.interpret = interpret
         self.name = "+".join(("ivf",) + stages)
 
     # -------------------------------------------------------- stage 0: ivf
@@ -427,7 +403,7 @@ class CascadePruner:
                     index, sup, mask,
                     None if pm is None else self.probed_members(index, pm,
                                                                 mask),
-                    self.use_kernel, self.interpret, lookup)
+                    lookup)
             out.append(lb if pm is None else _candidates_only(
                 lb, pm, index.clusters.assign_dev))
         return tuple(out)
@@ -490,9 +466,7 @@ PRUNERS = ("wcd", "rwmd", "wcd+rwmd", "ivf", "ivf+wcd", "ivf+rwmd",
            "ivf+wcd+rwmd", "ivf+pivot+wcd+rwmd", "ivf+pivot+rwmd")
 
 
-def resolve_pruner(spec, use_kernel: bool = False,
-                   interpret: bool | None = None,
-                   nprobe: int | None = None):
+def resolve_pruner(spec, nprobe: int | None = None):
     """Turn a spec (``"wcd"``, ``"rwmd"``, ``"wcd+rwmd"``, a cascaded
     ``"ivf[+wcd][+rwmd]"``, or a :class:`Pruner`/:class:`CascadePruner`
     instance) into a pruner instance. ``nprobe`` applies to cascades only
@@ -501,8 +475,7 @@ def resolve_pruner(spec, use_kernel: bool = False,
         parts = [p.strip() for p in spec.replace(",", "+").split("+") if p]
         if parts and parts[0] == "ivf":
             stages = tuple(parts[1:]) or ("wcd", "rwmd")
-            return CascadePruner(stages=stages, nprobe=nprobe,
-                                 use_kernel=use_kernel, interpret=interpret)
+            return CascadePruner(stages=stages, nprobe=nprobe)
         if nprobe is not None:
             raise ValueError(
                 f"nprobe={nprobe} only applies to ivf cascades; "
@@ -512,8 +485,7 @@ def resolve_pruner(spec, use_kernel: bool = False,
             if p == "wcd":
                 made.append(WcdPruner())
             elif p == "rwmd":
-                made.append(RwmdPruner(use_kernel=use_kernel,
-                                       interpret=interpret))
+                made.append(RwmdPruner())
             elif p == "pivot":
                 raise ValueError(
                     "the pivot prestage reads the index's precomputed "

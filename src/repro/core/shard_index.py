@@ -500,7 +500,7 @@ class ShardedWmdEngine:
     mesh), lifts shard-local ids to global ids, and merges with ONE
     ``all_gather`` + local ``top_k``. Exposes the same duck-typed surface
     ``runtime/serving.py`` consumes (``search``, ``min_bucket``,
-    ``iter_stats*``, ``dtype``/``impl``/``interpret``/``precision``)
+    ``iter_stats*``, ``dtype``/``precision``)
     plus sharding extras (``n_shards``, ``docs_per_shard``,
     ``cluster_counts``, ``iter_stats_by_shard``).
 
@@ -543,7 +543,6 @@ class ShardedWmdEngine:
                              for ix in sindex.shards)
         e0 = self.engines[0]
         self.lam, self.n_iter = e0.lam, e0.n_iter
-        self.impl, self.interpret = e0.impl, e0.interpret
         self.min_bucket, self.dtype = e0.min_bucket, e0.dtype
         self.precision, self.tol = e0.precision, e0.tol
         self._pool = ThreadPoolExecutor(max_workers=sindex.n_shards,
@@ -620,11 +619,11 @@ class ShardedWmdEngine:
         (each shard's rows live against its own device-resident ``vecs``
         copy). Recorded in ``_engine_kwargs`` so a restored shard
         (:meth:`restore_shard`) rebuilds with a fresh cache of the same
-        capacity. Returns ``False`` (no-op) on the kernel impl."""
-        ok = all(e.enable_kcache(slots) for e in self.engines)
-        if ok:
-            self._engine_kwargs["kcache_slots"] = int(slots)
-        return ok
+        capacity. Returns ``True``."""
+        for e in self.engines:
+            e.enable_kcache(slots)
+        self._engine_kwargs["kcache_slots"] = int(slots)
+        return True
 
     def kcache_stats(self) -> dict | None:
         """Shard-summed cache counters (``None`` when no shard carries a

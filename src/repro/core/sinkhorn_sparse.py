@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.kernels.sddmm_spmm import reconstruct_gm  # re-exported
 from .sinkhorn import LamUnderflowError, cdist, underflow_report
 from .sparse import PaddedDocs
 
@@ -114,13 +115,6 @@ class SparsePrecomputeLog(NamedTuple):
     G_over_r: jax.Array   # (v_r, N, L)  diag(1/r) G
     val: jax.Array        # (N, L)       normalized frequencies (0 = pad)
     shift: jax.Array      # (N, L)       per-column max of -lam*M (<= 0)
-
-
-def reconstruct_gm(G: jax.Array, lam) -> jax.Array:
-    """(K*M) gathered == -G*log(G)/lam; G == 0 entries (padding or exp
-    underflow) map to 0, matching the materialized gather."""
-    safe = jnp.where(G > 0, G, 1.0)
-    return jnp.where(G > 0, -G * jnp.log(safe), 0.0) / lam
 
 
 def log_shift_correction(shift: jax.Array, val: jax.Array,
@@ -216,26 +210,21 @@ def marginal_residual_per_query(w, w_prev, mask):
 
 
 def adaptive_loop(step, residual, x0, n_iter: int, tol: float,
-                  check_every: int, all_reduce=None,
-                  use_fori: bool = False):
+                  check_every: int, all_reduce=None):
     """Shared convergence-adaptive driver for every Sinkhorn variant
-    (einsum engine, single-query sparse, distributed shards, Pallas
-    kernel bodies — ONE copy of the exit machinery).
+    (einsum engine, single-query sparse, distributed shards — ONE copy
+    of the exit machinery).
 
     ``step(x) -> (x, w)`` runs one iteration; ``residual(w, w_prev)``
     reduces to the scalar exit statistic (:func:`marginal_residual` with
     the variant's own mask); ``all_reduce`` (optional) agrees on the
-    residual across shards (the distributed ``lax.pmax``);
-    ``use_fori=True`` drives the inner window with ``fori_loop`` instead
-    of ``scan`` (Pallas kernel bodies). The window is SEEDED with one
-    real iteration — against ``w_prev == 0`` the first residual would be
-    exactly 1.0 and a whole check period would be wasted — so realized
-    counts land on ``1 + k*check_every`` with ``n_iter`` the cap
-    (overshot by at most ``check_every - 1``). Returns (x, iters)."""
+    residual across shards (the distributed ``lax.pmax``). The window
+    is SEEDED with one real iteration — against ``w_prev == 0`` the
+    first residual would be exactly 1.0 and a whole check period would
+    be wasted — so realized counts land on ``1 + k*check_every`` with
+    ``n_iter`` the cap (overshot by at most ``check_every - 1``).
+    Returns (x, iters)."""
     def window(x, w):
-        if use_fori:
-            return lax.fori_loop(0, check_every,
-                                 lambda _, c: step(c[0]), (x, w))
         out, _ = lax.scan(lambda c, _: (step(c[0]), None), (x, w), None,
                           length=check_every)
         return out

@@ -13,8 +13,9 @@ and against the exact-LP oracle):
                     adaptation) — the production path
   sparse_unfused    separate SDDMM / SpMM with per-iteration gathers (paper
                     Fig. 3 before fusion; for the fusion ablation)
-  kernel            Pallas SDDMM_SpMM kernel path (TPU target; interpret-mode
-                    on CPU)
+  kernel            Pallas path (TPU target; interpret-mode on CPU): the
+                    Pallas cdist, then the engine's row gather and its
+                    VMEM-resident solve (``sinkhorn_resident``) at Q = 1
 
 Top-k retrieval goes through the staged pipeline (prune -> solve -> rank,
 :meth:`repro.core.index.WmdEngine.search`); :func:`search` is the one-shot
@@ -23,15 +24,60 @@ the corpus freeze across query batches).
 """
 from __future__ import annotations
 
+import functools
+
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from .sinkhorn import (LamUnderflowError, select_support, sinkhorn_wmd_dense,
                        sinkhorn_wmd_dense_stabilized, underflow_report)
-from .sinkhorn_sparse import sinkhorn_wmd_sparse, sinkhorn_wmd_sparse_unfused
+from .sinkhorn_sparse import (SolvePrecision, sinkhorn_wmd_sparse,
+                              sinkhorn_wmd_sparse_unfused)
 from .sparse import PaddedDocs, padded_docs_to_dense
 
 IMPLS = ("dense", "dense_stabilized", "sparse", "sparse_unfused", "kernel")
+
+
+@functools.partial(jax.jit, static_argnames=("lam", "n_iter", "precision",
+                                             "interpret"))
+def _kernel_wmd(r, vecs_sel, vecs, docs: PaddedDocs, lam: float,
+                n_iter: int, precision=None, interpret: bool | None = None):
+    """The kernel path: Pallas cdist -> the engine's whole-row gather ->
+    the resident solve, for one query of v_r words against ``docs``.
+
+    The query is staged as the engine stages a chunk of one: its rows
+    padded to a multiple of 8 with r = 1, K = 0 (-inf under the log
+    domain) and mask 0, and K handed over vocabulary-major, (V, B).
+    ``precision`` (a ``SolvePrecision`` or its spelling) sets the bf16
+    operands and the log domain of both kernels; under ``log_domain``
+    the cdist emits UNexponentiated log K, so no column can underflow at
+    any lam. Returns (N,) distances."""
+    from repro.kernels import ops
+    from .index import _gather_g
+    precision = SolvePrecision.parse(precision)
+    k = ops.cdist_exp(vecs_sel, vecs, r, lam, interpret=interpret,
+                      k_only=True, gemm=precision.gemm,
+                      log_k=precision.log_domain)          # (v_r, V)
+    row_pad = -jnp.inf if precision.log_domain else 0.0
+    kt = ops.pad_to(k, 0, 8, value=row_pad).T              # (V, B)
+    block_n = 128
+    g = _gather_g(kt, docs.idx, layout="qlbn", block_n=block_n, q=1)
+    rp = ops.pad_to(r, 0, 8, value=1.0)
+    mask = ops.pad_to(jnp.ones_like(r), 0, 8)
+    return ops.sinkhorn_resident(
+        g, docs.val, rp[None], mask[None], lam, n_iter, block_n=block_n,
+        interpret=interpret, gemm=precision.gemm,
+        log_domain=precision.log_domain)[0]
+
+
+# the impls that solve on the ELL docs directly
+_ELL_SOLVES = {
+    # one_to_many's own underflow check covers this impl: skip the solver's
+    "sparse": functools.partial(sinkhorn_wmd_sparse, check_underflow=False),
+    "sparse_unfused": sinkhorn_wmd_sparse_unfused,
+    "kernel": _kernel_wmd,
+}
 
 
 def one_to_many(r_full, docs: PaddedDocs, vecs, lam: float = 10.0,
@@ -50,16 +96,8 @@ def one_to_many(r_full, docs: PaddedDocs, vecs, lam: float = 10.0,
     vecs = jnp.asarray(vecs, dtype)
     r, vecs_sel, _ = select_support(r_full, vecs, dtype)
 
-    if impl == "sparse":
-        # the unified check below covers this impl — skip the solver's own
-        out = sinkhorn_wmd_sparse(r, vecs_sel, vecs, docs, lam, n_iter,
-                                  check_underflow=False)
-    elif impl == "sparse_unfused":
-        out = sinkhorn_wmd_sparse_unfused(r, vecs_sel, vecs, docs, lam,
-                                          n_iter)
-    elif impl == "kernel":
-        from repro.kernels.ops import sinkhorn_wmd_kernel
-        out = sinkhorn_wmd_kernel(r, vecs_sel, vecs, docs, lam, n_iter)
+    if impl in _ELL_SOLVES:
+        out = _ELL_SOLVES[impl](r, vecs_sel, vecs, docs, lam, n_iter)
     else:
         c = jnp.asarray(padded_docs_to_dense(docs, vecs.shape[0]), dtype)
         if impl == "dense":
@@ -85,20 +123,18 @@ def many_to_many(queries: list[np.ndarray], docs: PaddedDocs, vecs,
     """
     if batched and impl in ("sparse", "kernel"):
         from .index import WmdEngine, build_index
-        engine = WmdEngine(build_index(docs, vecs), lam=lam, n_iter=n_iter,
-                           impl=impl)
+        engine = WmdEngine(build_index(docs, vecs), lam=lam, n_iter=n_iter)
         out = engine.query_batch(queries)
         return [out[i] for i in range(out.shape[0])]
     return [one_to_many(q, docs, vecs, lam, n_iter, impl) for q in queries]
 
 
 def search(queries, docs: PaddedDocs, vecs, k: int = 10, lam: float = 10.0,
-           n_iter: int = 15, impl: str = "sparse", prune: object = "rwmd"):
+           n_iter: int = 15, prune: object = "rwmd"):
     """One-shot top-k retrieval through the staged pipeline: freeze an index,
     prune with an admissible lower bound, Sinkhorn-solve the survivors, rank.
     Returns a :class:`repro.core.index.SearchResult`. ``prune=None`` scores
     every document (exhaustive oracle path)."""
     from .index import WmdEngine, build_index
-    engine = WmdEngine(build_index(docs, vecs), lam=lam, n_iter=n_iter,
-                       impl=impl)
+    engine = WmdEngine(build_index(docs, vecs), lam=lam, n_iter=n_iter)
     return engine.search(queries, k, prune=prune)

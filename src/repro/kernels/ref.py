@@ -18,16 +18,6 @@ def cdist_exp_ref(a, b, r, lam: float):
     return m, k, k / r[:, None]
 
 
-def rwmd_min_cdist_ref(a, mask, b):
-    """Oracle for kernels.rwmd.rwmd_min_cdist: masked min-over-support
-    distances. a (Q, B, w), mask (Q, B), b (V, w) -> (Q, V)."""
-    a2 = jnp.sum(a * a, axis=-1)[:, :, None]
-    b2 = jnp.sum(b * b, axis=-1)[None, None, :]
-    ab = jnp.einsum("qbw,vw->qbv", a, b, precision=FP32_GEMM)
-    d = jnp.sqrt(jnp.maximum(a2 + b2 - 2.0 * ab, 0.0))
-    return jnp.min(jnp.where(mask[:, :, None] > 0, d, jnp.inf), axis=1)
-
-
 def _safe_inv(x):
     return jnp.where(x > 0, 1.0 / jnp.where(x > 0, x, 1.0), 0.0)
 
@@ -70,7 +60,8 @@ def reconstruct_gm_ref(g, lam: float):
 
 
 def sinkhorn_fused_all_ref(g, val, r, lam: float, n_iter: int):
-    """Oracle for kernels.sinkhorn_fused_all (full solve + distance; GM
-    reconstructed from G exactly as the kernel does)."""
+    """Oracle for one query of kernels.sinkhorn_resident (full solve +
+    distance; GM reconstructed from G exactly as the kernel does). g is
+    the query's (v_r, N, L) tile, val (N, L), r (v_r,)."""
     return sinkhorn_fused_all_materialized_ref(g, reconstruct_gm_ref(g, lam),
                                                val, r, n_iter)

@@ -12,7 +12,7 @@ scores Q stream requests per fused solve; ``--top-k K`` switches to the
 staged retrieval pipeline — prune with ``--prune`` bounds, Sinkhorn-solve
 only the surviving candidates, rank):
     PYTHONPATH=src python -m repro.launch.serve --wmd --n-docs 2048 \
-        --impl kernel --batch-queries 8
+        --batch-queries 8
     PYTHONPATH=src python -m repro.launch.serve --wmd --n-docs 2048 \
         --top-k 10 --prune rwmd
     PYTHONPATH=src python -m repro.launch.serve --wmd --n-docs 8192 \
@@ -90,7 +90,7 @@ def _build_wmd_engine(args, corpus):
     host-platform devices right after argparse (before the first jax
     array op); the ``ensure_host_devices`` here re-validates the count
     for callers that enter below ``main()``."""
-    kw = dict(lam=args.lam, n_iter=args.n_iter, impl=args.impl,
+    kw = dict(lam=args.lam, n_iter=args.n_iter,
               tol=args.tol if args.tol > 0 else None,
               check_every=args.check_every, precision=args.precision,
               scope=args.scope, warm_start=args.warm_start)
@@ -181,7 +181,6 @@ def serve_wmd(args) -> None:
     p50 = float(np.percentile(times, 50))   # median: late batches may still
     rec = {                                 # compile fresh bucket shapes
         "workload": "wmd_topk" if args.top_k > 0 else "wmd_batched",
-        "impl": args.impl,
         "n_docs": args.n_docs, "vocab": args.vocab, "batch_queries": bq,
         "ms_per_batch_p50": round(p50, 2),
         "queries_per_s": round(bq / (p50 / 1e3), 1),
@@ -296,7 +295,7 @@ def serve_async(args) -> None:
     span = float(arrivals[-1]) + max(
         (r.service_ms for r in responses), default=0.0) / 1e3
     print(json.dumps({
-        "workload": "wmd_serve", "impl": args.impl,
+        "workload": "wmd_serve",
         "n_docs": args.n_docs, "requests": n, "rate_qps": args.rate,
         "latency_ms_p50": round(float(np.percentile(lat, 50)), 2)
         if lat.size else None,
@@ -314,7 +313,6 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--steps", type=int, default=32)
     ap.add_argument("--wmd", action="store_true")
-    ap.add_argument("--impl", default="sparse")
     ap.add_argument("--batch-queries", type=int, default=8)
     ap.add_argument("--top-k", type=int, default=0,
                     help="> 0: staged top-k retrieval (prune->solve->rank) "
@@ -400,8 +398,7 @@ def main() -> None:
                          "(ServeConfig.kcache_slots); 0: disabled "
                          "everywhere; > 0: enabled at engine build with "
                          "this many device-resident (V,) rows. Results "
-                         "are bit-exact either way; requires "
-                         "--impl sparse")
+                         "are bit-exact either way")
     ap.add_argument("--inject-latency-rate", type=float, default=0.0,
                     help="fault injection: per-attempt probability of "
                          "added dispatch latency")

@@ -471,8 +471,7 @@ def rwmd_topk(engine: WmdEngine, queries: Sequence, k: int):
     out_d = np.full((len(queries), k), np.nan, engine.dtype)
     if not queries or n == 0 or k == 0:
         return out_i, out_d
-    pruner = RwmdPruner(use_kernel=(engine.impl == "kernel"),
-                        interpret=engine.interpret)
+    pruner = RwmdPruner()
     _, chunks = engine._plan(queries)
     for chunk, width in chunks:
         sup, r, mask = engine._prep_chunk([queries[qi] for qi in chunk],

@@ -14,8 +14,8 @@ converged profile. These tests pin the metamorphic contracts:
   (realized per-query iters asserted), and query/doc padding is inert;
 - warm-started survivor solves == cold solves bit-tolerant, with
   strictly fewer realized survivor iterations;
-- the distributed per-query (Q,) ``lax.pmax`` path and the kernel
-  ``resmask`` scoping agree with their unscoped selves where it matters.
+- the distributed per-query (Q,) ``lax.pmax`` path agrees with its
+  unscoped self where it matters.
 """
 
 import numpy as np
@@ -25,7 +25,6 @@ import pytest
 
 from repro.core import WmdEngine, build_index
 from repro.core.index import DocGroup, _gather_g, _solve_gathered
-from repro.kernels import ops
 
 
 @pytest.fixture(scope="module")
@@ -276,31 +275,3 @@ def test_sparse_solver_doc_mask_scoping(dedup):
         doc_mask=np.zeros(256, bool), return_iters=True)
     assert int(it_none) == 3, it_none        # 1 seed + one check window
 
-
-# ------------------------------------------------------------- kernel path
-def test_kernel_resmask_scoping(rng):
-    """Kernel resmask: an all-ones scope is identical to no scope; an
-    empty scope exits at the first check; a candidate scope's docs match
-    the unscoped solve at tolerance."""
-    q_n, v_r, n, length = 2, 8, 64, 8
-    g = jnp.asarray(rng.uniform(0.05, 1.0, (q_n, v_r, n, length)),
-                    dtype=jnp.float32)
-    val = jnp.where(jnp.asarray(rng.random((n, length))) > 0.3, 0.7, 0.0)
-    val = val.at[:, 0].set(1.0)
-    r = jnp.asarray(rng.uniform(0.1, 1.0, (q_n, v_r)).astype(np.float32))
-    kw = dict(block_n=32, tol=1e-3, check_every=3, with_iters=True)
-    base, it_b = ops.sinkhorn_fused_all_batched(g, val, r, 4.0, 40, **kw)
-    ones, it_o = ops.sinkhorn_fused_all_batched(
-        g, val, r, 4.0, 40, resmask=jnp.ones((q_n, n)), **kw)
-    np.testing.assert_array_equal(np.asarray(it_o), np.asarray(it_b))
-    np.testing.assert_array_equal(np.asarray(ones), np.asarray(base))
-    # empty scope for query 1: its blocks exit at the first check
-    rm = np.ones((q_n, n), np.float32)
-    rm[1] = 0.0
-    part, it_p = ops.sinkhorn_fused_all_batched(
-        g, val, r, 4.0, 40, resmask=jnp.asarray(rm), **kw)
-    it_p = np.asarray(it_p)
-    assert (it_p[1] == 4).all(), it_p          # 1 seed + one check window
-    assert (it_p[0] == np.asarray(it_b)[0]).all()
-    np.testing.assert_allclose(np.asarray(part)[0], np.asarray(base)[0],
-                               rtol=1e-6, atol=1e-6)

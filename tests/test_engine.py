@@ -8,12 +8,13 @@ equals the materialized (K*M) gather on both solver paths.
 import numpy as np
 import jax.numpy as jnp
 import pytest
+from _resident import solve_knl
 
+import repro.core.index as index_mod
 from repro.core import (WmdEngine, build_index, bucket_size, many_to_many,
                         one_to_many, reconstruct_gm, select_support)
 from repro.core.sinkhorn import cdist
 from repro.data.corpus import make_corpus
-from repro.kernels import ops
 from repro.kernels.ref import (reconstruct_gm_ref, sinkhorn_fused_all_ref,
                                sinkhorn_fused_all_materialized_ref)
 
@@ -39,14 +40,16 @@ def test_bucket_size_policy():
     assert bucket_size(3, min_bucket=4) == 4
 
 
-@pytest.mark.parametrize("impl", ["sparse", "kernel"])
-def test_batched_matches_per_query_oracle(engine_corpus, impl):
-    """Engine distances == per-query sparse oracle, across buckets."""
+@pytest.mark.parametrize("impl", ["sparse", "resident"])
+def test_batched_matches_per_query_oracle(engine_corpus, impl, monkeypatch):
+    """Engine distances == per-query sparse oracle, across buckets, on
+    the einsum and (its platform probe patched) the resident solve."""
     c = engine_corpus
-    eng = WmdEngine(build_index(c.docs, c.vecs), lam=8.0, n_iter=15,
-                    impl=impl)
+    monkeypatch.setattr(index_mod, "_on_tpu", lambda x: impl == "resident")
+    eng = WmdEngine(build_index(c.docs, c.vecs), lam=8.0, n_iter=15)
     got = np.asarray(eng.query_batch(list(c.queries)))
     assert got.shape == (len(c.queries), c.docs.n_docs)
+    assert (eng.host_stats()["resident_solves"] > 0) == (impl == "resident")
     for i, q in enumerate(c.queries):
         ref = _oracle(c, q, 8.0, 15)
         np.testing.assert_allclose(got[i], ref, rtol=5e-4, atol=5e-4)
@@ -127,8 +130,8 @@ def test_reconstruct_gm_equals_materialized(engine_corpus, rng):
 
 
 def test_kernel_path_gm_reconstruction(rng):
-    """Fused kernel (interpret) with in-VMEM GM reconstruction matches the
-    explicit materialized-GM oracle."""
+    """Resident kernel (interpret) with in-VMEM GM reconstruction matches
+    the explicit materialized-GM oracle."""
     v_r, n, length, lam, n_iter = 12, 64, 16, 4.0, 12
     g = jnp.asarray(rng.uniform(0.02, 1.0, (v_r, n, length)) ** 2,
                     dtype=jnp.float32)
@@ -137,7 +140,7 @@ def test_kernel_path_gm_reconstruction(rng):
     val = val.at[:, 0].set(1.0)
     r = jnp.asarray(rng.uniform(0.1, 1.0, v_r).astype(np.float32))
     gm_mat = reconstruct_gm_ref(g, lam)     # == -g*log(g)/lam, materialized
-    out = ops.sinkhorn_fused_all(g, val, r, lam, n_iter)
+    out = solve_knl(g[None], val, r[None], lam, n_iter)[0]
     want = sinkhorn_fused_all_materialized_ref(g, gm_mat, val, r, n_iter)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=5e-5, atol=5e-5)
@@ -160,17 +163,17 @@ def test_sparse_precompute_sheds_gm(engine_corpus):
 
 # -------------------------------------------------- batched kernel vs einsum
 def test_batched_kernel_matches_per_query_kernel(rng):
-    """sinkhorn_fused_all_batched == Q independent sinkhorn_fused_all."""
+    """sinkhorn_resident at Q = 3 == three calls at Q = 1."""
     q_n, v_r, n, length, lam, n_iter = 3, 10, 64, 16, 5.0, 10
     g = jnp.asarray(rng.uniform(0.02, 1.0, (q_n, v_r, n, length)),
                     dtype=jnp.float32)
     val = jnp.where(jnp.asarray(rng.random((n, length))) > 0.4, 0.5, 0.0)
     val = val.at[:, 0].set(1.0)
     r = jnp.asarray(rng.uniform(0.1, 1.0, (q_n, v_r)).astype(np.float32))
-    batched = ops.sinkhorn_fused_all_batched(g, val, r, lam, n_iter)
+    batched = solve_knl(g, val, r, lam, n_iter)
     assert batched.shape == (q_n, n)
     for qi in range(q_n):
-        single = ops.sinkhorn_fused_all(g[qi], val, r[qi], lam, n_iter)
+        single = solve_knl(g[qi:qi + 1], val, r[qi:qi + 1], lam, n_iter)[0]
         np.testing.assert_allclose(np.asarray(batched[qi]),
                                    np.asarray(single), rtol=5e-5, atol=5e-5)
 
@@ -184,9 +187,9 @@ def test_batched_kernel_pad_query_inert(rng):
     val = jnp.where(jnp.asarray(rng.random((n, length))) > 0.3, 0.7, 0.0)
     val = val.at[:, 0].set(1.0)
     r = jnp.asarray(rng.uniform(0.1, 1.0, (q_n, v_r)).astype(np.float32))
-    base = ops.sinkhorn_fused_all_batched(g, val, r, 4.0, 8)
+    base = solve_knl(g, val, r, 4.0, 8)
     g2 = jnp.concatenate([g, jnp.zeros((1, v_r, n, length))])
     r2 = jnp.concatenate([r, jnp.ones((1, v_r))])
-    padded = ops.sinkhorn_fused_all_batched(g2, val, r2, 4.0, 8)
+    padded = solve_knl(g2, val, r2, 4.0, 8)
     np.testing.assert_allclose(np.asarray(padded[:q_n]), np.asarray(base),
                                rtol=1e-6, atol=1e-6)

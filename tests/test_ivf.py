@@ -197,16 +197,13 @@ def test_partial_probe_bounds_only_probed_members(corpus, engine):
     assert members.size < index.n_docs
 
 
-@pytest.mark.parametrize("use_kernel", [False, True])
-def test_cascade_rwmd_stage_matches_full_pruner(corpus, engine, use_kernel):
+def test_cascade_rwmd_stage_matches_full_pruner(corpus, engine):
     """The cascade's RWMD stage == the full-sweep RwmdPruner's bounds
-    (one implementation, rwmd_bounds), whether its min-cdist runs in XLA
-    or in the Pallas kernel."""
+    (one implementation, rwmd_bounds)."""
     qc, sup, r, mask = _staged(engine, list(corpus.queries[:4]))
     index = engine.index
     full = np.asarray(RwmdPruner().lower_bounds(index, sup, r, mask))
-    casc = CascadePruner(use_kernel=use_kernel,
-                         interpret=True if use_kernel else None)
+    casc = CascadePruner()
     _, pm, qcent = casc.probe(index, sup, r, mask)
     (lb,) = casc.bounds(index, sup, mask, qcent, pm, ("rwmd",))
     np.testing.assert_allclose(np.asarray(lb)[:qc], full[:qc],

@@ -57,12 +57,15 @@ def test_kcache_rejects_zero_slots(small_corpus, index):
 
 
 def test_kernel_impl_refuses_cache(index):
-    with pytest.raises(ValueError):
+    """``impl="sparse"`` is the engine's only value: a config asking for
+    the deleted ``"kernel"`` impl fails loudly, with or without a cache."""
+    with pytest.raises(ValueError, match="impl"):
         WmdEngine(index, lam=LAM, impl="kernel", kcache_slots=8)
-    eng = WmdEngine(index, lam=LAM, impl="kernel")
-    # serving's enable-by-default path must be a quiet no-op here
-    assert eng.enable_kcache(8) is False
-    assert eng.kcache_stats() is None
+    with pytest.raises(ValueError, match="impl"):
+        WmdEngine(index, lam=LAM, impl="kernel")
+    eng = WmdEngine(index, lam=LAM, impl="sparse")
+    assert eng.enable_kcache(8) is True
+    assert eng.kcache_stats() is not None
 
 
 def test_rows_match_direct_cdist_and_lru_evicts_oldest(rng):

@@ -4,6 +4,7 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 from _hypothesis_compat import given, settings, st
+from _resident import solve_knl
 
 from repro.kernels import ops
 from repro.kernels.ref import (cdist_exp_ref, sddmm_spmm_step_ref,
@@ -72,41 +73,79 @@ def test_sddmm_spmm_step_shapes(rng, v_r, n, length, block_n):
     np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
 
 
-# --------------------------------------------------------- fused full solver
-def _rand_g(rng, v_r, n, length):
+# ------------------------------------------------------ resident full solver
+def _rand_g(rng, *shape):
     """G entries as the solver sees them: gathered K = exp(-lam*M) in (0, 1]."""
-    return jnp.asarray(rng.uniform(0.02, 1.0,
-                                   (v_r, n, length)).astype(np.float32))
+    return jnp.asarray(rng.uniform(0.02, 1.0, shape).astype(np.float32))
 
 
-@pytest.mark.parametrize("v_r,n,length,n_iter,block_n", [
-    (19, 128, 40, 15, 64), (8, 64, 16, 5, 32), (43, 256, 64, 25, 128),
-])
+SHAPES = [(19, 128, 40, 15, 64), (8, 64, 16, 5, 32), (43, 256, 64, 25, 128)]
+
+
+@pytest.mark.parametrize("v_r,n,length,n_iter,block_n", SHAPES)
 def test_sinkhorn_fused_all_shapes(rng, v_r, n, length, n_iter, block_n):
+    """The resident solve on the (1, L, v_r, N_pad) transpose of one
+    query's tile, unaligned v_r and L included, against the oracle."""
     g = _rand_g(rng, v_r, n, length)
     val = jnp.abs(_rand(rng, n, length))
     val = jnp.where(val > 0.5, val, 0.0)
     val = val.at[:, 0].set(1.0)                   # every doc has >=1 word
     r = jnp.asarray(rng.uniform(0.1, 1.0, v_r).astype(np.float32))
     lam = 7.0
-    out = ops.sinkhorn_fused_all(g, val, r, lam, n_iter, block_n=block_n)
+    out = solve_knl(g[None], val, r[None], lam, n_iter, block_n=block_n)[0]
     ref = sinkhorn_fused_all_ref(g, val, r, lam, n_iter)
     np.testing.assert_allclose(out, ref, rtol=5e-5, atol=5e-5)
+
+
+@pytest.mark.parametrize("v_r,n,length,n_iter,block_n", SHAPES)
+def test_resident_shapes_multi_query(rng, v_r, n, length, n_iter, block_n):
+    """The same shape grid at Q = 3: each query's row of the multi-query
+    grid equals the oracle on that query's tile."""
+    q = 3
+    g = _rand_g(rng, q, v_r, n, length)
+    val = jnp.abs(_rand(rng, n, length))
+    val = jnp.where(val > 0.5, val, 0.0)
+    val = val.at[:, 0].set(1.0)
+    r = jnp.asarray(rng.uniform(0.1, 1.0, (q, v_r)).astype(np.float32))
+    lam = 7.0
+    out = solve_knl(g, val, r, lam, n_iter, block_n=block_n)
+    assert out.shape == (q, n)
+    for qi in range(q):
+        ref = sinkhorn_fused_all_ref(g[qi], val, r[qi], lam, n_iter)
+        np.testing.assert_allclose(out[qi], ref, rtol=5e-5, atol=5e-5)
 
 
 def test_fused_all_handles_padded_rows(rng):
     """Padded query rows (G row == 0, r == 1) must be exactly inert."""
     v_r, n, length = 10, 64, 16
-    g = _rand_g(rng, v_r, n, length)
+    g = _rand_g(rng, 1, v_r, n, length)
     val = jnp.where(jnp.abs(_rand(rng, n, length)) > 0.5, 1.0, 0.0)
     val = val.at[:, 0].set(1.0)
-    r = jnp.asarray(rng.uniform(0.1, 1.0, v_r).astype(np.float32))
-    base = ops.sinkhorn_fused_all(g, val, r, 5.0, 10)
+    r = jnp.asarray(rng.uniform(0.1, 1.0, (1, v_r)).astype(np.float32))
+    base = solve_knl(g, val, r, 5.0, 10)
     # append 6 dead rows
-    zpad = jnp.zeros((6, n, length))
-    g2 = jnp.concatenate([g, zpad])
-    r2 = jnp.concatenate([r, jnp.ones(6)])
-    padded = ops.sinkhorn_fused_all(g2, val, r2, 5.0, 10)
+    zpad = jnp.zeros((1, 6, n, length))
+    g2 = jnp.concatenate([g, zpad], axis=1)
+    r2 = jnp.concatenate([r, jnp.ones((1, 6))], axis=1)
+    padded = solve_knl(g2, val, r2, 5.0, 10)
+    np.testing.assert_allclose(padded, base, rtol=1e-6, atol=1e-6)
+
+
+def test_resident_log_domain_padded_rows_inert(rng):
+    """Under ``log_domain`` a padded query row holds log K = -inf (a 0
+    would be a valid entry, distance 0) with r == 1 and mask 0: the
+    distances equal those without the rows."""
+    v_r, n, length, lam = 10, 64, 16, 5.0
+    logk = jnp.log(_rand_g(rng, 1, v_r, n, length))
+    val = jnp.where(jnp.abs(_rand(rng, n, length)) > 0.5, 1.0, 0.0)
+    val = val.at[:, 0].set(1.0)
+    r = jnp.asarray(rng.uniform(0.1, 1.0, (1, v_r)).astype(np.float32))
+    base = solve_knl(logk, val, r, lam, 10, log_domain=True)
+    g2 = jnp.concatenate([logk, jnp.full((1, 6, n, length), -jnp.inf)],
+                         axis=1)
+    r2 = jnp.concatenate([r, jnp.ones((1, 6))], axis=1)
+    padded = solve_knl(g2, val, r2, lam, 10, log_domain=True)
+    assert bool(jnp.all(jnp.isfinite(padded)))
     np.testing.assert_allclose(padded, base, rtol=1e-6, atol=1e-6)
 
 

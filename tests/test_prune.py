@@ -18,12 +18,9 @@ from repro.core import (LamUnderflowError, MaxPruner, RwmdPruner,
                         build_index, one_to_many, resolve_pruner,
                         select_support)
 from repro.core.exact_ot import exact_emd
-from repro.core.prune import _rwmd_minm
 from repro.core.sinkhorn import cdist
 from repro.core.sparse import PaddedDocs
 from repro.data.corpus import make_corpus
-from repro.kernels import ops
-from repro.kernels.ref import rwmd_min_cdist_ref
 
 
 @pytest.fixture(scope="module")
@@ -112,25 +109,6 @@ def test_bound_chain_vs_exact_lp(seed):
         assert lp <= sink[j] * 1.05 + 0.05, (lp, sink[j])
 
 
-# ----------------------------------------------------------- rwmd min-cdist
-def test_rwmd_min_cdist_kernel_matches_ref(rng):
-    a = jnp.asarray(rng.standard_normal((3, 12, 40)).astype(np.float32))
-    b = jnp.asarray(rng.standard_normal((300, 40)).astype(np.float32))
-    mask = jnp.asarray((rng.random((3, 12)) > 0.3).astype(np.float32))
-    mask = mask.at[:, 0].set(1.0)             # every query has support
-    want = rwmd_min_cdist_ref(a, mask, b)
-    got = ops.rwmd_min_cdist(a, mask, b, block_v=128)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-4, atol=1e-4)
-    # the program's XLA producer takes the query words from the
-    # vocabulary by id
-    sup = jnp.asarray(rng.integers(0, 300, (3, 12)).astype(np.int32))
-    want_v = rwmd_min_cdist_ref(jnp.take(b, sup, axis=0), mask, b)
-    got_xla = _rwmd_minm(sup, mask, b)
-    np.testing.assert_allclose(np.asarray(got_xla), np.asarray(want_v),
-                               rtol=1e-6, atol=1e-6)
-
-
 # ------------------------------------------------------------------- search
 @pytest.mark.parametrize("prune", ["wcd", "rwmd", "wcd+rwmd"])
 @pytest.mark.parametrize("k", [1, 5])
@@ -170,18 +148,6 @@ def test_search_solves_strict_subset_on_separable_corpus():
     for qi in range(len(queries)):
         assert set(ex.indices[qi]) == set(pr.indices[qi])
     assert (pr.solved < 128).all(), pr.solved     # < half the corpus
-
-def test_search_kernel_impl_matches_sparse(corpus):
-    qs = list(corpus.queries[:3])
-    es = WmdEngine(build_index(corpus.docs, corpus.vecs), lam=6.0, n_iter=8,
-                   impl="sparse")
-    ek = WmdEngine(build_index(corpus.docs, corpus.vecs), lam=6.0, n_iter=8,
-                   impl="kernel")
-    rs = es.search(qs, 4, prune="rwmd")
-    rk = ek.search(qs, 4, prune="rwmd")
-    np.testing.assert_array_equal(rs.indices, rk.indices)
-    np.testing.assert_allclose(rs.distances, rk.distances,
-                               rtol=5e-4, atol=5e-4)
 
 
 def test_search_empty_and_edge_queries(corpus, engine):

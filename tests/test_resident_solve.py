@@ -12,10 +12,11 @@ import jax.numpy as jnp
 import pytest
 
 import repro.core.index as index_mod
-from repro.core import LamUnderflowError, WmdEngine, build_index
+from repro.core import LamUnderflowError, WmdEngine, build_index, one_to_many
 from repro.core.index import _gather_g
 from repro.data.corpus import make_corpus
 from repro.kernels import ops
+from repro.kernels.ref import sinkhorn_fused_all_ref
 
 LAM, N_ITER = 1.0, 10
 # query lengths: chunks of 1, 2, 3 (padded to 4 with a filler) and 4
@@ -96,7 +97,6 @@ def test_search_topk_matches_einsum(index, queries, monkeypatch, prune, k):
     ("tpu+tol", False),
     ("tpu+profile", False),
     ("tpu+warm", False),
-    ("tpu+kernel_impl", False),
 ])
 def test_selection_rule(index, queries, monkeypatch, case, resident):
     """The resident solve runs only on a TPU, without ``tol``; every
@@ -105,8 +105,7 @@ def test_selection_rule(index, queries, monkeypatch, case, resident):
     monkeypatch.setattr(index_mod, "_on_tpu", lambda x: case != "cpu")
     adaptive = case in ("tpu+tol", "tpu+profile", "tpu+warm")
     eng = WmdEngine(index, lam=LAM, n_iter=N_ITER,
-                    tol=1e-3 if adaptive else None,
-                    impl="kernel" if case == "tpu+kernel_impl" else "sparse")
+                    tol=1e-3 if adaptive else None)
     chunk = queries[1:3]
     sup, r, mask = eng._prep_chunk(chunk, 16)
     kq = eng._kq(sup, mask)
@@ -211,25 +210,30 @@ def test_block_wide_underflow_raises(corpus, monkeypatch, impl, tpu):
     """A live query whose K underflows against every doc word of a block
     raises on every path, rather than scoring the block's docs 0 as an
     all-pad filler query would be: its words lie far from every doc
-    word, so each G entry of the block is 0."""
+    word, so each G entry of the block is 0. ``"kernel"`` is
+    ``one_to_many``'s Pallas route into the same resident solve."""
     monkeypatch.setattr(index_mod, "_on_tpu", lambda x: tpu)
     vecs = np.asarray(corpus.vecs)
     far = np.full((3, vecs.shape[1]), 1e3, vecs.dtype)
-    index = build_index(corpus.docs, np.concatenate([vecs, far]))
-    eng = WmdEngine(index, lam=LAM, n_iter=N_ITER, impl=impl)
-    row = np.zeros(index.vecs.shape[0], np.float32)
+    vecs = np.concatenate([vecs, far])
+    row = np.zeros(vecs.shape[0], np.float32)
     row[-3:] = 1.0
+    if impl != "sparse":
+        with pytest.raises(LamUnderflowError):
+            one_to_many(row, corpus.docs, vecs, lam=LAM, n_iter=N_ITER,
+                        impl="kernel")
+        return
+    index = build_index(corpus.docs, vecs)
+    eng = WmdEngine(index, lam=LAM, n_iter=N_ITER, impl=impl)
     with pytest.raises(LamUnderflowError):
         eng.query_batch([row])
     assert eng.host_stats()["resident_solves"] == (
         len(index.groups) if tpu else 0)
 
 
-@pytest.mark.parametrize("n_docs", [128, 200])
-def test_resident_kernel_matches_fused_kernel(n_docs):
-    """The two layouts of the shared body give one answer: the resident
-    (L, B, N) tile against the kernel impl's (B, N, L) tile, inert pad
-    docs and a zero pad query row included."""
+def _oracle_tiles(n_docs):
+    """Two queries' (B, N, L) tiles, the last query row a pad row (G 0,
+    r 1, mask 0), and the docs' val, every doc with a live word."""
     rng = np.random.default_rng(n_docs)
     q, b, length = 2, 8, 5
     g = rng.uniform(0.05, 1.0, (q, b, n_docs, length)).astype(np.float32)
@@ -241,13 +245,46 @@ def test_resident_kernel_matches_fused_kernel(n_docs):
     val[val < 0.3] = 0.0
     val[:, 0] = 0.5                           # every doc has a live word
     val /= val.sum(axis=1, keepdims=True)
-    want = ops.sinkhorn_fused_all_batched(g, val, r, 2.0, 7, block_n=8)
-    n_pad = -(-n_docs // 128) * 128
-    tile = np.zeros((q, length, b, n_pad), np.float32)
-    tile[..., :n_docs] = np.transpose(g, (0, 3, 1, 2))
-    tile[..., n_docs:] = 0.5                  # pad docs: K columns, val 0
     mask = np.ones((q, b), np.float32)
     mask[:, -1] = 0.0
-    got = ops.sinkhorn_resident(tile, val, r, mask, 2.0, 7)
-    assert got.shape == (q, n_docs)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5)
+    return g, val, r, mask
+
+
+def _resident_tile(g, fill):
+    """The (Q, L, B, N_pad) tile of (Q, B, N, L) tiles, its pad docs
+    filled with K columns ``fill`` (the gather's word-0 columns)."""
+    q, b, n_docs, length = g.shape
+    n_pad = -(-n_docs // 128) * 128
+    tile = np.full((q, length, b, n_pad), fill, np.float32)
+    tile[..., :n_docs] = np.transpose(g, (0, 3, 1, 2))
+    return tile
+
+
+def _oracle(g, val, r):
+    return np.stack([np.asarray(sinkhorn_fused_all_ref(g[qi], val, r[qi],
+                                                       2.0, 7))
+                     for qi in range(g.shape[0])])
+
+
+@pytest.mark.parametrize("n_docs", [128, 200])
+def test_resident_kernel_matches_fused_kernel(n_docs):
+    """The resident (L, B, N) tile gives the oracle's answer per query,
+    inert pad docs and a zero pad query row included."""
+    g, val, r, mask = _oracle_tiles(n_docs)
+    want = _oracle(g, val, r)
+    got = ops.sinkhorn_resident(_resident_tile(g, 0.5), val, r, mask, 2.0, 7)
+    assert got.shape == (2, n_docs)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5)
+
+
+@pytest.mark.parametrize("n_docs", [128, 200])
+def test_resident_log_domain_matches_oracle(n_docs):
+    """Under ``log_domain`` the resident solve takes log K, the pad row
+    at -inf, and still gives the (linear) oracle's answer."""
+    g, val, r, mask = _oracle_tiles(n_docs)
+    want = _oracle(g, val, r)
+    with np.errstate(divide="ignore"):
+        logk = _resident_tile(np.log(g), np.log(0.5))
+    got = ops.sinkhorn_resident(logk, val, r, mask, 2.0, 7, log_domain=True)
+    assert got.shape == (2, n_docs)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5)

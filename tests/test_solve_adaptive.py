@@ -14,6 +14,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
+from _resident import solve_knl
 
 from repro.core import (
     LamUnderflowError,
@@ -29,7 +30,6 @@ from repro.core.index import _gather_g, _solve_gathered
 from repro.core.sinkhorn_sparse import sinkhorn_wmd_sparse
 from repro.core.sparse import PaddedDocs
 from repro.data.corpus import make_corpus
-from repro.kernels import ops
 
 
 @pytest.fixture(scope="module")
@@ -254,104 +254,20 @@ def test_log_domain_adaptive_engine_search(dedup, dedup_index):
 
 
 # ------------------------------------------------------------- kernel path
-def test_kernel_adaptive_matches_fixed(rng):
-    q_n, v_r, n, length = 2, 8, 64, 8
-    g = jnp.asarray(
-        rng.uniform(0.05, 1.0, (q_n, v_r, n, length)), dtype=jnp.float32
-    )
-    val = jnp.where(jnp.asarray(rng.random((n, length))) > 0.3, 0.7, 0.0)
-    val = val.at[:, 0].set(1.0)
-    r = jnp.asarray(rng.uniform(0.1, 1.0, (q_n, v_r)).astype(np.float32))
-    base = ops.sinkhorn_fused_all_batched(g, val, r, 4.0, 9, block_n=32)
-    capped, iters = ops.sinkhorn_fused_all_batched(
-        g,
-        val,
-        r,
-        4.0,
-        9,
-        block_n=32,
-        tol=0.0,
-        check_every=4,
-        with_iters=True,
-    )
-    assert iters.shape == (q_n, n // 32)
-    assert (np.asarray(iters) == 9).all()  # 1 + 2*check_every == the cap
-    np.testing.assert_allclose(
-        np.asarray(capped), np.asarray(base), rtol=1e-6, atol=1e-6
-    )
-
-
-def test_kernel_pad_query_block_exits_first_check(rng):
-    """An all-pad query's grid blocks are inert (w == 0 throughout), so
-    they exit at the FIRST residual check — per-block early exit."""
-    q_n, v_r, n, length = 1, 8, 32, 8
-    g = jnp.asarray(
-        rng.uniform(0.05, 1.0, (q_n, v_r, n, length)), dtype=jnp.float32
-    )
-    val = jnp.where(jnp.asarray(rng.random((n, length))) > 0.3, 0.7, 0.0)
-    val = val.at[:, 0].set(1.0)
-    r = jnp.asarray(rng.uniform(0.1, 1.0, (q_n, v_r)).astype(np.float32))
-    g2 = jnp.concatenate([g, jnp.zeros((1, v_r, n, length))])
-    r2 = jnp.concatenate([r, jnp.ones((1, v_r))])
-    wmd, iters = ops.sinkhorn_fused_all_batched(
-        g2,
-        val,
-        r2,
-        4.0,
-        20,
-        block_n=32,
-        tol=1e-4,
-        check_every=3,
-        with_iters=True,
-    )
-    iters = np.asarray(iters)
-    # pad query: the FIRST check exits (1 seed iter + one check window)
-    assert (iters[1] == 4).all(), iters
-    base = ops.sinkhorn_fused_all_batched(g, val, r, 4.0, 20, block_n=32)
-    np.testing.assert_allclose(
-        np.asarray(wmd)[:1], np.asarray(base), rtol=1e-3, atol=1e-4
-    )
-
-
 def test_kernel_log_domain_matches_linear(rng):
-    """Log-domain kernel (g = log K, pad rows -inf) == linear kernel."""
+    """Log-domain resident kernel (g = log K) == linear kernel."""
     v_r, n, length = 6, 32, 8
-    m = jnp.asarray(rng.uniform(0.1, 3.0, (v_r, n, length)), jnp.float32)
+    m = jnp.asarray(rng.uniform(0.1, 3.0, (1, v_r, n, length)), jnp.float32)
     lam = 2.0
     g = jnp.exp(-lam * m)
     val = jnp.where(jnp.asarray(rng.random((n, length))) > 0.3, 0.5, 0.0)
     val = val.at[:, 0].set(1.0)
-    r = jnp.asarray(rng.uniform(0.1, 1.0, v_r).astype(np.float32))
-    base = ops.sinkhorn_fused_all(g, val, r, lam, 10, block_n=32)
-    got = ops.sinkhorn_fused_all(
-        -lam * m, val, r, lam, 10, block_n=32, log_domain=True
-    )
+    r = jnp.asarray(rng.uniform(0.1, 1.0, (1, v_r)).astype(np.float32))
+    base = solve_knl(g, val, r, lam, 10, block_n=32)
+    got = solve_knl(-lam * m, val, r, lam, 10, block_n=32, log_domain=True)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(base), rtol=5e-4, atol=5e-4
     )
-
-
-def test_engine_kernel_impl_adaptive():
-    """Kernel engine path with the adaptive/precision knobs stays close to
-    the sparse fixed reference (tiny corpus; interpret mode)."""
-    small = make_corpus(
-        vocab_size=256, embed_dim=16, n_docs=32, n_queries=2, seed=4
-    )
-    index = build_index(small.docs, small.vecs)
-    ref = WmdEngine(index, lam=4.0, n_iter=13)
-    ker = WmdEngine(
-        index,
-        lam=4.0,
-        n_iter=13,  # 1 + 3*check_every: the capped while hits it exactly
-        impl="kernel",
-        block_n=32,
-        tol=0.0,
-        check_every=4,
-    )
-    d_ref = np.asarray(ref.query_batch(list(small.queries)))
-    d_ker = np.asarray(ker.query_batch(list(small.queries)))
-    np.testing.assert_allclose(d_ker, d_ref, rtol=5e-4, atol=5e-4)
-    assert (ker.iter_stats() == 13).all()
 
 
 # ------------------------------------------------------------- distributed

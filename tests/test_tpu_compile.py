@@ -54,23 +54,18 @@ def _cdist_exp():
                 ((VR,), jnp.float32)]
 
 
-def _rwmd_min_cdist():
-    from repro.kernels.ops import rwmd_min_cdist
-    fn = functools.partial(rwmd_min_cdist, interpret=False)
-    return fn, [((Q, VR, W), jnp.float32), ((Q, VR), jnp.float32),
-                ((V, W), jnp.float32)]
+def _kernel_route(precision):
+    """``one_to_many(impl="kernel")``: the Pallas cdist, the row gather
+    and the resident solve for one query of 43 words (padded to 48)."""
+    from repro.core.sparse import PaddedDocs
+    from repro.core.wmd import _kernel_wmd
 
-
-def _fused_batched(tol):
-    from repro.kernels.ops import sinkhorn_fused_all_batched
-
-    def fn(g, val, r, resmask, mask):
-        return sinkhorn_fused_all_batched(
-            g, val, r, 1.0, 15, interpret=False, tol=tol, resmask=resmask,
-            with_iters=True, mask=mask)
-    return fn, [((Q, VR, N_DOCS, L), jnp.float32), ((N_DOCS, L), jnp.float32),
-                ((Q, VR), jnp.float32), ((Q, N_DOCS), jnp.float32),
-                ((Q, VR), jnp.float32)]
+    def fn(r, vecs_sel, vecs, idx, val):
+        return _kernel_wmd(r, vecs_sel, vecs, PaddedDocs(idx, val), 1.0, 15,
+                           precision=precision, interpret=False)
+    return fn, [((43,), jnp.float32), ((43, W), jnp.float32),
+                ((V, W), jnp.float32), ((N_DOCS, L), jnp.int32),
+                ((N_DOCS, L), jnp.float32)]
 
 
 def _compute_kq():
@@ -106,11 +101,9 @@ def _solve_gathered(tol):
 
 CASES = {
     "cdist_exp": (_cdist_exp, True),
-    "rwmd_min_cdist": (_rwmd_min_cdist, True),
-    "sinkhorn_fused_all_batched": (functools.partial(_fused_batched, None),
-                                   True),
-    "sinkhorn_fused_all_batched_tol": (functools.partial(_fused_batched,
-                                                         1e-3), True),
+    "kernel_route": (functools.partial(_kernel_route, "fp32"), True),
+    "kernel_route_log": (functools.partial(_kernel_route, "log"), True),
+    "kernel_route_bf16": (functools.partial(_kernel_route, "bf16"), True),
     "compute_kq": (_compute_kq, False),
     "compute_kq_rows": (_compute_kq_rows, False),
     "gather_g": (_gather_g, False),

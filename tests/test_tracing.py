@@ -27,9 +27,9 @@ def corpus():
                        words_per_doc=(3, 40), seed=3)
 
 
-def _engine(corpus, impl="sparse", **kw):
+def _engine(corpus, **kw):
     return WmdEngine(build_index(corpus.docs, corpus.vecs), lam=2.0,
-                     n_iter=5, impl=impl, **kw)
+                     n_iter=5, **kw)
 
 
 ZERO = {"dispatches": 0, "host_syncs": 0, "resident_solves": 0,
@@ -81,9 +81,13 @@ def test_spans_nest_inside_query_batch(corpus, tmp_path):
         "wmd.scatter": c * g, "wmd.return": 1}
 
 
-@pytest.mark.parametrize("impl", ["sparse", "kernel"])
-def test_host_stats_match_the_plan(corpus, impl):
-    engine = _engine(corpus, impl)
+@pytest.mark.parametrize("path", ["sparse", "resident"])
+def test_host_stats_match_the_plan(corpus, path, monkeypatch):
+    """The counters follow the plan on the einsum and (its platform probe
+    patched) the resident solve, which also counts its solves."""
+    import repro.core.index as index_mod
+    monkeypatch.setattr(index_mod, "_on_tpu", lambda x: path == "resident")
+    engine = _engine(corpus)
     queries = list(corpus.queries)
     assert engine.host_stats() == ZERO
     engine.query_batch(queries)
@@ -92,8 +96,10 @@ def test_host_stats_match_the_plan(corpus, impl):
     engine.query_batch(queries[:1])
     c, g = _plan_counts(engine, queries)
     c1, _ = _plan_counts(engine, queries[:1])
+    solves = (c + c1) * g if path == "resident" else 0
     assert engine.host_stats() == dict(
-        ZERO, dispatches=(c + c1) * (1 + 2 * g), host_syncs=(c + c1) * g)
+        ZERO, dispatches=(c + c1) * (1 + 2 * g), host_syncs=(c + c1) * g,
+        resident_solves=solves, row_gathers=solves)
     engine.reset_host_stats()
     assert engine.host_stats() == ZERO
 
